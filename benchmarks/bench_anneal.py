@@ -40,4 +40,6 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     run()

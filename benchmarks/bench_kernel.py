@@ -1085,6 +1085,8 @@ def run(quick: bool = False, psl_only: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small shapes / single iteration (CI smoke)")

@@ -119,6 +119,8 @@ def run(quick: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="small fleet / short training (CI smoke)")

@@ -28,7 +28,9 @@ import numpy as np
 
 from repro import psl
 from repro.core.chimera import make_chimera, make_chip_graph
+from repro.runtime.compile_cache import use_compile_cache
 
+use_compile_cache()
 QUICK = bool(os.environ.get("REPRO_EXAMPLE_QUICK"))
 
 if QUICK:

@@ -24,7 +24,9 @@ from repro import api
 from repro.core import HardwareConfig, PBitMachine, CDConfig
 from repro.core import tasks
 from repro.core.chimera import make_chimera
+from repro.runtime.compile_cache import use_compile_cache
 
+use_compile_cache()
 QUICK = bool(os.environ.get("REPRO_EXAMPLE_QUICK"))
 
 graph = make_chimera(1, 2)   # two coupled cells: 5 visibles + 8 hiddens

@@ -22,7 +22,9 @@ from repro.core import (
     solve_maxcut,
 )
 from repro.core.chimera import make_chip_graph
+from repro.runtime.compile_cache import use_compile_cache
 
+use_compile_cache()
 graph = make_chip_graph()
 machine = PBitMachine.create(graph, jax.random.PRNGKey(0),
                              HardwareConfig(), beta=1.0, w_scale=0.03)

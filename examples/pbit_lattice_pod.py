@@ -38,6 +38,7 @@ from repro.core.chimera import make_chimera
 from repro.core.distributed import halo_bytes_per_sweep, sparse_energy
 from repro.core.hardware import HardwareConfig
 from repro.launch.mesh import halo_vs_hbm_seconds, make_line_mesh
+from repro.runtime.compile_cache import use_compile_cache
 
 SYNCS = {
     "barrier": api.Sync(),
@@ -50,6 +51,7 @@ ap = argparse.ArgumentParser()
 ap.add_argument("--sync", choices=sorted(SYNCS), default="barrier",
                 help="shard synchronization policy (api.Sync)")
 args = ap.parse_args()
+use_compile_cache()
 
 quick = bool(os.environ.get("REPRO_EXAMPLE_QUICK"))
 side = 8 if quick else 32          # 32x32 cells = 8192 p-bits

@@ -15,6 +15,9 @@ import jax
 from repro.core import HardwareConfig, PBitMachine, CDConfig
 from repro.core.chimera import make_chimera
 from repro.core import tasks
+from repro.runtime.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # one Chimera unit cell = a 4:4 RBM, exactly like the chip's
 graph = make_chimera(1, 1)

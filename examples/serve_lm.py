@@ -12,6 +12,8 @@ import sys
 from repro.launch.serve import main
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.argv = [sys.argv[0], "--arch", "gemma2-2b", "--reduced",
                 "--batch", "4", "--prompt-len", "32", "--gen", "32",
                 *sys.argv[1:]]

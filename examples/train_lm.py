@@ -19,12 +19,14 @@ from repro.launch import mesh as mesh_mod
 from repro.launch.steps import make_train_step
 from repro.models.model import build_model
 from repro.optim import adamw
+from repro.runtime.compile_cache import use_compile_cache
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=300)
 ap.add_argument("--batch", type=int, default=8)
 ap.add_argument("--seq", type=int, default=256)
 args = ap.parse_args()
+use_compile_cache()
 
 # ~100M params: 12L x 768, llama-style (deepseek family geometry, scaled)
 cfg = ModelCfg(

@@ -6,8 +6,8 @@ the execution backends in core/pbit.py + kernels/.  Construction does all
 the one-time work:
 
   * validates the spec and resolves ``backend`` / ``interpret`` (the only
-    place REPRO_PBIT_BACKEND / REPRO_PALLAS_INTERPRET are read — call
-    time never touches the environment);
+    place REPRO_PBIT_BACKEND is read — call time never touches the
+    environment);
   * builds the noise step function once (philox / counter / lfsr,
     including the LFSR's per-node gather permutation);
   * caches the graph's color masks, edge list, and Chimera slot tables;

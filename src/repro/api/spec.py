@@ -23,8 +23,8 @@ time) is a spec field, resolved exactly once at `Session` construction:
   * ``noise`` — ``philox | counter | lfsr`` (see core/pbit.py).
   * ``schedule`` — a first-class `Schedule`: `Constant`, `Anneal`
     (geometric/linear), or `Tempered` (per-chain ladder -> (S, B) betas).
-  * ``interpret`` — Pallas interpret mode; ``None`` resolves
-    ``REPRO_PALLAS_INTERPRET`` at compile.
+  * ``interpret`` — Pallas interpret mode; ``None`` resolves at compile
+    to compiled kernels on a TPU and interpret mode elsewhere.
   * ``mesh`` + ``partition`` — multi-device execution.  A `Partition`
     names the mesh axis the Chimera *cell rows* shard over (contiguous
     row bands per device, chain-coupler boundary spins halo-exchanged by
@@ -698,8 +698,8 @@ def spec_fingerprint(spec: SamplerSpec) -> str:
 def resolve_interpret(spec: SamplerSpec) -> bool:
     """Pallas interpret mode, resolved once at compile.
 
-    Delegates to the kernel layer's `default_interpret` so the
-    REPRO_PALLAS_INTERPRET parsing rule exists in exactly one place.
+    Delegates to the kernel layer's `default_interpret` so the rule
+    exists in exactly one place.
     """
     if spec.interpret is not None:
         return bool(spec.interpret)

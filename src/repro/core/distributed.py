@@ -58,6 +58,7 @@ from repro.kernels.shard_sweep import (
     halo_exchange,
     halo_half_sweep,
 )
+from repro.launch.mesh import auto_axes
 
 
 # ---------------------------------------------------------------------------
@@ -362,7 +363,7 @@ class ShardedEngine:
             from repro.api.spec import Sync
             sync = Sync()
         self.graph = graph
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh)
         self.noise = noise
         self.decimation = decimation
         self.chains = chains
@@ -472,9 +473,8 @@ class ShardedEngine:
                    for k in ("h", "gain", "off", "rg", "co")}}
 
     def _shard_map(self, fn, in_specs, out_specs):
-        from repro.launch.mesh import shard_map as shard_map_compat
-        return shard_map_compat(fn, mesh=self.mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_vma=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     # -- global <-> parts layout ----------------------------------------
     def _chip_parts(self, chip: EffectiveChip) -> dict:
@@ -1279,4 +1279,4 @@ def make_lattice_anneal(
 
 def lattice_input_sharding(mesh: Mesh, row_axes=("data",),
                            col_axes=("model",)):
-    return NamedSharding(mesh, P(row_axes, col_axes))
+    return NamedSharding(auto_axes(mesh), P(row_axes, col_axes))

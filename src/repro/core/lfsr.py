@@ -58,8 +58,12 @@ def reverse_bytes_bits(b: jax.Array) -> jax.Array:
 
 
 def byte_to_uniform(b: jax.Array) -> jax.Array:
-    """Map a byte to a mid-tread uniform in (-1, 1), as the 8-bit RNG DAC does."""
-    return (b.astype(jnp.float32) - 127.5) / 128.0
+    """Map a byte to a mid-tread uniform in (-1, 1), as the 8-bit RNG DAC does.
+
+    Converts through int32 (exact: the value is below 256) because Mosaic
+    has no uint32 -> float32 cast.
+    """
+    return (b.astype(jnp.int32).astype(jnp.float32) - 127.5) / 128.0
 
 
 def reverse_byte_bits_swar(b: jax.Array) -> jax.Array:
@@ -89,8 +93,8 @@ def flat_cell_uniforms(state: jax.Array) -> jax.Array:
 
     state: uint32[..., C].  Returns float32[..., 8*C] where column
     ``k*C + cell`` is vertical byte k of ``cell`` and ``(4+k)*C + cell`` is
-    the bit-reversed (horizontal) byte k.  Built from 2-D shift/mask ops only
-    so the same code runs inside the fused Pallas kernel.
+    the bit-reversed (horizontal) byte k.  The host reference; the fused
+    kernel reads the same bytes per node (`node_byte_uniforms`).
     """
     parts = []
     for k in range(4):
@@ -100,6 +104,21 @@ def flat_cell_uniforms(state: jax.Array) -> jax.Array:
         b = (state >> jnp.uint32(8 * k)) & jnp.uint32(0xFF)
         parts.append(byte_to_uniform(reverse_byte_bits_swar(b)))
     return jnp.concatenate(parts, axis=-1)
+
+
+def node_byte_uniforms(state: jax.Array, byte_sel: jax.Array) -> jax.Array:
+    """Per-node uniforms from per-node copies of the cell registers.
+
+    state: uint32[..., N], node i holding its cell's register; byte_sel:
+    uint32 broadcastable to state, ``perm // n_cells`` of
+    ``node_gather_perm`` (0..3 vertical byte k, 4..7 bit-reversed byte
+    k-4).  Equal to ``flat_cell_uniforms`` gathered by that perm, with
+    shift/mask ops only — the fused kernel's in-kernel LFSR read.
+    """
+    b = (state >> ((byte_sel & jnp.uint32(3)) << jnp.uint32(3))) \
+        & jnp.uint32(0xFF)
+    b = jnp.where(byte_sel >= jnp.uint32(4), reverse_byte_bits_swar(b), b)
+    return byte_to_uniform(b)
 
 
 def node_gather_perm(vert_scatter, horiz_scatter, n_nodes: int) -> np.ndarray:

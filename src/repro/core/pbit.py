@@ -179,7 +179,10 @@ def neuron_input(m: jax.Array, chip: EffectiveChip) -> jax.Array:
             "this chip carries only the sparse slot layout (W=None); use a "
             "sparse backend ('sparse' or 'fused_sparse'), e.g. "
             "PBitMachine(backend='sparse') or REPRO_PBIT_BACKEND=sparse")
-    return m @ chip.W.T + chip.h
+    # f32 weights at full precision: the TPU's default matmul precision
+    # would round the analog couplings to bf16
+    return jnp.matmul(m, chip.W.T,
+                      precision=jax.lax.Precision.HIGHEST) + chip.h
 
 
 def half_sweep(
@@ -289,9 +292,9 @@ def gibbs_sample(
     env var, default "ref").  The fused engine runs every sweep inside one
     kernel launch; it cannot emit per-sweep trajectories, so ``collect``
     falls back to the scan path.
-    interpret: Pallas interpret mode for the kernel backends (None -> the
-    REPRO_PALLAS_INTERPRET env default; api.Session resolves it once at
-    compile and passes it explicitly).
+    interpret: Pallas interpret mode for the kernel backends (None -> off
+    on a TPU, on elsewhere; api.Session resolves it once at compile and
+    passes it explicitly).
     """
     backend = resolve_backend(backend)
     # an explicit kernel= always wins (custom half-sweep injection): the

@@ -30,13 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
 
 def _kernel(mv_ref, mh_ref, mv_up_ref, mv_dn_ref,
             w_vh_ref, wv_up_ref, wv_dnin_ref, h_ref,

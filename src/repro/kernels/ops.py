@@ -13,9 +13,6 @@ used by cd.sample_visible_dist.
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -26,9 +23,7 @@ from repro.kernels.sweep_fused import sweep_fused_pallas, sweep_sparse_pallas
 
 
 def default_interpret() -> bool:
-    """interpret=True unless we are actually on TPU."""
-    if os.environ.get("REPRO_PALLAS_INTERPRET"):
-        return os.environ["REPRO_PALLAS_INTERPRET"] == "1"
+    """Interpret mode off the TPU (CPU tests), compiled kernels on it."""
     return jax.default_backend() != "tpu"
 
 

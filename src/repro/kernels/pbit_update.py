@@ -27,15 +27,7 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.util import pad_axis as _pad_to
 
-try:  # compiler params class moved across jax versions
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                               getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-    _COMPILER_PARAMS = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(m_k_ref, w_ref, m_io_ref, h_ref, gain_ref, off_ref,
@@ -128,18 +120,15 @@ def pbit_half_sweep_pallas(
             pl.BlockSpec((block_b, 1), lambda i, j, k: (i, 0)),        # beta col
     ]
     out_specs = pl.BlockSpec((block_b, block_n), lambda i, j, k: (i, j))
-    kw = {}
-    if not interpret and _COMPILER_PARAMS is not None:
-        kw["compiler_params"] = _COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         functools.partial(_kernel, n_k=n_k),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=jax.ShapeDtypeStruct((Bp, Np), out_dtype),
-        scratch_shapes=[_VMEM((block_b, block_n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_b, block_n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kw,
     )(mp, Wp, mp, hp, gp, op_, rgp, cop, maskp, up, bp)
     return out[:B, :N]

@@ -9,6 +9,7 @@ one term list.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -40,7 +41,7 @@ def pbit_half_sweep_ref(m, W, h, gain, off, rand_gain, comp_off,
     update_mask: (N,) bool;  beta: scalar or (B,) per-chain inverse
     temperature (parallel tempering replicas);  u: (B, N) uniform noise.
     """
-    I = m @ W.T + h
+    I = jnp.matmul(m, W.T, precision=jax.lax.Precision.HIGHEST) + h
     return field_decision_update(m, I, gain, off, rand_gain, comp_off,
                                  update_mask, beta, u)
 

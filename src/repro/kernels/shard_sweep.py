@@ -259,7 +259,7 @@ def fused_shard_exchange_resident(
     as the segmented emulation (`fused_shard_sweeps` windows + ppermute):
     identical noise counters, identical exchange-point staleness.  TPU
     meshes only — interpret mode raises, CI proves the contract through
-    the emulation.  Pending on-TPU validation (see ROADMAP.md).
+    the emulation; `chip_smoke.py --chips 4` checks it on 4 chips.
     """
     from repro.kernels.sweep_fused import sweep_sparse_exchange_pallas
 

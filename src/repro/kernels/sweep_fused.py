@@ -28,7 +28,8 @@ Two weight layouts share the kernel body:
     resident engine to roughly N <= 1.5k fp32 on a 16 MB-VMEM core.
   * sparse (`sweep_sparse_pallas`) — the Chimera-native fixed-degree slot
     layout (ChimeraGraph.neighbor_table): nbr_idx/nbr_w (D, N) with D = 6
-    on the chip's graph.  Neuron input is D lane-gathers + multiply-adds —
+    on the chip's graph.  Neuron input is D gathers (built from lane
+    rotations, `_rotate_gather`) + multiply-adds —
     2·B·N·D FLOPs instead of 2·B·N², and 8·D·N weight bytes instead of
     4·N², so ≥32k-spin lattices stay VMEM-resident.  Slots accumulate in
     ascending-neighbor order, making the result bit-exact against both the
@@ -58,25 +59,71 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import lfsr as lfsr_mod
 from repro.kernels.util import pad_axis as _pad_axis
 from repro.kernels.util import round_up as _round_up
 
-try:  # compiler params class moved across jax versions
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-    _COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                               getattr(pltpu, "TPUCompilerParams", None))
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-    _COMPILER_PARAMS = None
+_VMEM = pltpu.VMEM
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 NOISE_COUNTER = "counter"
 NOISE_LFSR = "lfsr"
 
 MAX_HIST_VISIBLE = 12  # one-hot reduction over 2^nv bins; keep it VMEM-sane
+
+
+def _lane_rotations(idx, n_cols: int, Np: int) -> jax.Array:
+    """Distinct lane rotations that realize the column gather ``idx``.
+
+    idx: (R, W) int source columns; only the first ``n_cols`` columns are
+    real.  Returns an int32 (1 + Np,) table ``[K, s_0, .., s_{K-1}, 0..]``:
+    the K distinct ``s = (i - idx[r, i]) mod Np`` for which
+    ``jnp.roll(x, s, axis=-1)[:, i] == x[:, idx[r, i]]``.  At most Np
+    rotations exist, so the table never truncates.  Chimera tables need
+    few: 19 on the chip graph (in-cell ±1..±7, ±8 across columns, ±64
+    across rows, and 0 for self-pointing padding slots).
+    """
+    idx = jnp.asarray(idx, jnp.int32)[:, :n_cols]
+    s = (jnp.arange(n_cols, dtype=jnp.int32)[None, :] - idx) % Np
+    s = jnp.where(idx >= 0, s, 0)   # idx < 0: lane takes no source
+    present = jnp.zeros((Np,), bool).at[s.reshape(-1)].set(True)
+    shifts = jnp.nonzero(present, size=Np, fill_value=0)[0]
+    return jnp.concatenate([jnp.sum(present, dtype=jnp.int32)[None],
+                            shifts.astype(jnp.int32)])
+
+
+def _rotate_gather(x, idx_rows, rot_ref):
+    """In-kernel column gather: ``out[r][:, i] = x[:, idx_rows[r][0, i]]``.
+
+    Mosaic gathers only inside one 128-lane vreg, so a gather across a
+    multi-vreg row is built from whole-row lane rotations instead: for
+    each rotation in ``rot_ref`` (see `_lane_rotations`), rotate x and
+    keep the lanes whose rotated lane id equals the wanted source.  Pure
+    selection — every output element is one input element, bit for bit.
+    idx_rows: (1, W) int32 rows with W <= x's width (a multiple of 128).
+    """
+    W = idx_rows[0].shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[-1]), 1)
+
+    def body(k, gs):
+        s = rot_ref[k + 1]
+        src = pltpu.roll(lane, s, 1)[:, :W]
+        xr = pltpu.roll(x, s, 1)[:, :W]
+        return tuple(jnp.where(ix == src, xr, g)
+                     for ix, g in zip(idx_rows, gs))
+
+    init = tuple(jnp.zeros((x.shape[0], W), x.dtype) for _ in idx_rows)
+    return jax.lax.fori_loop(0, rot_ref[0], body, init)
+
+
+def _noise_state_out(noise_in, n_half):
+    """Counter state (seed, ctr) advanced by n_half ticks, as one (1, 2)
+    vector (Mosaic cannot store scalars to VMEM)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1)
+    return noise_in + jnp.where(lane == 1, jnp.uint32(n_half),
+                                jnp.uint32(0))
 
 
 def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
@@ -89,6 +136,7 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
     if sparse:
         idx_ref = next(it)                    # (Dp, Np) neighbor table
         w_ref = next(it)                      # (Dp, Np) slot weights
+        rot_ref = next(it)                    # SMEM (1 + Np,) rotations
     else:
         w_ref = next(it)                      # (Np, Np) dense couplings
     h_ref, g_ref, off_ref, rg_ref, co_ref = (next(it) for _ in range(5))
@@ -96,10 +144,9 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
     betas_ref = next(it)
     clampm_ref = next(it) if has_clamp else None
     clampv_ref = next(it) if has_clamp else None
-    meas_ref = next(it) if (accumulate or collect_hist) else None
-    vis_ref = next(it) if collect_hist else None   # (1, NVp) visible cols
-    pow_ref = next(it) if collect_hist else None   # (1, NVp) 2^k bin powers
-    perm_ref = next(it) if noise_mode == NOISE_LFSR else None
+    meas_ref = next(it) if (accumulate or collect_hist) else None  # SMEM
+    binw_ref = next(it) if collect_hist else None  # (1, Np) 2^k at vis k
+    byte_ref = next(it) if noise_mode == NOISE_LFSR else None  # (1, Np)
     coords_ref = next(it) if has_coords else None
     noise_in_ref = next(it)
     if stream:
@@ -165,19 +212,23 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
         cols = jax.lax.broadcasted_iota(jnp.uint32, (tb, Np), 1) + col0
         noise_carry0 = jnp.zeros((), jnp.uint32)  # unused
     else:
-        noise_carry0 = noise_in_ref[...]          # (tb, Cp) LFSR states
-        perm_cols = perm_ref[0, :]                # node -> flat LFSR column
+        # (tb, Np) LFSR states replicated onto each cell's nodes: every
+        # node reads its own byte in place, so no gather is needed
+        noise_carry0 = noise_in_ref[...]
+        byte_sel = byte_ref[...]
+    if sparse:
+        idx_rows = [idx_ref[pl.ds(d, 1), :] for d in range(D)]
 
     def neuron_current(m):
         """Eqn 1 over the resident tile: matmul (dense) or D-slot gather."""
         if sparse:
             acc = jnp.zeros((tb, Np), jnp.float32)
-            for d in range(D):
-                acc = acc + w_ref[pl.ds(d, 1), :] * jnp.take(
-                    m, idx_ref[d, :], axis=-1)
+            for d, g in enumerate(_rotate_gather(m, idx_rows, rot_ref)):
+                acc = acc + w_ref[pl.ds(d, 1), :] * g
             return acc + hrow
         return jax.lax.dot_general(
             m, w, dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32) + hrow
 
     # Launch-relative half-sweep window.  The fused-exchange engine splits
@@ -207,8 +258,7 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
             u = lfsr_mod.counter_uniform(seed, ctr, rows, cols)
         else:
             st = lfsr_mod.lfsr_step_n(st, decimation)
-            u = jnp.take(lfsr_mod.flat_cell_uniforms(st), perm_cols,
-                         axis=-1)
+            u = lfsr_mod.node_byte_uniforms(st, byte_sel)
         beta_col = betas_ref[pl.ds(s_idx, 1), :].reshape(tb, 1)
         I = neuron_current(m)
         act = jnp.tanh(beta_col * grow * (I + offrow))
@@ -218,7 +268,7 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
 
     def sweep_stats(m, s_idx):
         """Accumulate moments/histogram after sweep s_idx completes."""
-        wgt = meas_ref[pl.ds(s_idx, 1), :]                      # (1, 1)
+        wgt = meas_ref[s_idx]                                   # scalar
         # padded batch rows update like real chains; keep them out of
         # the statistics
         row_ids = (jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0)
@@ -227,25 +277,22 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
             mv = jnp.where(row_ids < B, m, 0.0)
             ssum_ref[...] += wgt * jnp.sum(mv, axis=0, keepdims=True)
             if sparse:
-                for d in range(D):
-                    corr = jnp.sum(
-                        mv * jnp.take(mv, idx_ref[d, :], axis=-1),
-                        axis=0, keepdims=True)                   # (1, Np)
-                    csum_ref[pl.ds(d, 1), :] += wgt[0, 0] * corr
+                gathered = _rotate_gather(mv, idx_rows, rot_ref)
+                for d, g in enumerate(gathered):
+                    corr = jnp.sum(mv * g, axis=0, keepdims=True)  # (1, Np)
+                    csum_ref[pl.ds(d, 1), :] += wgt * corr
             else:
-                csum_ref[...] += wgt[0, 0] * jax.lax.dot_general(
+                csum_ref[...] += wgt * jax.lax.dot_general(
                     mv, mv, dimension_numbers=(((0,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32)          # m^T m
         if collect_hist:
-            mv_vis = jnp.take(m, vis_ref[0, :], axis=-1)        # (tb, NVp)
-            codes = jnp.sum(
-                jnp.where(mv_vis > 0, pow_ref[...], 0),
-                axis=1, keepdims=True)                           # (tb, 1)
+            # visible node k carries weight 2^k, every other node 0
+            codes = jnp.sum(jnp.where(m > 0, binw_ref[...], 0),
+                            axis=1, keepdims=True)               # (tb, 1)
             bin_ids = jax.lax.broadcasted_iota(jnp.int32, (tb, NBp), 1)
             onehot = ((codes == bin_ids)
                       & (row_ids < B)).astype(jnp.float32)
-            hist_ref[...] += wgt[0, 0] * jnp.sum(onehot, axis=0,
-                                                 keepdims=True)
+            hist_ref[...] += wgt * jnp.sum(onehot, axis=0, keepdims=True)
 
     m_cur = m0_ref[...].astype(jnp.float32)
     st_cur = noise_carry0
@@ -278,8 +325,7 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
     m_out_ref[...] = m_fin.astype(m_out_ref.dtype)
 
     if noise_mode == NOISE_COUNTER:
-        noise_out_ref[0, 0] = seed
-        noise_out_ref[0, 1] = ctr0 + jnp.uint32(n_half_eff)
+        noise_out_ref[...] = _noise_state_out(noise_in_ref[...], n_half_eff)
     else:
         noise_out_ref[...] = st_fin
 
@@ -389,8 +435,9 @@ def _launch(
         wp = _pad_axis(_pad_axis(
             jnp.asarray(nbr_w, jnp.float32), Dp, 0), 128, 1)
         in_specs += [pl.BlockSpec((Dp, Np), lambda i: (0, 0)),  # nbr_idx
-                     pl.BlockSpec((Dp, Np), lambda i: (0, 0))]  # nbr_w
-        args += [idxp, wp]
+                     pl.BlockSpec((Dp, Np), lambda i: (0, 0)),  # nbr_w
+                     _SMEM]                                     # rotations
+        args += [idxp, wp, _lane_rotations(nbr_idx, N, Np)]
     else:
         Wp = _pad_axis(_pad_axis(dense_W, 128, 0), 128, 1)
         in_specs.append(pl.BlockSpec((Np, Np), lambda i: (0, 0)))  # W
@@ -409,20 +456,16 @@ def _launch(
         args.append(_pad_axis(_pad_axis(
             jnp.asarray(clamp_values, jnp.float32), tb, 0), 128, 1))
     if accumulate or collect_hist:
-        in_specs.append(pl.BlockSpec((S, 1), lambda i: (0, 0)))
-        args.append(jnp.asarray(measured, jnp.float32).reshape(S, 1))
+        in_specs.append(_SMEM)
+        args.append(jnp.asarray(measured, jnp.float32).reshape(S))
     NBp = 0
     if collect_hist:
-        NVp = _round_up(n_visible, 128)
         NBp = _round_up(NB, 128)
-        visp = _pad_axis(
-            jnp.asarray(visible_idx, jnp.int32).reshape(1, -1), 128, 1, 0)
-        powp = _pad_axis(jnp.asarray(
-            2 ** np.arange(n_visible, dtype=np.int32)).reshape(1, -1),
-            128, 1, 0)
-        in_specs += [pl.BlockSpec((1, NVp), lambda i: (0, 0)),
-                     pl.BlockSpec((1, NVp), lambda i: (0, 0))]
-        args += [visp, powp]
+        binw = jnp.zeros((Np,), jnp.int32).at[
+            jnp.asarray(visible_idx, jnp.int32)].add(
+            jnp.asarray(2 ** np.arange(n_visible, dtype=np.int32)))
+        in_specs.append(vec())
+        args.append(binw.reshape(1, Np))
 
     has_coords = coord_offset is not None
     if has_coords:
@@ -442,21 +485,28 @@ def _launch(
         if gather_perm is None:
             raise ValueError("lfsr noise_mode needs gather_perm "
                              "(see core/lfsr.py::node_gather_perm)")
+        # flat LFSR column perm[i] = byte * C + cell: node i reads byte
+        # `byte` (4..7: bit-reversed) of its cell's register.  The kernel
+        # steps one register copy per node, so each node reads its byte
+        # in place; every copy of a cell evolves identically, and the
+        # cell's first node hands its copy back.
         C = noise_state.shape[-1]
-        Cp = _round_up(C, 128)
-        # remap flat columns from the C-cell layout to the padded-Cp layout
         p = np.asarray(gather_perm, np.int64)
-        p = (p // C) * Cp + (p % C)
-        perm_padded = np.concatenate(
-            [p, np.zeros(Np - N, np.int64)]).astype(np.int32)
-        in_specs.append(pl.BlockSpec((1, Np), lambda i: (0, 0)))
-        args.append(jnp.asarray(perm_padded).reshape(1, Np))
-        stp = _pad_axis(_pad_axis(jnp.asarray(noise_state, jnp.uint32),
-                                  tb, 0, 1), 128, 1, 1)
-        in_specs.append(pl.BlockSpec((tb, Cp), lambda i: (i, 0)))
+        node_cell, node_byte = p % C, p // C
+        first = np.full(C, -1, np.int64)
+        first[node_cell[::-1]] = np.arange(N)[::-1]
+        if (first < 0).any():
+            raise ValueError("gather_perm leaves an LFSR cell without nodes")
+        in_specs.append(vec())
+        args.append(_pad_axis(
+            jnp.asarray(node_byte, jnp.uint32).reshape(1, N), 128, 1))
+        stp = _pad_axis(_pad_axis(
+            jnp.asarray(noise_state, jnp.uint32)[:, node_cell],
+            tb, 0, 1), 128, 1, 1)
+        in_specs.append(pl.BlockSpec((tb, Np), lambda i: (i, 0)))
         args.append(stp)
-        noise_out_shape = jax.ShapeDtypeStruct((Bp, Cp), jnp.uint32)
-        noise_out_spec = pl.BlockSpec((tb, Cp), lambda i: (i, 0))
+        noise_out_shape = jax.ShapeDtypeStruct((Bp, Np), jnp.uint32)
+        noise_out_spec = pl.BlockSpec((tb, Np), lambda i: (i, 0))
 
     aliases = {}
     if stream:
@@ -495,12 +545,6 @@ def _launch(
         scratch += [_VMEM((Dp, Np), jnp.float32),
                     _VMEM((1, Np), jnp.float32)]
 
-    kw = {}
-    if not interpret and _COMPILER_PARAMS is not None:
-        kw["compiler_params"] = _COMPILER_PARAMS(
-            dimension_semantics=("arbitrary",))
-    if aliases:
-        kw["input_output_aliases"] = aliases
     outs = pl.pallas_call(
         functools.partial(
             _kernel, S=S, tb=tb, Np=Np, n_b=n_b, B=B,
@@ -514,15 +558,17 @@ def _launch(
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        **kw,
     )(*args)
 
     result = [outs[0][:B, :N]]
     if noise_mode == NOISE_COUNTER:
         result.append(outs[1].reshape(2))
     else:
-        result.append(outs[1][:B, :noise_state.shape[-1]])
+        result.append(outs[1][:B, first])
     k = 2
     if accumulate:
         result.append(outs[k][0, :N])
@@ -725,11 +771,11 @@ def sweep_sparse_stream_pallas(
 # `mode="async"` installs the PREVIOUS exchange's values and lets the
 # in-flight copy overlap the segment's compute — the same staleness
 # contract as the host engine's pend-buffer.  Host CI cannot run RDMA:
-# `REPRO_PALLAS_INTERPRET` runs the bit-exact emulation instead
+# interpret mode runs the bit-exact emulation instead
 # (ShardedEngine's fused-resident-exchange loop shape: the same launch
 # split at exchange points via `half_offset`/`n_half`, ppermute between
-# segments, one jitted graph).  This kernel compiles only on real TPU
-# meshes and is pending on-TPU validation (ROADMAP).
+# segments, one jitted graph).  This kernel compiles only for TPU meshes
+# (tests/test_tpu_compile.py).
 
 _HALO_UP, _HALO_DN = 0, 1  # recv-buffer direction slots
 
@@ -740,10 +786,14 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
     it = iter(refs)
     m0_ref = next(it)                         # (tb, Np) [local|hu|hd]
     idx_ref, w_ref = next(it), next(it)       # (Dp, Np)
+    rot_ref = next(it)                        # SMEM neighbor rotations
     h_ref, g_ref, off_ref, rg_ref, co_ref = (next(it) for _ in range(5))
     mask0_ref, mask1_ref = next(it), next(it)
     betas_ref = next(it)                      # (S, tb)
     sendu_ref, sendd_ref = next(it), next(it)  # (1, Hp) boundary gathers
+    srot_ref = next(it)                       # SMEM send rotations
+    inst_ref = next(it)                       # (1, Np) halo lane sources
+    irot_ref = next(it)                       # SMEM install rotations
     clampm_ref = next(it) if has_clamp else None
     clampv_ref = next(it) if has_clamp else None
     meas_ref = next(it) if accumulate else None
@@ -797,75 +847,83 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
     @pl.when(up_ok)
     def _sig_up():
         pltpu.semaphore_signal(
-            barrier, inc=1, device_id=(my - 1,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+            barrier, inc=1, device_id={axis_name: my - 1},
+            device_id_type=pltpu.DeviceIdType.MESH)
 
     @pl.when(dn_ok)
     def _sig_dn():
         pltpu.semaphore_signal(
-            barrier, inc=1, device_id=(my + 1,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
+            barrier, inc=1, device_id={axis_name: my + 1},
+            device_id_type=pltpu.DeviceIdType.MESH)
 
     pltpu.semaphore_wait(barrier, n_nbr)
 
+    idx_rows = [idx_ref[pl.ds(d, 1), :] for d in range(D)]
+    send_rows = [sendu_ref[...], sendd_ref[...]]
+
+    def copy(direction, parity):
+        """My outgoing boundary copy: direction 0 sends my first-row
+        boundary UP (it becomes that neighbor's halo_dn), 1 sends my
+        last-row boundary DOWN (that neighbor's halo_up).  The same
+        descriptor waits the matching local semaphores."""
+        slot = _HALO_DN if direction == 0 else _HALO_UP
+        return pltpu.make_async_remote_copy(
+            src_ref=sbuf_ref.at[direction, parity],
+            dst_ref=rbuf_ref.at[slot, parity],
+            send_sem=send_sem.at[direction, parity],
+            recv_sem=recv_sem.at[slot, parity],
+            device_id={axis_name: my - 1 if direction == 0 else my + 1},
+            device_id_type=pltpu.DeviceIdType.MESH)
+
     def start_exchange(m, parity):
         """Gather boundary spins and fire both neighbor RDMAs."""
-        sbuf_ref[0, parity] = jnp.take(m, sendu_ref[0, :], axis=1)
-        sbuf_ref[1, parity] = jnp.take(m, sendd_ref[0, :], axis=1)
+        up, dn = _rotate_gather(m, send_rows, srot_ref)
+        sbuf_ref[0, parity] = up
+        sbuf_ref[1, parity] = dn
 
         @pl.when(up_ok)
         def _send_up():
-            # my first-row boundary becomes the UP neighbor's halo_dn
-            pltpu.make_async_remote_copy(
-                src_ref=sbuf_ref.at[0, parity],
-                dst_ref=rbuf_ref.at[_HALO_DN, parity],
-                send_sem=send_sem.at[0, parity],
-                recv_sem=recv_sem.at[_HALO_DN, parity],
-                device_id=(my - 1,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL).start()
+            copy(0, parity).start()
 
         @pl.when(dn_ok)
         def _send_dn():
-            # my last-row boundary becomes the DOWN neighbor's halo_up
-            pltpu.make_async_remote_copy(
-                src_ref=sbuf_ref.at[1, parity],
-                dst_ref=rbuf_ref.at[_HALO_UP, parity],
-                send_sem=send_sem.at[1, parity],
-                recv_sem=recv_sem.at[_HALO_UP, parity],
-                device_id=(my + 1,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL).start()
+            copy(1, parity).start()
+
+    halo_lanes = inst_ref[...] >= 0
 
     def install_halos(m, parity):
         """Wait the incoming copies of `parity` and refresh halo columns."""
         @pl.when(up_ok)
         def _wait_up():
-            pltpu.semaphore_wait(recv_sem.at[_HALO_UP, parity], 1)
+            copy(1, parity).wait_recv()    # lands in my _HALO_UP slot
 
         @pl.when(dn_ok)
         def _wait_dn():
-            pltpu.semaphore_wait(recv_sem.at[_HALO_DN, parity], 1)
-        hu = jnp.where(up_ok, rbuf_ref[_HALO_UP, parity][:, :H], 0.0)
-        hd = jnp.where(dn_ok, rbuf_ref[_HALO_DN, parity][:, :H], 0.0)
-        m = jax.lax.dynamic_update_slice(m, hu, (0, n_loc))
-        return jax.lax.dynamic_update_slice(m, hd, (0, n_loc + H))
+            copy(0, parity).wait_recv()    # lands in my _HALO_DN slot
+        hu = jnp.where(up_ok, rbuf_ref[_HALO_UP, parity], 0.0)
+        hd = jnp.where(dn_ok, rbuf_ref[_HALO_DN, parity], 0.0)
+        src = jnp.concatenate(
+            [hu, hd] + ([jnp.zeros((tb, Np - 2 * Hp), jnp.float32)]
+                        if Np > 2 * Hp else []), axis=1)
+        (halos,) = _rotate_gather(src, [inst_ref[...]], irot_ref)
+        return jnp.where(halo_lanes, halos, m)
 
     def wait_sends(parity):
         @pl.when(up_ok)
         def _ws_up():
-            pltpu.semaphore_wait(send_sem.at[0, parity], 1)
+            copy(0, parity).wait_send()
 
         @pl.when(dn_ok)
         def _ws_dn():
-            pltpu.semaphore_wait(send_sem.at[1, parity], 1)
+            copy(1, parity).wait_send()
 
     def half_update(m, s_idx, c, half_j):
         ctr = ctr0 + half_j
         u = lfsr_mod.counter_uniform(seed, ctr, rows, cols)
         beta_col = betas_ref[pl.ds(s_idx, 1), :].reshape(tb, 1)
         acc = jnp.zeros((tb, Np), jnp.float32)
-        for d in range(D):
-            acc = acc + w_ref[pl.ds(d, 1), :] * jnp.take(
-                m, idx_ref[d, :], axis=-1)
+        for d, g in enumerate(_rotate_gather(m, idx_rows, rot_ref)):
+            acc = acc + w_ref[pl.ds(d, 1), :] * g
         act = jnp.tanh(beta_col * grow * (acc + hrow + offrow))
         decision = act + rgrow * u + corow
         new = jnp.where(decision >= 0.0, 1.0, -1.0)
@@ -877,14 +935,13 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
         return m
 
     def sweep_stats(m, s_idx):
-        wgt = meas_ref[pl.ds(s_idx, 1), :]
+        wgt = meas_ref[s_idx]
         row_ids = jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0)
         mv = jnp.where(row_ids < B, m, 0.0)
         ssum_ref[...] += wgt * jnp.sum(mv, axis=0, keepdims=True)
-        for d in range(D):
-            corr = jnp.sum(mv * jnp.take(mv, idx_ref[d, :], axis=-1),
-                           axis=0, keepdims=True)
-            csum_ref[pl.ds(d, 1), :] += wgt[0, 0] * corr
+        for d, g in enumerate(_rotate_gather(mv, idx_rows, rot_ref)):
+            corr = jnp.sum(mv * g, axis=0, keepdims=True)
+            csum_ref[pl.ds(d, 1), :] += wgt * corr
 
     m = m0_ref[...].astype(jnp.float32)
     n_ex = len(segments)
@@ -940,8 +997,7 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
         wait_sends(parity)
 
     m_out_ref[...] = m.astype(m_out_ref.dtype)
-    noise_out_ref[0, 0] = seed
-    noise_out_ref[0, 1] = ctr0 + jnp.uint32(2 * S)
+    noise_out_ref[...] = _noise_state_out(noise_in_ref[...], 2 * S)
     if accumulate:
         ssum_out_ref[...] = ssum_ref[...]
         csum_out_ref[...] = csum_ref[...]
@@ -984,10 +1040,10 @@ def sweep_sparse_exchange_pallas(
     """S resident sweeps with IN-KERNEL halo refresh at every exchange
     point — the hardware twin of the engine's fused-resident-exchange
     emulation (identical noise counters, identical exchange-point
-    staleness), pending on-TPU validation.
+    staleness); `chip_smoke.py --chips 4` checks the match on 4 chips.
 
-    Must run under ``shard_map`` over a 1-D ``axis_name`` mesh of
-    ``n_row`` devices.  Single batch tile (the exchange needs the whole
+    Must run under ``shard_map`` over a mesh whose ``axis_name`` axis
+    holds the ``n_row`` row bands.  Single batch tile (the exchange needs the whole
     shard's boundary at once).  Raises in interpret mode: host CI runs
     the segmented emulation (`ShardedEngine._local_sweeps`), which this
     kernel must match bit-for-bit on hardware.
@@ -997,8 +1053,6 @@ def sweep_sparse_exchange_pallas(
             "in-kernel RDMA halo exchange needs a real TPU mesh; "
             "interpret mode runs the bit-exact segmented emulation "
             "(ShardedEngine's fused-resident-exchange loop shape)")
-    if pltpu is None or _COMPILER_PARAMS is None:
-        raise RuntimeError("pallas TPU backend unavailable")
     from repro.kernels.ref import halo_exchange_segments
 
     B, N = m_ext.shape
@@ -1016,6 +1070,13 @@ def sweep_sparse_exchange_pallas(
     Hp = _round_up(max(H, 1), 128)
     tb = _round_up(B, 8)
     Dp = _round_up(D, 8)
+    if 2 * Hp > Np:
+        raise ValueError(f"halo width {H} too wide for a {N}-column shard")
+    # halo installs gather from the [recv_up | recv_dn] buffers: ext
+    # column n_loc + j reads up-lane j, n_loc + H + j reads dn-lane j
+    inst = np.full((1, Np), -1, np.int32)
+    inst[0, n_loc:n_loc + H] = np.arange(H)
+    inst[0, n_loc + H:n_loc + 2 * H] = Hp + np.arange(H)
 
     row = lambda x: _pad_axis(
         jnp.asarray(x).reshape(1, -1).astype(jnp.float32), 128, 1)
@@ -1034,12 +1095,17 @@ def sweep_sparse_exchange_pallas(
 
     full = lambda shape: pl.BlockSpec(shape, lambda: tuple(
         0 for _ in shape))
-    in_specs = [full((tb, Np)), full((Dp, Np)), full((Dp, Np))]
-    args = [mp, idxp, wp]
+    in_specs = [full((tb, Np)), full((Dp, Np)), full((Dp, Np)), _SMEM]
+    args = [mp, idxp, wp, _lane_rotations(nbr_idx, N, Np)]
     in_specs += [full((1, Np))] * 7 + [full((S, tb)),
-                                       full((1, Hp)), full((1, Hp))]
+                                       full((1, Hp)), full((1, Hp)), _SMEM]
     args += [row(h), row(gain), row(off), row(rand_gain), row(comp_off),
-             m0p, m1p, betasp, sup, sdn]
+             m0p, m1p, betasp, sup, sdn,
+             _lane_rotations(jnp.stack([jnp.asarray(send_up, jnp.int32),
+                                        jnp.asarray(send_dn, jnp.int32)]),
+                             H, Np),
+             jnp.asarray(inst), _lane_rotations(inst, Np, Np)]
+    in_specs += [full((1, Np)), _SMEM]
     if has_clamp:
         in_specs += [full((1, Np)), full((tb, Np))]
         args += [_pad_axis(jnp.asarray(clamp_mask).reshape(1, -1)
@@ -1048,8 +1114,8 @@ def sweep_sparse_exchange_pallas(
                      jnp.asarray(clamp_values, jnp.float32), tb, 0),
                      128, 1)]
     if accumulate:
-        in_specs.append(full((S, 1)))
-        args.append(jnp.asarray(measured, jnp.float32).reshape(S, 1))
+        in_specs.append(_SMEM)
+        args.append(jnp.asarray(measured, jnp.float32).reshape(S))
     in_specs.append(full((1, 2)))
     args.append(jnp.zeros((1, 2), jnp.uint32) if coord_offset is None
                 else jnp.asarray(coord_offset, jnp.uint32).reshape(1, 2))
@@ -1084,12 +1150,8 @@ def sweep_sparse_exchange_pallas(
         scratch += [_VMEM((Dp, Np), jnp.float32), _VMEM((1, Np),
                                                         jnp.float32)]
 
-    kw = {"compiler_params": _COMPILER_PARAMS(
-        dimension_semantics=(), has_side_effects=True,
-        collective_id=collective_id)}
-    if stream:
-        # stream excludes accumulate, so staged outputs sit at 2/3
-        kw["input_output_aliases"] = {len(args) - 2: 2, len(args) - 1: 3}
+    # stream excludes accumulate, so staged outputs sit at 2/3
+    aliases = {len(args) - 2: 2, len(args) - 1: 3} if stream else {}
     outs = pl.pallas_call(
         functools.partial(
             _exchange_kernel, S=S, tb=tb, Np=Np, B=B, n_loc=n_loc, H=H,
@@ -1101,8 +1163,11 @@ def sweep_sparse_exchange_pallas(
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(), has_side_effects=True,
+            collective_id=collective_id),
         interpret=False,
-        **kw,
     )(*args)
 
     result = [outs[0][:B, :N], outs[1].reshape(2)]

@@ -11,34 +11,23 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed after jax 0.4.x; older versions imply Auto axes
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` across jax versions.
+def auto_axes(mesh: Mesh) -> Mesh:
+    """The same devices and axis names with every axis `AxisType.Auto`.
 
-    jax >= 0.6 exposes it at the top level with `check_vma`; 0.4.x has
-    `jax.experimental.shard_map.shard_map` with the same knob named
-    `check_rep`.
+    `jax.make_mesh` gives Explicit axes by default, under which a gather
+    over a sharded operand must name its output sharding.  The sharded
+    engine lays data out with `shard_map` specs and leaves the rest to
+    the compiler, so it runs every user mesh as an Auto mesh.
     """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shmap
-    return _shmap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 # TPU v5e hardware constants (roofline + napkin math)
 PEAK_FLOPS_BF16 = 197e12        # per chip

@@ -40,6 +40,8 @@ def build_requests(n_requests: int, n_tenants: int, chains: int,
 
 
 def main(argv=None) -> int:
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m repro.serve",
         description="Demo loop for the resilient multi-tenant p-bit "
