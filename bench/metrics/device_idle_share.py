@@ -1,0 +1,12 @@
+"""Share of the traced window in which no operation ran on the device (%).
+
+1 - (union of the device's op intervals) / (traced window), from the trace
+(bench/trace_reduce.py), averaged over the chips used.
+"""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
