@@ -46,6 +46,7 @@ from repro.core.hardware import (
     quantize_codes,
 )
 from repro.kernels.ref import scatter_edge_slots
+from repro.runtime.spans import named_jit, span
 
 # the fleet axis vmaps whole sampling closures; the launch-resident fused
 # engines demote to their bit-exact scan siblings under vmap (the Pallas
@@ -473,33 +474,34 @@ class Session:
         dense/sparse programming route is a static property of the
         trace).
         """
-        E, n = self.graph.n_edges, self.graph.n_nodes
-        J = jnp.asarray(J_edge_codes)
-        h = jnp.asarray(h_codes)
-        if J.shape != (E,):
-            raise ValueError(
-                f"J_edge_codes must be edge-list shaped ({E},), got "
-                f"{J.shape}; scatter dense codes to the edge list first")
-        if h.shape != (n,):
-            raise ValueError(f"h_codes must be ({n},), got {h.shape}")
-        if mismatch is not None and \
-                type(mismatch) is not type(self.spec.mismatch):
-            raise ValueError(
-                f"program mismatch type {type(mismatch).__name__} does "
-                f"not match the spec's "
-                f"{type(self.spec.mismatch).__name__}; the dense/sparse "
-                f"programming route is baked into the trace")
-        if clamp_mask is not None:
-            clamp_mask = jnp.asarray(clamp_mask)
-            if clamp_values is not None:
-                clamp_values = jnp.asarray(clamp_values, jnp.float32)
-        elif clamp_values is not None:
-            raise ValueError("clamp_values without clamp_mask")
-        if betas is not None:
-            betas = jnp.asarray(betas, jnp.float32)
-        return Program(J_codes=J, h_codes=h, mismatch=mismatch,
-                       clamp_mask=clamp_mask, clamp_values=clamp_values,
-                       betas=betas)
+        with span("session.make_program"):
+            E, n = self.graph.n_edges, self.graph.n_nodes
+            J = jnp.asarray(J_edge_codes)
+            h = jnp.asarray(h_codes)
+            if J.shape != (E,):
+                raise ValueError(
+                    f"J_edge_codes must be edge-list shaped ({E},), got "
+                    f"{J.shape}; scatter dense codes to the edge list first")
+            if h.shape != (n,):
+                raise ValueError(f"h_codes must be ({n},), got {h.shape}")
+            if mismatch is not None and \
+                    type(mismatch) is not type(self.spec.mismatch):
+                raise ValueError(
+                    f"program mismatch type {type(mismatch).__name__} does "
+                    f"not match the spec's "
+                    f"{type(self.spec.mismatch).__name__}; the dense/sparse "
+                    f"programming route is baked into the trace")
+            if clamp_mask is not None:
+                clamp_mask = jnp.asarray(clamp_mask)
+                if clamp_values is not None:
+                    clamp_values = jnp.asarray(clamp_values, jnp.float32)
+            elif clamp_values is not None:
+                raise ValueError("clamp_values without clamp_mask")
+            if betas is not None:
+                betas = jnp.asarray(betas, jnp.float32)
+            return Program(J_codes=J, h_codes=h, mismatch=mismatch,
+                           clamp_mask=clamp_mask, clamp_values=clamp_values,
+                           betas=betas)
 
     def sample_program(
         self,
@@ -519,13 +521,14 @@ class Session:
         Beta priority: explicit ``betas`` arg > ``prog.betas`` > the
         spec's schedule.
         """
-        if betas is None and prog.betas is None:
-            betas = self._betas(None)
-        elif betas is not None:
-            betas = jnp.asarray(betas, jnp.float32)
-        fn = self._fn(("sample_program", collect),
-                      self._build_sample_program, collect)
-        return fn(prog, m, noise_state, betas)
+        with span("session.sample_program"):
+            if betas is None and prog.betas is None:
+                betas = self._betas(None)
+            elif betas is not None:
+                betas = jnp.asarray(betas, jnp.float32)
+            fn = self._fn(("sample_program", collect),
+                          self._build_sample_program, collect)
+            return fn(prog, m, noise_state, betas)
 
     def _build_sample_program(self, collect: bool):
         def impl(prog, m, ns, betas):
@@ -543,7 +546,7 @@ class Session:
 
         # one jit: a changed optional-field structure (clamps, mismatch,
         # program-borne betas) retraces, changed values never do
-        return jax.jit(impl)
+        return named_jit(impl, "sample_program")
 
     def sample_fleet(
         self,
@@ -588,7 +591,8 @@ class Session:
                 backend=backend, interpret=self.interpret,
                 flip_fn=self._flip_fn)
 
-        return jax.jit(jax.vmap(one, in_axes=(0, 0, 0, None)))
+        return named_jit(jax.vmap(one, in_axes=(0, 0, 0, None)),
+                         "sample_fleet")
 
     # ------------------------------------------------------------------
     # sampling closures
@@ -630,8 +634,9 @@ class Session:
                 flip_fn=self._flip_fn)
 
         if clamped:
-            return jax.jit(impl)
-        return jax.jit(lambda chip, m, ns, betas: impl(chip, m, ns, betas))
+            return named_jit(impl, "sample")
+        return named_jit(lambda chip, m, ns, betas: impl(chip, m, ns, betas),
+                         "sample")
 
     def stats(
         self,
@@ -668,8 +673,8 @@ class Session:
                 interpret=self.interpret, flip_fn=self._flip_fn)
 
         if clamped:
-            return jax.jit(impl)
-        return jax.jit(lambda chip, m, ns: impl(chip, m, ns))
+            return named_jit(impl, "stats")
+        return named_jit(lambda chip, m, ns: impl(chip, m, ns), "stats")
 
     def visible_hist(
         self,
@@ -700,7 +705,7 @@ class Session:
                 interpret=self.interpret, clamp_mask=cm, clamp_values=cv,
                 flip_fn=self._flip_fn)
 
-        return jax.jit(impl)
+        return named_jit(impl, "visible_hist")
 
     # ------------------------------------------------------------------
     # contrastive divergence (the in-situ learning closure)
@@ -764,14 +769,15 @@ class Session:
         def build():
             step_mm = self._build_cd_step_mm(cfg, np.asarray(visible_idx),
                                              fleet=True)
-            return jax.jit(jax.vmap(step_mm,
-                                    in_axes=(0, 0, 0, None, 0, 0, 0)))
+            return named_jit(jax.vmap(step_mm,
+                                      in_axes=(0, 0, 0, None, 0, 0, 0)),
+                             "cd_fleet_step")
 
         return self._fn(key, build)
 
     def _build_cd_step(self, cfg, visible_idx):
-        step_mm = jax.jit(self._build_cd_step_mm(cfg, visible_idx,
-                                                 fleet=False))
+        step_mm = named_jit(self._build_cd_step_mm(cfg, visible_idx,
+                                                   fleet=False), "cd_step")
         mm = self.spec.mismatch
 
         def step(Jm, hm, data_vis, m, noise_state, vel):

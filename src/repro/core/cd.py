@@ -36,6 +36,7 @@ import numpy as np
 from repro import api
 from repro.core import energy as energy_mod
 from repro.runtime import fault_tolerance
+from repro.runtime.spans import span
 from repro.core import pbit
 from repro.core.chimera import ChimeraGraph
 from repro.core.hardware import (
@@ -273,16 +274,18 @@ def sample_visible_dist(machine: PBitMachine, Jm, hm,
     folds into the sweep loop, on the fused backends it accumulates inside
     the kernel — the (sweeps, chains, N) trajectory never materializes.
     """
-    session = machine.session(
-        schedule=api.Constant(beta=machine.beta, n_sweeps=sweeps),
-        chains=chains)
-    chip = session.program_master(Jm, hm)
-    k1, k2 = jax.random.split(key)
-    m0 = session.random_spins(k1)
-    noise_state = session.noise_state(k2)
-    counts, _, _ = session.visible_hist(chip, m0, noise_state, visible_idx,
-                                        burn_in)
-    counts = np.asarray(counts, np.float64)
+    with span("cd.eval.program"):
+        session = machine.session(
+            schedule=api.Constant(beta=machine.beta, n_sweeps=sweeps),
+            chains=chains)
+        chip = session.program_master(Jm, hm)
+    with span("cd.eval.hist"):
+        k1, k2 = jax.random.split(key)
+        m0 = session.random_spins(k1)
+        noise_state = session.noise_state(k2)
+        counts, _, _ = session.visible_hist(chip, m0, noise_state,
+                                            visible_idx, burn_in)
+        counts = np.asarray(counts, np.float64)
     return counts / max(counts.sum(), 1.0)
 
 
@@ -316,40 +319,59 @@ def train_cd(
     eval_every: int = 10,
     verbose: bool = False,
 ) -> CDResult:
-    """Full in-situ CD training loop against a target visible distribution."""
-    g = machine.graph
-    n, nv = g.n_nodes, len(visible_idx)
-    session = machine.session(chains=cfg.chains)
-    step = session.make_cd_step(cfg, visible_idx)
+    """Full in-situ CD training loop against a target visible distribution.
 
-    key, k1, k2, k3 = jax.random.split(key, 4)
-    Jm = jnp.zeros((g.n_edges,), jnp.float32)
-    hm = jnp.zeros((n,), jnp.float32)
-    m = session.random_spins(k1)
-    noise_state = session.noise_state(k2)
+    Profiler spans: ``repro.cd.train`` holds ``cd.setup``, one
+    ``cd.epoch`` per epoch (``cd.data``, ``cd.step``, ``cd.sync``), one
+    ``cd.eval`` per evaluation (``cd.eval.program``, ``cd.eval.hist``,
+    ``cd.eval.kl``) and ``cd.result``.
+    """
+    with span("cd.train"):
+        with span("cd.setup"):
+            g = machine.graph
+            n, nv = g.n_nodes, len(visible_idx)
+            session = machine.session(chains=cfg.chains)
+            step = session.make_cd_step(cfg, visible_idx)
 
-    # enumerate visible configs for sampling data from the target dist
-    codes = energy_mod.all_states(nv)  # (2^nv, nv) ±1, code order
-    vel = (jnp.zeros((g.n_edges,), jnp.float32),
-           jnp.zeros((n,), jnp.float32))
-    kl_hist, met_hist = [], []
-    for epoch in range(cfg.epochs):
-        key, kd, ke = jax.random.split(key, 3)
-        idx = jax.random.choice(
-            kd, codes.shape[0], (cfg.chains,), p=jnp.asarray(target_dist))
-        data_vis = jnp.asarray(codes)[idx]
-        Jm, hm, m, noise_state, vel, metrics = step(Jm, hm, data_vis, m,
-                                                    noise_state, vel)
-        met_hist.append({k: float(v) for k, v in metrics.items()})
-        if (epoch + 1) % eval_every == 0 or epoch == cfg.epochs - 1:
-            emp = sample_visible_dist(machine, Jm, hm, visible_idx, ke)
-            kl = energy_mod.kl_divergence(np.asarray(target_dist), emp)
-            kl_hist.append((epoch + 1, kl))
-            if verbose:
-                print(f"epoch {epoch+1:4d}  KL={kl:.4f}  "
-                      f"corr_err={met_hist[-1]['corr_err']:.4f}")
-    return CDResult(np.asarray(Jm), np.asarray(hm), kl_hist, met_hist,
-                    edges=np.asarray(g.edges), n_nodes=n)
+            key, k1, k2, k3 = jax.random.split(key, 4)
+            Jm = jnp.zeros((g.n_edges,), jnp.float32)
+            hm = jnp.zeros((n,), jnp.float32)
+            m = session.random_spins(k1)
+            noise_state = session.noise_state(k2)
+
+            # the visible configs, to sample data from the target dist
+            codes = energy_mod.all_states(nv)  # (2^nv, nv) ±1, code order
+            vel = (jnp.zeros((g.n_edges,), jnp.float32),
+                   jnp.zeros((n,), jnp.float32))
+        kl_hist, met_hist = [], []
+        for epoch in range(cfg.epochs):
+            with span("cd.epoch", epoch=epoch):
+                with span("cd.data"):
+                    key, kd, ke = jax.random.split(key, 3)
+                    idx = jax.random.choice(
+                        kd, codes.shape[0], (cfg.chains,),
+                        p=jnp.asarray(target_dist))
+                    data_vis = jnp.asarray(codes)[idx]
+                with span("cd.step"):
+                    Jm, hm, m, noise_state, vel, metrics = step(
+                        Jm, hm, data_vis, m, noise_state, vel)
+                with span("cd.sync"):
+                    met_hist.append({k: float(v)
+                                     for k, v in metrics.items()})
+            if (epoch + 1) % eval_every == 0 or epoch == cfg.epochs - 1:
+                with span("cd.eval", epoch=epoch):
+                    emp = sample_visible_dist(machine, Jm, hm, visible_idx,
+                                              ke)
+                    with span("cd.eval.kl"):
+                        kl = energy_mod.kl_divergence(
+                            np.asarray(target_dist), emp)
+                kl_hist.append((epoch + 1, kl))
+                if verbose:
+                    print(f"epoch {epoch+1:4d}  KL={kl:.4f}  "
+                          f"corr_err={met_hist[-1]['corr_err']:.4f}")
+        with span("cd.result"):
+            return CDResult(np.asarray(Jm), np.asarray(hm), kl_hist,
+                            met_hist, edges=np.asarray(g.edges), n_nodes=n)
 
 
 # -- crash-safe training ---------------------------------------------------

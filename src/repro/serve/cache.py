@@ -24,15 +24,14 @@ make reuse systematic:
   needs no per-program state at all — dispatch is "scatter codes, call".
   The service holds one bucket-sized spec per fingerprint and evicts
   least-recently-used Sessions under memory pressure.  Hit/miss/
-  eviction counters feed the `serving` benchmark's compile-cache row;
-  its `program_swap` vs `recompile` split measures what the operand
-  design buys.
+  eviction counters surface in the service's `healthz()`; the
+  `compile_cache` row of `benchmarks/bench_serving.py` times what the
+  operand design buys (`program_swap` vs `recompile`).
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from collections import OrderedDict
 from typing import Any, Callable, Optional
 
@@ -154,7 +153,6 @@ class CacheEntry:
     spec: Any                    # api.SamplerSpec (bucket-sized)
     embeddable: ChimeraGraph     # the bucket graph
     meshed: bool                 # compiled against a device mesh?
-    build_s: float               # wall-clock spent constructing + warming
 
 
 class SessionCache:
@@ -186,10 +184,7 @@ class SessionCache:
         if entry is not None:
             return entry
         self.misses += 1
-        t0 = time.monotonic()
         entry = build()
-        if not entry.build_s:
-            entry.build_s = time.monotonic() - t0
         self._entries[fingerprint] = entry
         while len(self._entries) > self.capacity:
             self._entries.popitem(last=False)

@@ -62,6 +62,7 @@ from repro.core.chimera import ChimeraGraph
 from repro.core.distributed import surviving_mesh
 from repro.core.hardware import HardwareConfig, sample_mismatch_sparse
 from repro.runtime.fault_tolerance import StragglerWatchdog, retry_step
+from repro.runtime.spans import span
 from repro.serve.cache import (
     DEFAULT_BUCKETS,
     CacheEntry,
@@ -134,17 +135,19 @@ class RequestResult:
     launch_key: Optional[np.ndarray] = None  # raw key data: full replay
                                              # recipe (tests rebuild the
                                              # launch from it)
+    request_id: int = -1              # the ``request`` of its submit span
 
 
 class Ticket:
     """Handle returned by `submit`; resolved by `pump`/`drain`."""
 
-    def __init__(self, req: SampleRequest, *, deadline: Optional[float],
-                 t_admitted: float, bshape: tuple[int, int],
-                 emb: Embedding, Jb: np.ndarray, hb: np.ndarray,
-                 betas: np.ndarray, bucket_mask: Optional[np.ndarray],
-                 digest: str):
+    def __init__(self, req: SampleRequest, *, request_id: int,
+                 deadline: Optional[float], t_admitted: float,
+                 bshape: tuple[int, int], emb: Embedding, Jb: np.ndarray,
+                 hb: np.ndarray, betas: np.ndarray,
+                 bucket_mask: Optional[np.ndarray], digest: str):
         self.req = req
+        self.request_id = request_id
         self.deadline = deadline
         self.t_admitted = t_admitted
         self.bshape = bshape
@@ -279,6 +282,7 @@ class SamplerService:
         self._queue: deque[Ticket] = deque()
         self._dead: set[int] = set()
         self._launch_seq = 0
+        self._request_seq = 0
         self._bucket_graphs: dict[tuple, ChimeraGraph] = {}
         self._bucket_mismatch: dict[tuple, Any] = {}
         self._embeddings: dict[tuple, Embedding] = {}
@@ -287,56 +291,62 @@ class SamplerService:
     # admission
     # ------------------------------------------------------------------
     def submit(self, req: SampleRequest) -> Ticket:
-        now = self._clock()
-        if not self.breaker.allow(req.tenant, now):
-            self.metrics["rejected_breaker"] += 1
-            raise CircuitOpenError(
-                f"tenant {req.tenant!r}: circuit open after repeated "
-                f"deadline misses; retry after cooldown")
-        if len(self._queue) >= self.max_queue:
-            self.metrics["rejected_backpressure"] += 1
-            raise AdmissionError(
-                f"admission queue full ({self.max_queue}); apply "
-                f"backpressure upstream and retry")
-        if not (1 <= req.chains <= self.capacity_chains):
-            raise ValueError(
-                f"chains={req.chains} out of range [1, "
-                f"{self.capacity_chains}] (capacity_chains)")
-        bshape = bucket_shape(req.graph, self.buckets)
-        emb = self._embedding(req.graph, bshape)
-        J = np.asarray(req.J_codes, np.int32)
-        h = np.asarray(req.h_codes, np.int32)
-        if J.shape != (req.graph.edges.shape[0],):
-            raise ValueError(
-                f"J_codes shape {J.shape} != (E,)="
-                f"({req.graph.edges.shape[0]},)")
-        if h.shape != (req.graph.n_nodes,):
-            raise ValueError(
-                f"h_codes shape {h.shape} != (N,)=({req.graph.n_nodes},)")
-        Jb, hb = embed_program(emb, J, h)
-        betas = self._canon_betas(req)
-        bucket_mask = None
-        if req.clamp_mask is not None:
-            cm = np.asarray(req.clamp_mask, bool)
-            if cm.shape != (req.graph.n_nodes,):
+        # every submit takes an id, refused ones too: the ids of
+        # admitted requests rise but may skip
+        request_id = self._request_seq
+        self._request_seq += 1
+        with span("serve.submit", request=request_id):
+            now = self._clock()
+            if not self.breaker.allow(req.tenant, now):
+                self.metrics["rejected_breaker"] += 1
+                raise CircuitOpenError(
+                    f"tenant {req.tenant!r}: circuit open after repeated "
+                    f"deadline misses; retry after cooldown")
+            if len(self._queue) >= self.max_queue:
+                self.metrics["rejected_backpressure"] += 1
+                raise AdmissionError(
+                    f"admission queue full ({self.max_queue}); apply "
+                    f"backpressure upstream and retry")
+            if not (1 <= req.chains <= self.capacity_chains):
                 raise ValueError(
-                    f"clamp_mask shape {cm.shape} != (N,)")
-            cv = np.asarray(req.clamp_values, np.float32)
-            if cv.shape != (req.chains, req.graph.n_nodes):
+                    f"chains={req.chains} out of range [1, "
+                    f"{self.capacity_chains}] (capacity_chains)")
+            bshape = bucket_shape(req.graph, self.buckets)
+            emb = self._embedding(req.graph, bshape)
+            J = np.asarray(req.J_codes, np.int32)
+            h = np.asarray(req.h_codes, np.int32)
+            if J.shape != (req.graph.edges.shape[0],):
                 raise ValueError(
-                    f"clamp_values shape {cv.shape} != (chains, N)="
-                    f"({req.chains}, {req.graph.n_nodes})")
-            bucket_mask = np.zeros(emb.bucket.n_nodes, bool)
-            bucket_mask[emb.node_map] = cm
-        timeout = (req.timeout_s if req.timeout_s is not None
-                   else self.default_timeout_s)
-        ticket = Ticket(
-            req, deadline=now + timeout, t_admitted=now, bshape=bshape,
-            emb=emb, Jb=Jb, hb=hb, betas=betas, bucket_mask=bucket_mask,
-            digest=program_digest(bshape, Jb, hb, betas, bucket_mask))
-        self._queue.append(ticket)
-        self.metrics["admitted"] += 1
-        return ticket
+                    f"J_codes shape {J.shape} != (E,)="
+                    f"({req.graph.edges.shape[0]},)")
+            if h.shape != (req.graph.n_nodes,):
+                raise ValueError(
+                    f"h_codes shape {h.shape} != (N,)=({req.graph.n_nodes},)")
+            Jb, hb = embed_program(emb, J, h)
+            betas = self._canon_betas(req)
+            bucket_mask = None
+            if req.clamp_mask is not None:
+                cm = np.asarray(req.clamp_mask, bool)
+                if cm.shape != (req.graph.n_nodes,):
+                    raise ValueError(
+                        f"clamp_mask shape {cm.shape} != (N,)")
+                cv = np.asarray(req.clamp_values, np.float32)
+                if cv.shape != (req.chains, req.graph.n_nodes):
+                    raise ValueError(
+                        f"clamp_values shape {cv.shape} != (chains, N)="
+                        f"({req.chains}, {req.graph.n_nodes})")
+                bucket_mask = np.zeros(emb.bucket.n_nodes, bool)
+                bucket_mask[emb.node_map] = cm
+            timeout = (req.timeout_s if req.timeout_s is not None
+                       else self.default_timeout_s)
+            ticket = Ticket(
+                req, request_id=request_id, deadline=now + timeout,
+                t_admitted=now, bshape=bshape, emb=emb, Jb=Jb, hb=hb,
+                betas=betas, bucket_mask=bucket_mask,
+                digest=program_digest(bshape, Jb, hb, betas, bucket_mask))
+            self._queue.append(ticket)
+            self.metrics["admitted"] += 1
+            return ticket
 
     def _canon_betas(self, req: SampleRequest) -> np.ndarray:
         if req.betas is not None:
@@ -417,12 +427,10 @@ class SamplerService:
         fp = api.spec_fingerprint(spec)
 
         def build() -> CacheEntry:
-            t0 = time.monotonic()
-            session = api.Session(spec)
-            return CacheEntry(session=session, spec=spec,
-                              embeddable=spec.graph,
-                              meshed=spec.mesh is not None,
-                              build_s=time.monotonic() - t0)
+            with span("serve.build"):
+                return CacheEntry(session=api.Session(spec), spec=spec,
+                                  embeddable=spec.graph,
+                                  meshed=spec.mesh is not None)
 
         return fp, self.cache.get_or_build(fp, build)
 
@@ -433,11 +441,13 @@ class SamplerService:
         """Form one launch group from the queue head, execute it, resolve
         its tickets.  Returns the number of requests resolved (including
         queue-expired ones)."""
-        batch, expired = self._next_batch()
-        if not batch:
-            return expired
-        self._execute(batch)
-        return expired + len(batch)
+        with span("serve.pump"):
+            with span("serve.batch"):
+                batch, expired = self._next_batch()
+            if not batch:
+                return expired
+            self._execute(batch)
+            return expired + len(batch)
 
     def drain(self) -> int:
         """Pump until the queue is empty; returns requests resolved."""
@@ -477,7 +487,7 @@ class SamplerService:
             status="deadline_exceeded", tenant=t.req.tenant, spins=None,
             error="deadline passed while queued",
             t_admitted=t.t_admitted, t_finished=now,
-            queue_s=now - t.t_admitted))
+            queue_s=now - t.t_admitted, request_id=t.request_id))
 
     def _execute(self, batch: list[Ticket]) -> None:
         seq = self._launch_seq
@@ -490,58 +500,62 @@ class SamplerService:
             attempts[0] += 1
             return self._attempt(batch, seq, key)
 
-        n_dev = 0 if self.mesh is None else int(
-            np.prod([self.mesh.shape[a] for a in self.mesh.axis_names]))
-        replays = 0
-        while True:
-            try:
-                m, fp, entry = retry_step(
-                    attempt, max_retries=self.max_retries,
-                    backoff_s=self.backoff_s,
-                    max_backoff_s=self.max_backoff_s,
-                    rng=self._rng, sleep=self._sleep)
-                break
-            except ShardLostError as e:
-                replays += 1
-                self._degrade(e.dead)
-                if replays > n_dev + 1:   # can't happen: ladder is finite
-                    now = self._clock()
-                    for t in batch:
-                        t._resolve(RequestResult(
-                            status="failed", tenant=t.req.tenant,
-                            spins=None, error=str(e),
-                            t_admitted=t.t_admitted, t_finished=now))
-                    self.metrics["failed"] += len(batch)
-                    return
-        now = self._clock()
-        exec_s = now - t_start
-        self.metrics["launches"] += 1
-        self.metrics["launch_attempts_total"] += attempts[0]
-        if attempts[0] > 1:
-            self.metrics["transient_retries"] += attempts[0] - 1
-        if replays:
-            self.metrics["replays"] += replays
-        if self.watchdog.observe(seq, exec_s):
-            self.metrics["stragglers_flagged"] += 1
-        degraded = bool(self._dead)
-        off = 0
-        for t in batch:
-            spins = np.asarray(
-                m[off:off + t.req.chains][:, t.emb.node_map])
-            missed = now > t.deadline
-            self.breaker.record(t.req.tenant, ok=not missed, now=now)
-            self.metrics["completed"] += 1
-            if missed:
-                self.metrics["deadline_missed_exec"] += 1
-            t._resolve(RequestResult(
-                status="ok", tenant=t.req.tenant, spins=spins,
-                degraded=degraded, deadline_missed=missed,
-                t_admitted=t.t_admitted, t_finished=now,
-                queue_s=t_start - t.t_admitted, exec_s=exec_s,
-                attempts=attempts[0], launch_seq=seq, chain_offset=off,
-                bucket_shape=t.bshape, bucket_fingerprint=fp,
-                launch_key=np.asarray(key)))
-            off += t.req.chains
+        with span("serve.launch", seq=seq, requests=len(batch),
+                  chains=sum(t.req.chains for t in batch)):
+            n_dev = 0 if self.mesh is None else int(
+                np.prod([self.mesh.shape[a] for a in self.mesh.axis_names]))
+            replays = 0
+            while True:
+                try:
+                    m, fp, entry = retry_step(
+                        attempt, max_retries=self.max_retries,
+                        backoff_s=self.backoff_s,
+                        max_backoff_s=self.max_backoff_s,
+                        rng=self._rng, sleep=self._sleep)
+                    break
+                except ShardLostError as e:
+                    replays += 1
+                    self._degrade(e.dead)
+                    if replays > n_dev + 1:  # can't: the ladder is finite
+                        now = self._clock()
+                        for t in batch:
+                            t._resolve(RequestResult(
+                                status="failed", tenant=t.req.tenant,
+                                spins=None, error=str(e),
+                                t_admitted=t.t_admitted, t_finished=now,
+                                request_id=t.request_id))
+                        self.metrics["failed"] += len(batch)
+                        return
+        with span("serve.resolve"):
+            now = self._clock()
+            exec_s = now - t_start
+            self.metrics["launches"] += 1
+            self.metrics["launch_attempts_total"] += attempts[0]
+            if attempts[0] > 1:
+                self.metrics["transient_retries"] += attempts[0] - 1
+            if replays:
+                self.metrics["replays"] += replays
+            if self.watchdog.observe(seq, exec_s):
+                self.metrics["stragglers_flagged"] += 1
+            degraded = bool(self._dead)
+            off = 0
+            for t in batch:
+                spins = np.asarray(
+                    m[off:off + t.req.chains][:, t.emb.node_map])
+                missed = now > t.deadline
+                self.breaker.record(t.req.tenant, ok=not missed, now=now)
+                self.metrics["completed"] += 1
+                if missed:
+                    self.metrics["deadline_missed_exec"] += 1
+                t._resolve(RequestResult(
+                    status="ok", tenant=t.req.tenant, spins=spins,
+                    degraded=degraded, deadline_missed=missed,
+                    t_admitted=t.t_admitted, t_finished=now,
+                    queue_s=t_start - t.t_admitted, exec_s=exec_s,
+                    attempts=attempts[0], launch_seq=seq, chain_offset=off,
+                    bucket_shape=t.bshape, bucket_fingerprint=fp,
+                    launch_key=np.asarray(key), request_id=t.request_id))
+                off += t.req.chains
 
     def _attempt(self, batch: list[Ticket], seq: int, key):
         if self.injector is not None:
@@ -551,23 +565,29 @@ class SamplerService:
                 self._sleep(delay)
         self._check_shards()
         head = batch[0]
-        fp, entry = self._entry_for(head.bshape)
+        with span("serve.entry"):
+            fp, entry = self._entry_for(head.bshape)
         bg = entry.embeddable
-        km, kn = jax.random.split(key)
-        m0 = pbit.random_spins(km, self.capacity_chains, bg.n_nodes)
-        ns = entry.session.noise_state(kn)
-        cm, cv = self._assemble_clamps(batch, bg)
-        # scatter codes, call: the program (codes + clamps) is a runtime
-        # operand of the bucket Session's one compiled executable — no
-        # per-digest chip cache, no retrace on a new tenant problem
-        prog = entry.session.make_program(
-            jnp.asarray(head.Jb), jnp.asarray(head.hb),
-            clamp_mask=cm, clamp_values=cv)
-        m, _, _ = entry.session.sample_program(
-            prog, m0, ns, jnp.asarray(head.betas))
+        with span("serve.inputs"):
+            km, kn = jax.random.split(key)
+            m0 = pbit.random_spins(km, self.capacity_chains, bg.n_nodes)
+            ns = entry.session.noise_state(kn)
+            cm, cv = self._assemble_clamps(batch, bg)
+            # scatter codes, call: the program (codes + clamps) is a
+            # runtime operand of the bucket Session's one compiled
+            # executable — no per-digest chip cache, no retrace on a new
+            # tenant problem
+            prog = entry.session.make_program(
+                jnp.asarray(head.Jb), jnp.asarray(head.hb),
+                clamp_mask=cm, clamp_values=cv)
+            betas = jnp.asarray(head.betas)
+        with span("serve.dispatch"):
+            m, _, _ = entry.session.sample_program(prog, m0, ns, betas)
         # materialize on the host *inside* the attempt: a shard dying
         # mid-launch surfaces here, where the replay machinery can see it
-        return np.asarray(m), fp, entry
+        with span("serve.fetch"):
+            m = np.asarray(m)
+        return m, fp, entry
 
     def _assemble_clamps(self, batch: list[Ticket], bg: ChimeraGraph):
         head = batch[0]

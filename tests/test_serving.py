@@ -189,7 +189,7 @@ class TestEmbedding:
 class TestSessionCache:
     def _entry(self, meshed=False):
         return CacheEntry(session=None, spec=None, embeddable=None,
-                          meshed=meshed, build_s=0.01)
+                          meshed=meshed)
 
     def test_lru_eviction_and_counters(self):
         c = SessionCache(capacity=2)
