@@ -36,6 +36,7 @@ from repro.api.spec import (
     spec_fingerprint,
 )
 from repro.api.session import (
+    CD_METRICS,
     Session,
     SessionState,
     program,
@@ -49,6 +50,7 @@ __all__ = [
     "SPARSE_BACKENDS",
     "Schedule", "Constant", "Anneal", "Tempered",
     "Partition", "Sync", "SamplerSpec", "Session", "SessionState",
+    "CD_METRICS",
     "Faults", "sample_faults", "Program", "stack_programs",
     "program", "program_chip", "program_edges", "program_master",
     "dense_vmem_feasible", "resolve_backend", "resolve_interpret",

@@ -54,6 +54,22 @@ from repro.runtime.spans import named_jit, span
 # result is bit-identical to K sequential single-program calls
 _FLEET_BACKEND = {"fused": "ref", "fused_sparse": "sparse", "pallas": "ref"}
 
+# the CD step's metrics, in the order `make_cd_epoch` stacks them
+CD_METRICS = ("corr_err", "mean_err", "update_skipped")
+
+
+def cd_data_draw(key: jax.Array, p: jax.Array, codes: jax.Array,
+                 chains: int):
+    """One CD epoch's key split and data batch: (key', ke, data_vis).
+
+    ``key`` splits into (key', kd, ke); ``chains`` rows of ``codes`` are
+    drawn with probabilities ``p`` under ``kd``; ``ke`` is left for the
+    epoch's evaluation.
+    """
+    key, kd, ke = jax.random.split(key, 3)
+    idx = jax.random.choice(kd, codes.shape[0], (chains,), p=p)
+    return key, ke, codes[idx]
+
 
 class SessionState(NamedTuple):
     """Spins + noise state, the carry every closure threads explicitly."""
@@ -693,6 +709,10 @@ class Session:
         return fn(chip, m, noise_state, betas)
 
     def _build_hist(self, visible_idx, burn_in):
+        return named_jit(self._hist_impl(visible_idx, burn_in),
+                         "visible_hist")
+
+    def _hist_impl(self, visible_idx, burn_in):
         def impl(chip, m, ns, betas):
             m, cm, cv = self._merge_faults(m, None, None)
             if self._engine is not None:
@@ -705,7 +725,43 @@ class Session:
                 interpret=self.interpret, clamp_mask=cm, clamp_values=cv,
                 flip_fn=self._flip_fn)
 
-        return named_jit(impl, "visible_hist")
+        return impl
+
+    def master_visible_hist(
+        self,
+        Jm: jax.Array,
+        hm: jax.Array,
+        key: jax.Array,
+        visible_idx: np.ndarray,
+        burn_in: int,
+    ) -> jax.Array:
+        """Program float masters and histogram the visible spins from
+        fresh chains under the spec's schedule: counts[2^nv], in one
+        dispatch.
+
+        Equals `program_master`, then ``split(key)`` into the chains'
+        spins (`random_spins`) and noise state (`noise_state`), then
+        `visible_hist`, bit for bit; the mismatch draw enters as an
+        operand, as in `make_cd_step`.
+        """
+        vis_key = tuple(int(i) for i in np.asarray(visible_idx))
+        fn = self._fn(("master_hist", vis_key, burn_in),
+                      self._build_master_hist, np.asarray(visible_idx),
+                      burn_in)
+        return fn(self.spec.mismatch, Jm, hm, key, self._betas(None))
+
+    def _build_master_hist(self, visible_idx, burn_in):
+        hist = self._hist_impl(visible_idx, burn_in)
+
+        def impl(mismatch, Jm, hm, key, betas):
+            chip = program_master(self.spec.replace(mismatch=mismatch),
+                                  Jm, hm, tables=self._nbr)
+            k1, k2 = jax.random.split(key)
+            counts, _, _ = hist(chip, self.random_spins(k1),
+                                self.noise_state(k2), betas)
+            return counts
+
+        return named_jit(impl, "cd_eval")
 
     # ------------------------------------------------------------------
     # contrastive divergence (the in-situ learning closure)
@@ -726,17 +782,36 @@ class Session:
         `make_cd_fleet_step` and of zero-retrace hardware-in-the-loop
         epochs.
         """
+        return self._fn(self._cd_key("cd_step", cfg, visible_idx),
+                        self._build_cd_step, cfg, np.asarray(visible_idx))
+
+    def make_cd_epoch(self, cfg, visible_idx: np.ndarray):
+        """Build the jitted CD epoch: the data draw and `make_cd_step`'s
+        update in one dispatch.
+
+        Returns epoch(key, p, codes, Jm, hm, m, noise_state, vel) ->
+        (key', ke, Jm, hm, m, noise_state, vel, metrics).  The epoch
+        splits ``key`` into (key', kd, ke), draws ``chains`` rows of
+        ``codes`` (the 2^nv visible configurations) with probabilities
+        ``p`` under ``kd``, and runs the step on them; ``ke`` is the
+        epoch's evaluation key, and ``metrics`` the step's metrics
+        stacked in `CD_METRICS` order, left on the device.
+        ``epoch.with_mismatch`` is the raw (mismatch, key, ...) entry.
+        """
+        return self._fn(self._cd_key("cd_epoch", cfg, visible_idx),
+                        self._build_cd_epoch, cfg, np.asarray(visible_idx))
+
+    def _cd_key(self, kind, cfg, visible_idx) -> tuple:
+        """The cache key of a CD builder; checks the chain count."""
         if cfg.chains != self.spec.chains:
             raise ValueError(
                 f"CDConfig.chains={cfg.chains} but this Session was "
                 f"compiled for chains={self.spec.chains}; build the "
                 f"session with chains=cfg.chains")
-        key = ("cd_step", cfg.lr, cfg.cd_k, cfg.pos_sweeps, cfg.burn_in,
-               cfg.h_lr_scale, cfg.weight_decay, cfg.persistent,
-               cfg.momentum,
-               tuple(int(i) for i in np.asarray(visible_idx)))
-        return self._fn(key, self._build_cd_step, cfg,
-                        np.asarray(visible_idx))
+        return (kind, cfg.lr, cfg.cd_k, cfg.pos_sweeps, cfg.burn_in,
+                cfg.h_lr_scale, cfg.weight_decay, cfg.persistent,
+                cfg.momentum,
+                tuple(int(i) for i in np.asarray(visible_idx)))
 
     def make_cd_fleet_step(self, cfg, visible_idx: np.ndarray):
         """Build the K-replica hardware-aware CD step: one executable,
@@ -756,15 +831,7 @@ class Session:
             raise ValueError(
                 "fleet CD runs on single-device Sessions; a sharded mesh "
                 "already owns the device axis — run one fleet per device")
-        if cfg.chains != self.spec.chains:
-            raise ValueError(
-                f"CDConfig.chains={cfg.chains} but this Session was "
-                f"compiled for chains={self.spec.chains}; build the "
-                f"session with chains=cfg.chains")
-        key = ("cd_fleet", cfg.lr, cfg.cd_k, cfg.pos_sweeps, cfg.burn_in,
-               cfg.h_lr_scale, cfg.weight_decay, cfg.persistent,
-               cfg.momentum,
-               tuple(int(i) for i in np.asarray(visible_idx)))
+        key = self._cd_key("cd_fleet", cfg, visible_idx)
 
         def build():
             step_mm = self._build_cd_step_mm(cfg, np.asarray(visible_idx),
@@ -785,6 +852,25 @@ class Session:
 
         step.with_mismatch = step_mm
         return step
+
+    def _build_cd_epoch(self, cfg, visible_idx):
+        step_mm = self._build_cd_step_mm(cfg, visible_idx, fleet=False)
+
+        def epoch_mm(mismatch, key, p, codes, Jm, hm, m, noise_state, vel):
+            key, ke, data_vis = cd_data_draw(key, p, codes, cfg.chains)
+            Jm, hm, m, noise_state, vel, metrics = step_mm(
+                mismatch, Jm, hm, data_vis, m, noise_state, vel)
+            return (key, ke, Jm, hm, m, noise_state, vel,
+                    jnp.stack([metrics[k] for k in CD_METRICS]))
+
+        epoch_mm = named_jit(epoch_mm, "cd_epoch")
+        mm = self.spec.mismatch
+
+        def epoch(key, p, codes, Jm, hm, m, noise_state, vel):
+            return epoch_mm(mm, key, p, codes, Jm, hm, m, noise_state, vel)
+
+        epoch.with_mismatch = epoch_mm
+        return epoch
 
     def _build_cd_step_mm(self, cfg, visible_idx, *, fleet: bool):
         from repro.core.hardware import WMAX, WMIN

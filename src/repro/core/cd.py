@@ -273,19 +273,15 @@ def sample_visible_dist(machine: PBitMachine, Jm, hm,
     histogram streams (`Session.visible_hist`): on the scan backends it
     folds into the sweep loop, on the fused backends it accumulates inside
     the kernel — the (sweeps, chains, N) trajectory never materializes.
+    Programming, the chains' initial state from ``key`` and the histogram
+    are one compiled call (`Session.master_visible_hist`).
     """
-    with span("cd.eval.program"):
+    with span("cd.eval.hist"):
         session = machine.session(
             schedule=api.Constant(beta=machine.beta, n_sweeps=sweeps),
             chains=chains)
-        chip = session.program_master(Jm, hm)
-    with span("cd.eval.hist"):
-        k1, k2 = jax.random.split(key)
-        m0 = session.random_spins(k1)
-        noise_state = session.noise_state(k2)
-        counts, _, _ = session.visible_hist(chip, m0, noise_state,
-                                            visible_idx, burn_in)
-        counts = np.asarray(counts, np.float64)
+        counts = np.asarray(session.master_visible_hist(
+            Jm, hm, key, visible_idx, burn_in), np.float64)
     return counts / max(counts.sum(), 1.0)
 
 
@@ -321,9 +317,16 @@ def train_cd(
 ) -> CDResult:
     """Full in-situ CD training loop against a target visible distribution.
 
+    Each epoch is one compiled dispatch (`Session.make_cd_epoch`: the data
+    draw and the update), and so is each evaluation
+    (`sample_visible_dist`); the host never waits on the device between
+    evaluations.  The epochs' metrics stay on the device until the next
+    evaluation fetches them all at once; the last epoch always evaluates,
+    so the run ends with none left.
+
     Profiler spans: ``repro.cd.train`` holds ``cd.setup``, one
-    ``cd.epoch`` per epoch (``cd.data``, ``cd.step``, ``cd.sync``), one
-    ``cd.eval`` per evaluation (``cd.eval.program``, ``cd.eval.hist``,
+    ``cd.epoch`` per epoch (``cd.step``), one ``cd.eval`` per evaluation
+    (``cd.sync``, the one fetch of the metrics, ``cd.eval.hist`` and
     ``cd.eval.kl``) and ``cd.result``.
     """
     with span("cd.train"):
@@ -331,7 +334,7 @@ def train_cd(
             g = machine.graph
             n, nv = g.n_nodes, len(visible_idx)
             session = machine.session(chains=cfg.chains)
-            step = session.make_cd_step(cfg, visible_idx)
+            epoch_fn = session.make_cd_epoch(cfg, visible_idx)
 
             key, k1, k2, k3 = jax.random.split(key, 4)
             Jm = jnp.zeros((g.n_edges,), jnp.float32)
@@ -339,27 +342,26 @@ def train_cd(
             m = session.random_spins(k1)
             noise_state = session.noise_state(k2)
 
-            # the visible configs, to sample data from the target dist
-            codes = energy_mod.all_states(nv)  # (2^nv, nv) ±1, code order
+            # the visible configs (code order) and their target
+            # probabilities, moved to the device once per run
+            codes = jnp.asarray(energy_mod.all_states(nv))
+            p = jnp.asarray(target_dist, jnp.float32)
             vel = (jnp.zeros((g.n_edges,), jnp.float32),
                    jnp.zeros((n,), jnp.float32))
-        kl_hist, met_hist = [], []
+        kl_hist, met_hist, pending = [], [], []
         for epoch in range(cfg.epochs):
             with span("cd.epoch", epoch=epoch):
-                with span("cd.data"):
-                    key, kd, ke = jax.random.split(key, 3)
-                    idx = jax.random.choice(
-                        kd, codes.shape[0], (cfg.chains,),
-                        p=jnp.asarray(target_dist))
-                    data_vis = jnp.asarray(codes)[idx]
                 with span("cd.step"):
-                    Jm, hm, m, noise_state, vel, metrics = step(
-                        Jm, hm, data_vis, m, noise_state, vel)
-                with span("cd.sync"):
-                    met_hist.append({k: float(v)
-                                     for k, v in metrics.items()})
+                    key, ke, Jm, hm, m, noise_state, vel, metrics = \
+                        epoch_fn(key, p, codes, Jm, hm, m, noise_state, vel)
+                pending.append(metrics)
             if (epoch + 1) % eval_every == 0 or epoch == cfg.epochs - 1:
                 with span("cd.eval", epoch=epoch):
+                    with span("cd.sync"):
+                        met_hist += [
+                            dict(zip(api.CD_METRICS, map(float, row)))
+                            for row in jax.device_get(pending)]
+                        pending = []
                     emp = sample_visible_dist(machine, Jm, hm, visible_idx,
                                               ke)
                     with span("cd.eval.kl"):

@@ -98,13 +98,14 @@ def _serve_run():
     return [t.result() for t in tickets]
 
 
-def _cd_run():
+def _cd_run(machine=None, epochs=2, eval_every=1):
     g = make_chimera(1, 1)
     task = tasks.and_gate_task(g)
     cfg = CDConfig(lr=6.0, cd_k=2, pos_sweeps=2, burn_in=1, chains=8,
-                   epochs=2)
-    return train_cd(_machine(), task.visible_idx, task.target_dist, cfg,
-                    jax.random.PRNGKey(9), eval_every=1)
+                   epochs=epochs)
+    return train_cd(machine or _machine(), task.visible_idx,
+                    task.target_dist, cfg, jax.random.PRNGKey(9),
+                    eval_every=eval_every)
 
 
 def test_sample_program_spans(tmp_path):
@@ -140,23 +141,37 @@ def test_serve_spans_nest_and_name_the_request_and_launch(tmp_path):
 
 
 def test_cd_spans_nest_per_epoch_and_evaluation(tmp_path):
-    _, spans = _record(tmp_path, _cd_run)
+    # 4 epochs, evaluated after the 2nd and the 4th: the host fetches the
+    # metrics only there, never inside an epoch
+    _, spans = _record(tmp_path, lambda: _cd_run(epochs=4, eval_every=2))
     (train,) = _named(spans, "cd.train")
     assert all(_within(s, train) for s in spans
                if s.name.startswith("cd."))
     _one_within(spans, "cd.setup", train)
     _one_within(spans, "cd.result", train)
     epochs = _named(spans, "cd.epoch")
-    assert [s.meta["epoch"] for s in epochs] == [0, 1]
+    assert [s.meta["epoch"] for s in epochs] == [0, 1, 2, 3]
     for ep in epochs:
-        for child in ("cd.data", "cd.step", "cd.sync"):
-            _one_within(spans, child, ep)
+        _one_within(spans, "cd.step", ep)
+        assert not [s for s in _named(spans, "cd.sync") if _within(s, ep)]
     evals = _named(spans, "cd.eval")
-    assert len(evals) == 2
+    assert [s.meta["epoch"] for s in evals] == [1, 3]
     for ev in evals:
-        for child in ("cd.eval.program", "cd.eval.hist", "cd.eval.kl"):
+        for child in ("cd.sync", "cd.eval.hist", "cd.eval.kl"):
             _one_within(spans, child, ev)
         assert not any(_within(ev, ep) for ep in epochs)
+    assert len(_named(spans, "cd.sync")) == len(evals)
+    assert not _named(spans, "cd.data")
+
+
+def test_cd_second_run_on_a_machine_retraces_nothing(tmp_path):
+    mach = _machine()
+    first = _cd_run(mach)
+    second, spans = _record(tmp_path, lambda: _cd_run(mach))
+    assert _named(spans, "cd.train")
+    assert not [s for s in spans if s.name.startswith("retrace.")]
+    np.testing.assert_equal([second.J_edges, second.hm],
+                            [first.J_edges, first.hm])
 
 
 def test_retrace_span_once_per_new_shape(tmp_path):
@@ -198,6 +213,9 @@ def lowerings():
     f32 = jnp.float32
     cd_args = (jnp.zeros(e, f32), jnp.zeros(n, f32), jnp.ones((b, 3), f32),
                m, ns, (jnp.zeros(e, f32), jnp.zeros(n, f32)))
+    key = jax.random.PRNGKey(5)
+    p = jnp.full((8,), 1 / 8, f32)
+    codes = jnp.ones((8, 3), f32)
 
     def fleet(x):
         return jnp.stack([x] * k)
@@ -210,6 +228,11 @@ def lowerings():
         "visible_hist": (ses._build_hist(vis, 1), (chip, m, ns, betas)),
         "cd_step": (ses.make_cd_step(cfg, vis).with_mismatch,
                     (mach.mismatch, *cd_args)),
+        "cd_epoch": (ses.make_cd_epoch(cfg, vis).with_mismatch,
+                     (mach.mismatch, key, p, codes, *cd_args[:2],
+                      *cd_args[3:])),
+        "cd_eval": (ses._build_master_hist(vis, 1),
+                    (mach.mismatch, *cd_args[:2], key, betas)),
         "sample_fleet": (ses._build_sample_fleet(),
                          (api.stack_programs([prog] * k), fleet(m),
                           fleet(ns), betas)),
@@ -223,7 +246,7 @@ def lowerings():
 
 @pytest.mark.parametrize("name", ["sample_program", "sample", "stats",
                                   "visible_hist", "cd_step", "sample_fleet",
-                                  "cd_fleet_step"])
+                                  "cd_fleet_step", "cd_epoch", "cd_eval"])
 def test_session_modules_are_named(lowerings, name):
     fn, args = lowerings[name]
     assert fn.lower(*args).as_text().startswith(f"module @jit_{name} ")
