@@ -26,6 +26,7 @@ immutable after construction and safe to share across workloads.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple
 
 import jax
@@ -69,6 +70,24 @@ def cd_data_draw(key: jax.Array, p: jax.Array, codes: jax.Array,
     key, kd, ke = jax.random.split(key, 3)
     idx = jax.random.choice(kd, codes.shape[0], (chains,), p=p)
     return key, ke, codes[idx]
+
+
+class _Bound:
+    """A jitted closure with a sharded Session's static tables bound as
+    its first argument; everything else (``lower``, the cache) is the
+    jitted function's."""
+
+    def __init__(self, fn, tables):
+        self._fn, self._tables = fn, tables
+
+    def __call__(self, *args):
+        return self._fn(self._tables, *args)
+
+    def lower(self, *args):
+        return self._fn.lower(self._tables, *args)
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
 
 
 class SessionState(NamedTuple):
@@ -248,8 +267,12 @@ class Session:
         self.interpret = resolve_interpret(spec)
         g = spec.graph
         self.graph = g
-        self._color = jnp.asarray(g.color)
-        self._edges = jnp.asarray(g.edges)
+        # single-device closures only: under a mesh the engine holds its
+        # own tables, split over the devices
+        self._color = self._edges = None
+        if spec.mesh is None:
+            self._color = jnp.asarray(g.color)
+            self._edges = jnp.asarray(g.edges)
         nbr_idx, nbr_mask = g.neighbor_table()
         slot_ij, slot_ji = g.edge_slots(nbr_idx)
         self._nbr = (nbr_idx, nbr_mask, slot_ij, slot_ji)
@@ -257,7 +280,8 @@ class Session:
             self._compile_faults()
         self._noise_init, self._noise_step = self._make_noise()
         self._flip_fn = self._make_flip_fn()
-        self._engine = None
+        self._engine = self._tables = None
+        self._band_program = False
         if spec.mesh is not None:
             # multi-device execution: the partition plan, the sync-policy
             # launch loop, and the shard_map'd sweep live in
@@ -271,6 +295,11 @@ class Session:
                 spec.decimation, spec.chains, sync=spec.sync_policy(),
                 backend=self.backend, interpret=self.interpret,
                 faults=spec.faults)
+            self._tables = self._engine.tables
+            # a band-resident graph programs band by band on its devices
+            self._band_program = (self._engine.band_resident
+                                  and spec.sparse_native
+                                  and spec.faults is None)
         self.default_betas = (
             None if spec.schedule is None
             else spec.schedule.betas(spec.chains))
@@ -426,6 +455,41 @@ class Session:
             self._fns[key] = fn
         return fn
 
+    def _jit(self, impl, name: str, *, donate_spins: bool = False):
+        """`named_jit` of ``impl(tables, ...)``, returned with the
+        tables bound.
+
+        A single-device Session binds None, so its closures keep their
+        signatures.  A sharded one binds the engine's static tables, which
+        then enter the compiled program as sharded operands, never as
+        constants.  ``donate_spins`` donates the spins (the argument after
+        the chip or program), so a call holds one buffer of them."""
+        if self._engine is None:
+            return named_jit(functools.partial(impl, None), name)
+        kw = {"donate_argnums": (2,)} if donate_spins else {}
+        return _Bound(named_jit(impl, name, **kw), self._tables)
+
+    def _dispatch(self, fn, *args):
+        """Call a closure; a sharded Session's host dispatch is the
+        ``repro.dist.sample`` span."""
+        if self._engine is None:
+            return fn(*args)
+        with span("dist.sample"):
+            return fn(*args)
+
+    def _program_in_jit(self, tables, mismatch, J_edge_codes, h_codes):
+        """`program_edges` inside a jitted closure, with the chip instance
+        as an operand: band by band on a band-resident mesh (the (E,)
+        codes gathered into each band's incident edges), else the global
+        slot scatter."""
+        if self._band_program:
+            parts = jnp.take(jnp.asarray(J_edge_codes),
+                             tables["edge_ids"], axis=0)
+            return self._engine.program(tables, mismatch, self.spec.hw,
+                                        self.spec.w_scale, parts, h_codes)
+        return program_edges(self.spec.replace(mismatch=mismatch),
+                             J_edge_codes, h_codes, tables=self._nbr)
+
     def _betas(self, betas) -> jax.Array:
         if betas is None:
             if self.default_betas is None:
@@ -439,10 +503,30 @@ class Session:
     # state initialization (explicit key threading)
     # ------------------------------------------------------------------
     def random_spins(self, key: jax.Array) -> jax.Array:
-        return pbit.random_spins(key, self.spec.chains, self.graph.n_nodes)
+        """(B, N) spins of +-1 drawn from ``key``; on a band-resident mesh
+        each device draws only its band (the draw is partitionable, so it
+        equals the single-device one)."""
+        sharding = None if self._engine is None \
+            else self._engine.spin_sharding
+        if sharding is None:
+            return pbit.random_spins(key, self.spec.chains,
+                                     self.graph.n_nodes)
+        fn = self._fn(("random_spins",), lambda: jax.jit(
+            functools.partial(pbit.random_spins, batch=self.spec.chains,
+                              n_nodes=self.graph.n_nodes),
+            out_shardings=sharding))
+        with span("dist.place"):
+            return fn(key)
 
     def noise_state(self, key: jax.Array) -> jax.Array:
-        return self._noise_init(key)
+        sharding = None if self._engine is None \
+            else self._engine.noise_sharding
+        if sharding is None:
+            return self._noise_init(key)
+        fn = self._fn(("noise_state",), lambda: jax.jit(
+            self._noise_init, out_shardings=sharding))
+        with span("dist.place"):
+            return fn(key)
 
     def init_state(self, key: jax.Array) -> SessionState:
         k1, k2 = jax.random.split(key)
@@ -459,9 +543,25 @@ class Session:
 
     def program_edges(self, J_edge_codes: jax.Array, h_codes: jax.Array
                       ) -> EffectiveChip:
-        """Program per-edge codes (E,) — the CD master-weight layout."""
-        return program_edges(self.spec, J_edge_codes, h_codes,
-                             tables=self._nbr)
+        """Program per-edge codes (E,) — the CD master-weight layout.
+
+        On a band-resident mesh the codes go from the host straight to the
+        devices, each band's incident edges to its own device, and every
+        device programs its band: the chip comes back split over the rows
+        axis, and no device ever holds the whole edge list."""
+        if not self._band_program:
+            return program_edges(self.spec, J_edge_codes, h_codes,
+                                 tables=self._nbr)
+        with span("dist.place"):
+            parts = self._engine.place_edge_codes(J_edge_codes)
+            h = self._engine.place_nodes(h_codes)
+        fn = self._fn(("program_bands",), self._jit,
+                      self._band_program_impl, "program_edges")
+        return fn(self.spec.mismatch, parts, h)
+
+    def _band_program_impl(self, tables, mismatch, parts, h):
+        return self._engine.program(tables, mismatch, self.spec.hw,
+                                    self.spec.w_scale, parts, h)
 
     def program_master(self, Jm: jax.Array, hm: jax.Array) -> EffectiveChip:
         """Quantize float masters — edge-list (E,) or dense (n, n) — and
@@ -492,8 +592,15 @@ class Session:
         """
         with span("session.make_program"):
             E, n = self.graph.n_edges, self.graph.n_nodes
-            J = jnp.asarray(J_edge_codes)
-            h = jnp.asarray(h_codes)
+            if self._band_program:
+                # codes stay off any one device: h in its bands, the
+                # edge list split evenly (each band gathers its edges
+                # in-jit)
+                J = self._engine.place_edges(J_edge_codes)
+                h = self._engine.place_nodes(h_codes)
+            else:
+                J = jnp.asarray(J_edge_codes)
+                h = jnp.asarray(h_codes)
             if J.shape != (E,):
                 raise ValueError(
                     f"J_edge_codes must be edge-list shaped ({E},), got "
@@ -544,16 +651,20 @@ class Session:
                 betas = jnp.asarray(betas, jnp.float32)
             fn = self._fn(("sample_program", collect),
                           self._build_sample_program, collect)
-            return fn(prog, m, noise_state, betas)
+            return self._dispatch(fn, prog, m, noise_state, betas)
 
     def _build_sample_program(self, collect: bool):
-        def impl(prog, m, ns, betas):
-            chip = program_chip(self.spec, prog, tables=self._nbr)
+        def impl(tables, prog, m, ns, betas):
+            mm = self.spec.mismatch if prog.mismatch is None \
+                else prog.mismatch
+            chip = self._program_in_jit(tables, mm, prog.J_codes,
+                                        prog.h_codes)
             b = betas if betas is not None else prog.betas
             m, cm, cv = self._merge_faults(m, prog.clamp_mask,
                                            prog.clamp_values)
             if self._engine is not None:
-                return self._engine.sample(chip, m, ns, b, cm, cv, collect)
+                return self._engine.sample(tables, chip, m, ns, b, cm, cv,
+                                           collect)
             return pbit.gibbs_sample(
                 chip, self._color, m, b, ns, self._noise_step,
                 clamp_mask=cm, clamp_values=cv, collect=collect,
@@ -562,7 +673,7 @@ class Session:
 
         # one jit: a changed optional-field structure (clamps, mismatch,
         # program-borne betas) retraces, changed values never do
-        return named_jit(impl, "sample_program")
+        return self._jit(impl, "sample_program")
 
     def sample_fleet(
         self,
@@ -631,28 +742,30 @@ class Session:
         """
         betas = self._betas(betas)
         clamped = clamp_mask is not None
-        fn = self._fn(("sample", collect, clamped),
-                      self._build_sample, collect, clamped)
+        # band-resident spins are consumed in place: the output reuses
+        # their buffer, and the caller's array is gone after the call
+        donate = self._engine is not None and self._engine.holds(m)
+        fn = self._fn(("sample", collect, clamped, donate),
+                      self._build_sample, collect, clamped, donate)
         if clamped:
-            return fn(chip, m, noise_state, betas, clamp_mask, clamp_values)
-        return fn(chip, m, noise_state, betas)
+            return self._dispatch(fn, chip, m, noise_state, betas,
+                                  clamp_mask, clamp_values)
+        return self._dispatch(fn, chip, m, noise_state, betas)
 
-    def _build_sample(self, collect: bool, clamped: bool):
-        def impl(chip, m, ns, betas, cm=None, cv=None):
+    def _build_sample(self, collect: bool, clamped: bool,
+                      donate: bool = False):
+        def impl(tables, chip, m, ns, betas, cm=None, cv=None):
             m, cm, cv = self._merge_faults(m, cm, cv)
             if self._engine is not None:
-                return self._engine.sample(chip, m, ns, betas, cm, cv,
-                                           collect)
+                return self._engine.sample(tables, chip, m, ns, betas, cm,
+                                           cv, collect)
             return pbit.gibbs_sample(
                 chip, self._color, m, betas, ns, self._noise_step,
                 clamp_mask=cm, clamp_values=cv, collect=collect,
                 backend=self.backend, interpret=self.interpret,
                 flip_fn=self._flip_fn)
 
-        if clamped:
-            return named_jit(impl, "sample")
-        return named_jit(lambda chip, m, ns, betas: impl(chip, m, ns, betas),
-                         "sample")
+        return self._jit(impl, "sample", donate_spins=donate)
 
     def stats(
         self,
@@ -673,24 +786,23 @@ class Session:
         fn = self._fn(("stats", n_sweeps, burn_in, beta, clamped),
                       self._build_stats, n_sweeps, burn_in, beta, clamped)
         if clamped:
-            return fn(chip, m, noise_state, clamp_mask, clamp_values)
-        return fn(chip, m, noise_state)
+            return self._dispatch(fn, chip, m, noise_state, clamp_mask,
+                                  clamp_values)
+        return self._dispatch(fn, chip, m, noise_state)
 
     def _build_stats(self, n_sweeps, burn_in, beta, clamped):
-        def impl(chip, m, ns, cm=None, cv=None):
+        def impl(tables, chip, m, ns, cm=None, cv=None):
             m, cm, cv = self._merge_faults(m, cm, cv)
             if self._engine is not None:
-                return self._engine.stats(chip, m, ns, beta, n_sweeps,
-                                          burn_in, cm, cv)
+                return self._engine.stats(tables, chip, m, ns, beta,
+                                          n_sweeps, burn_in, cm, cv)
             return pbit.gibbs_stats(
                 chip, self._color, m, beta, n_sweeps, burn_in, ns,
                 self._noise_step, self._edges, clamp_mask=cm,
                 clamp_values=cv, backend=self.backend,
                 interpret=self.interpret, flip_fn=self._flip_fn)
 
-        if clamped:
-            return named_jit(impl, "stats")
-        return named_jit(lambda chip, m, ns: impl(chip, m, ns), "stats")
+        return self._jit(impl, "stats")
 
     def visible_hist(
         self,
@@ -706,17 +818,17 @@ class Session:
         vis_key = tuple(int(i) for i in np.asarray(visible_idx))
         fn = self._fn(("hist", vis_key, burn_in),
                       self._build_hist, np.asarray(visible_idx), burn_in)
-        return fn(chip, m, noise_state, betas)
+        return self._dispatch(fn, chip, m, noise_state, betas)
 
     def _build_hist(self, visible_idx, burn_in):
-        return named_jit(self._hist_impl(visible_idx, burn_in),
+        return self._jit(self._hist_impl(visible_idx, burn_in),
                          "visible_hist")
 
     def _hist_impl(self, visible_idx, burn_in):
-        def impl(chip, m, ns, betas):
+        def impl(tables, chip, m, ns, betas):
             m, cm, cv = self._merge_faults(m, None, None)
             if self._engine is not None:
-                return self._engine.visible_hist(chip, m, ns, betas,
+                return self._engine.visible_hist(tables, chip, m, ns, betas,
                                                  burn_in, visible_idx,
                                                  cm, cv)
             return pbit.gibbs_visible_hist(
@@ -748,20 +860,29 @@ class Session:
         fn = self._fn(("master_hist", vis_key, burn_in),
                       self._build_master_hist, np.asarray(visible_idx),
                       burn_in)
-        return fn(self.spec.mismatch, Jm, hm, key, self._betas(None))
+        return self._dispatch(fn, self.spec.mismatch, Jm, hm, key,
+                              self._betas(None))
 
     def _build_master_hist(self, visible_idx, burn_in):
         hist = self._hist_impl(visible_idx, burn_in)
 
-        def impl(mismatch, Jm, hm, key, betas):
-            chip = program_master(self.spec.replace(mismatch=mismatch),
-                                  Jm, hm, tables=self._nbr)
+        def impl(tables, mismatch, Jm, hm, key, betas):
+            Jm = jnp.asarray(Jm)
+            if Jm.ndim == 1:
+                chip = self._program_in_jit(tables, mismatch,
+                                            quantize_codes(Jm),
+                                            quantize_codes(hm))
+            else:
+                chip = program_master(self.spec.replace(mismatch=mismatch),
+                                      Jm, hm, tables=self._nbr)
             k1, k2 = jax.random.split(key)
-            counts, _, _ = hist(chip, self.random_spins(k1),
-                                self.noise_state(k2), betas)
+            counts, _, _ = hist(tables, chip,
+                                pbit.random_spins(k1, self.spec.chains,
+                                                  self.graph.n_nodes),
+                                self._noise_init(k2), betas)
             return counts
 
-        return named_jit(impl, "cd_eval")
+        return self._jit(impl, "cd_eval")
 
     # ------------------------------------------------------------------
     # contrastive divergence (the in-situ learning closure)
@@ -834,8 +955,8 @@ class Session:
         key = self._cd_key("cd_fleet", cfg, visible_idx)
 
         def build():
-            step_mm = self._build_cd_step_mm(cfg, np.asarray(visible_idx),
-                                             fleet=True)
+            step_mm = functools.partial(self._build_cd_step_mm(
+                cfg, np.asarray(visible_idx), fleet=True), None)
             return named_jit(jax.vmap(step_mm,
                                       in_axes=(0, 0, 0, None, 0, 0, 0)),
                              "cd_fleet_step")
@@ -843,7 +964,7 @@ class Session:
         return self._fn(key, build)
 
     def _build_cd_step(self, cfg, visible_idx):
-        step_mm = named_jit(self._build_cd_step_mm(cfg, visible_idx,
+        step_mm = self._jit(self._build_cd_step_mm(cfg, visible_idx,
                                                    fleet=False), "cd_step")
         mm = self.spec.mismatch
 
@@ -856,14 +977,15 @@ class Session:
     def _build_cd_epoch(self, cfg, visible_idx):
         step_mm = self._build_cd_step_mm(cfg, visible_idx, fleet=False)
 
-        def epoch_mm(mismatch, key, p, codes, Jm, hm, m, noise_state, vel):
+        def epoch_mm(tables, mismatch, key, p, codes, Jm, hm, m,
+                     noise_state, vel):
             key, ke, data_vis = cd_data_draw(key, p, codes, cfg.chains)
             Jm, hm, m, noise_state, vel, metrics = step_mm(
-                mismatch, Jm, hm, data_vis, m, noise_state, vel)
+                tables, mismatch, Jm, hm, data_vis, m, noise_state, vel)
             return (key, ke, Jm, hm, m, noise_state, vel,
                     jnp.stack([metrics[k] for k in CD_METRICS]))
 
-        epoch_mm = named_jit(epoch_mm, "cd_epoch")
+        epoch_mm = self._jit(epoch_mm, "cd_epoch")
         mm = self.spec.mismatch
 
         def epoch(key, p, codes, Jm, hm, m, noise_state, vel):
@@ -882,23 +1004,22 @@ class Session:
         backend = (_FLEET_BACKEND.get(self.backend, self.backend)
                    if fleet else self.backend)
 
-        def phase(chip, m0, n_sweeps, ns, cm=None, cv=None):
+        def phase(tables, chip, m0, n_sweeps, ns, cm=None, cv=None):
             if self._engine is not None:
                 # sharded phases: rows partition halo-exchanges, a chains
                 # partition runs the Gibbs replicas per-device and
                 # psum-reduces the (E,) gradient moments once per phase
-                return self._engine.stats(chip, m0, ns, beta, n_sweeps,
-                                          cfg.burn_in, cm, cv)
+                return self._engine.stats(tables, chip, m0, ns, beta,
+                                          n_sweeps, cfg.burn_in, cm, cv)
             return pbit.gibbs_stats(
                 chip, self._color, m0, beta, n_sweeps, cfg.burn_in, ns,
                 self._noise_step, self._edges, clamp_mask=cm,
                 clamp_values=cv, backend=backend,
                 interpret=self.interpret, flip_fn=self._flip_fn)
 
-        def step(mismatch, Jm, hm, data_vis, m, noise_state, vel):
-            chip = program_edges(self.spec.replace(mismatch=mismatch),
-                                 quantize_codes(Jm), quantize_codes(hm),
-                                 tables=self._nbr)
+        def step(tables, mismatch, Jm, hm, data_vis, m, noise_state, vel):
+            chip = self._program_in_jit(tables, mismatch, quantize_codes(Jm),
+                                        quantize_codes(hm))
             clamp_values = jnp.zeros((cfg.chains, n), jnp.float32)
             clamp_values = clamp_values.at[:, vis].set(data_vis)
 
@@ -907,12 +1028,12 @@ class Session:
             m, pos_cm, pos_cv = self._merge_faults(m, clamp_mask,
                                                    clamp_values)
             pos_s, pos_c, m_pos, noise_state = phase(
-                chip, m, cfg.pos_sweeps, noise_state, pos_cm, pos_cv)
+                tables, chip, m, cfg.pos_sweeps, noise_state, pos_cm, pos_cv)
             # negative phase: CD-k from the positive-phase state, or from
             # the persistent chains (PCD)
             neg_init = m if cfg.persistent else m_pos
             neg_s, neg_c, m_neg, noise_state = phase(
-                chip, neg_init, cfg.cd_k, noise_state, self._fault_cm,
+                tables, chip, neg_init, cfg.cd_k, noise_state, self._fault_cm,
                 None)
 
             gJ = pos_c - neg_c

@@ -68,6 +68,27 @@ VMEM_BYTES = 16 * 2 ** 20
 _RESIDENT_BLOCK_B = 128
 
 
+def band_vmem_feasible(spec: "SamplerSpec") -> bool:
+    """Can one row band of a sharded spec run in the fused per-shard
+    kernel (docs/kernels.md, "VMEM model of the per-shard kernel")?
+
+    The kernel keeps about two dozen f32 tiles of (chain block, band
+    plus halos) live in VMEM at once — spins in and out, double-buffered,
+    noise, fields, lane rotations — so a tile may take at most 1/24 of
+    the 16 MB core.  The chain block is 128 chains (all of a device's
+    chains when the kernel owns mid-launch exchanges).  Calibrated by
+    compiling for a v5e: 147k-element tiles compile, 263k do not."""
+    from repro.core.distributed import partition_size, plan_row_partition
+    part, sync = spec.partitioning(), spec.sync_policy()
+    plan = plan_row_partition(spec.graph,
+                              partition_size(spec.mesh, part.rows_axes))
+    b_loc = spec.chains // partition_size(spec.mesh, part.chain_axes)
+    tb = -(-b_loc // 8) * 8
+    if sync.kernel_fusible:
+        tb = min(_RESIDENT_BLOCK_B, tb)
+    return 4 * tb * (plan.n_loc + 2 * plan.halo) * 24 <= VMEM_BYTES
+
+
 def dense_vmem_feasible(n_nodes: int) -> bool:
     """Can a dense (N, N) float32 W stay VMEM-resident (kernels.md model)?"""
     return 4 * n_nodes * n_nodes + 2 * (_RESIDENT_BLOCK_B * n_nodes * 4) \
@@ -645,8 +666,8 @@ def _resolve_sharded_backend(spec: SamplerSpec) -> str:
         if env:
             b, src = env, f"REPRO_PBIT_BACKEND={env!r}"
         else:
-            return ("fused_sparse"
-                    if fused_ok and sync.launch_resident else "sparse")
+            return ("fused_sparse" if fused_ok and sync.launch_resident
+                    and band_vmem_feasible(spec) else "sparse")
     if b == "sparse":
         return b
     if b == "fused_sparse":
@@ -660,6 +681,15 @@ def _resolve_sharded_backend(spec: SamplerSpec) -> str:
                 f"{spec.noise!r}, sync={sync}, faults={spec.faults}); "
                 f"nearest legal Sync: lower halo_every to {S}, raise it "
                 f"to >= {2 * S} or math.inf, or use backend='sparse'")
+        if not band_vmem_feasible(spec):
+            raise ValueError(
+                f"{src} runs each row band inside one kernel, but a band "
+                f"of this spec does not fit the kernel's VMEM "
+                f"(docs/kernels.md, \"VMEM model of the per-shard "
+                f"kernel\"): {spec.graph.n_nodes} spins in row bands, "
+                f"{spec.chains} chains; use "
+                f"backend='sparse', more row shards, or fewer chains per "
+                f"device")
         return b
     raise ValueError(
         f"{src} cannot run a mesh-sharded spec: the partitioned engine "

@@ -50,6 +50,20 @@ from repro.core.hardware import (
 )
 
 
+def _band_shardings(graph, mesh, partition):
+    """Where a sparse chip instance for ``mesh`` lives: each device holds
+    its band of it on a band-resident graph; None (one device) otherwise
+    or without a mesh."""
+    if mesh is None:
+        return None
+    from repro.core import distributed as dist
+    part = partition if partition is not None else api.Partition()
+    if not dist.band_resident(graph,
+                              dist.partition_size(mesh, part.rows_axes)):
+        return None
+    return dist.mismatch_shardings(mesh, part)
+
+
 @dataclasses.dataclass
 class PBitMachine:
     """A (simulated) chip instance: graph + mismatch + programmable weights.
@@ -85,8 +99,9 @@ class PBitMachine:
         hw = hw or HardwareConfig()
         if sparse:
             nbr_idx, _ = graph.neighbor_table()
-            mism = sample_mismatch_sparse(key, graph.n_nodes,
-                                          nbr_idx.shape[0], hw)
+            mism = sample_mismatch_sparse(
+                key, graph.n_nodes, nbr_idx.shape[0], hw,
+                _band_shardings(graph, kw.get("mesh"), kw.get("partition")))
             # sparse-native chips have no dense W: the dense backends
             # cannot run them, so don't let "auto" resolve to one
             kw.setdefault("backend", "sparse")

@@ -51,7 +51,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import lfsr as lfsr_mod
 from repro.core.chimera import ChimeraGraph, make_chimera
 from repro.core.hardware import EffectiveChip, HardwareConfig
-from repro.kernels.ref import halo_exchange_segments, sparse_neuron_input
+from repro.kernels.ref import (
+    field_decision_update,
+    halo_exchange_segments,
+    sparse_neuron_input,
+)
 from repro.kernels.shard_sweep import (
     fused_shard_exchange_resident,
     fused_shard_sweeps,
@@ -59,6 +63,7 @@ from repro.kernels.shard_sweep import (
     halo_half_sweep,
 )
 from repro.launch.mesh import auto_axes
+from repro.runtime.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -143,19 +148,74 @@ def plan_row_partition(graph: ChimeraGraph, n_shards: int,
     return plan
 
 
+def _band_starts(graph: ChimeraGraph, n_shards: int):
+    """(first cell row, first node id) of each row band, n_shards + 1 each.
+
+    Cell rows split as evenly as they go, the first bands taking one more;
+    nodes are numbered by (r, c, side, k), so each band owns a contiguous
+    id range regardless of cell masking."""
+    base, rem = divmod(graph.rows, n_shards)
+    counts = [base + (d < rem) for d in range(n_shards)]
+    r_start = np.concatenate([[0], np.cumsum(counts)])
+    node_starts = np.searchsorted(np.asarray(graph.node_r),
+                                  r_start).astype(np.int64)
+    return r_start, node_starts
+
+
+def partition_size(mesh, axes) -> int:
+    """Devices along ``axes`` of ``mesh`` (1 for no axes)."""
+    return int(np.prod([mesh.shape[a] for a in axes], dtype=np.int64))
+
+
+def band_resident(graph: ChimeraGraph, n_row: int) -> bool:
+    """Are the row bands the even split of the node ids?
+
+    True when every band owns exactly N / n_row nodes: then band d is
+    the d-th equal slice of the global node axis, so a global (..., N)
+    array split evenly over the rows axis already *is* the band layout —
+    spins, programs, chip instances and noise live in their bands with no
+    gather, and the sharded engine moves no data between layouts.  Masked
+    cells or rows that do not divide leave uneven bands, which keep the
+    gather path (chip-scale graphs)."""
+    if n_row < 1 or n_row > graph.rows or graph.n_nodes % n_row:
+        return False
+    _, starts = _band_starts(graph, n_row)
+    return bool(np.all(np.diff(starts) == graph.n_nodes // n_row))
+
+
+def band_sharding(mesh: Mesh, partition, ndim: int, node_axis: int,
+                  chain_axis: int | None = None) -> NamedSharding:
+    """The sharding that splits ``node_axis`` of an ``ndim`` array over
+    the partition's rows axes (and ``chain_axis`` over its chains axes):
+    on a band-resident graph, each device holds exactly its band."""
+    spec = [None] * ndim
+    spec[node_axis] = tuple(partition.rows_axes) or None
+    if chain_axis is not None:
+        spec[chain_axis] = tuple(partition.chain_axes) or None
+    return NamedSharding(auto_axes(mesh), P(*spec))
+
+
+def mismatch_shardings(mesh: Mesh, partition):
+    """`band_sharding` of every leaf of a slot-layout chip instance."""
+    from repro.core.hardware import SparseMismatch
+
+    def nodes(ndim, axis):
+        return band_sharding(mesh, partition, ndim, axis)
+
+    return SparseMismatch(
+        dac_bit_j=nodes(3, 1), dac_bit_h=nodes(2, 0), edge_gain=nodes(2, 1),
+        tanh_gain=nodes(1, 0), tanh_offset=nodes(1, 0),
+        rand_gain=nodes(1, 0), comp_offset=nodes(1, 0), leak=nodes(2, 1))
+
+
 def _plan_row_partition(graph: ChimeraGraph, n_shards: int,
                         with_lfsr: bool = False) -> RowPartition:
     if n_shards < 1 or n_shards > graph.rows:
         raise ValueError(
             f"cannot cut {graph.rows} cell rows into {n_shards} bands")
-    base, rem = divmod(graph.rows, n_shards)
-    counts = [base + (d < rem) for d in range(n_shards)]
-    r_start = np.concatenate([[0], np.cumsum(counts)])       # (n_shards+1,)
+    r_start, node_starts = _band_starts(graph, n_shards)
     node_r = np.asarray(graph.node_r)
     node_side = np.asarray(graph.node_side)
-    # nodes are numbered by (r, c, side, k): each band owns a contiguous
-    # id range regardless of cell masking
-    node_starts = np.searchsorted(node_r, r_start).astype(np.int64)
     n_loc = max(1, int(np.max(np.diff(node_starts))))
     N = graph.n_nodes
     owner = np.searchsorted(node_starts[1:], np.arange(N), side="right")
@@ -334,11 +394,21 @@ class ShardedEngine:
     """Plan + mesh + sync policy -> device-local sweep implementations.
 
     Built once at `api.Session` compile when the spec carries a mesh.
-    The public impls (`sample` / `stats` / `visible_hist`) keep the exact
+    The public impls (`sample` / `stats` / `visible_hist`) keep the
     array contracts of the single-device engine (global (B, N) spins,
     global noise state) — the Session's closures call them unchanged, so
     every workload (CD, annealing, tempering, Max-Cut) shards without
-    modification.
+    modification.  Each impl takes the engine's static tables (`tables`)
+    as its first argument: the Session passes them into its jitted
+    closures as sharded operands, so no lattice-sized table is ever
+    baked into a compiled program as a constant.
+
+    On a `band_resident` graph (every band the even slice of the node
+    axis) the global arrays are the band layout: spins, programs, chip
+    instances and noise go into `shard_map` as they are, split over the
+    rows axis, and come out the same way — no gather, no copy.  Uneven
+    bands (masked cells, rows that do not divide) are gathered into a
+    padded (n_shards, ..., n_loc) layout and back.
 
     The `api.Sync` policy is compiled into a *launch loop*: the sweep
     schedule is cut into launches of ``sweeps_per_launch`` sweeps, the
@@ -364,6 +434,7 @@ class ShardedEngine:
             sync = Sync()
         self.graph = graph
         self.mesh = auto_axes(mesh)
+        self.partition = partition
         self.noise = noise
         self.decimation = decimation
         self.chains = chains
@@ -381,10 +452,8 @@ class ShardedEngine:
         self._fused = backend == "fused_sparse"
         self.rows_axes = partition.rows_axes
         self.chain_axes = partition.chain_axes
-        self.n_row = int(np.prod([mesh.shape[a] for a in self.rows_axes],
-                                 dtype=np.int64)) if self.rows_axes else 1
-        self.n_chain = int(np.prod([mesh.shape[a] for a in self.chain_axes],
-                                   dtype=np.int64)) if self.chain_axes else 1
+        self.n_row = partition_size(mesh, self.rows_axes)
+        self.n_chain = partition_size(mesh, self.chain_axes)
         if chains % self.n_chain:
             raise ValueError(f"chains={chains} not divisible by the "
                              f"chain-axis size {self.n_chain}")
@@ -404,7 +473,9 @@ class ShardedEngine:
             and not os.environ.get("REPRO_HALO_EMULATE"))
         self.plan = plan_row_partition(graph, self.n_row,
                                        with_lfsr=(noise == "lfsr"))
-        p = self.plan
+        self.band_resident = band_resident(graph, self.n_row)
+        # a band of whole cell rows sweeps by shifted views, no gathers
+        self.grid = self.band_resident and not graph.masked_cells
         self._row_name = (self.rows_axes[0] if len(self.rows_axes) == 1
                           else (tuple(self.rows_axes) or None))
         self._chain_name = (self.chain_axes[0] if len(self.chain_axes) == 1
@@ -412,31 +483,53 @@ class ShardedEngine:
         # P-spec dimension entries (None = replicated over that dim)
         self._r = tuple(self.rows_axes) if self.rows_axes else None
         self._c = tuple(self.chain_axes) if self.chain_axes else None
-        self._part_ids = jnp.asarray(p.part_ids)
-        self._inv_ids = jnp.asarray(p.inv_ids)
-        self._edge_inv = jnp.asarray(p.edge_inv)
-        self._dev = {
-            "nbr": jnp.asarray(p.nbr_idx),
-            "send_up": jnp.asarray(p.send_up),
-            "send_dn": jnp.asarray(p.send_dn),
-            "upd": jnp.asarray(p.upd_masks),
-            "cols": jnp.asarray(p.part_ids.astype(np.uint32)),
-            "edge_e0": jnp.asarray(p.edge_e0),
-            "edge_e1": jnp.asarray(p.edge_e1),
-        }
-        if noise == "lfsr":
-            self._dev["lfsr_perm"] = jnp.asarray(p.lfsr_perm)
-            self._cell_ids = jnp.asarray(p.cell_ids)
-            self._cell_inv = jnp.asarray(p.cell_inv)
-            if faults is not None and faults.lfsr_stuck:
-                n_cells = graph.n_nodes // 8
+        with span("dist.place"):
+            self.tables = self._place_tables()
+
+    # -- static tables (numpy plan -> sharded device arrays) -------------
+    @staticmethod
+    def _put(x: np.ndarray, sharding: NamedSharding) -> jax.Array:
+        """Place a host table; each device receives only its part."""
+        return jax.device_put(x, sharding)
+
+    def _place_tables(self) -> dict:
+        """Every static table the impls read, each placed so that a
+        device holds only what it reads: per-band tables split over the
+        rows axis, global index tables (the uneven gathers, edge moments)
+        split where they divide and replicated where they are small."""
+        p, g = self.plan, self.graph
+
+        def bands(x):
+            return self._put(x, NamedSharding(
+                self.mesh, P(self._r, *([None] * (x.ndim - 1)))))
+
+        def flat(x):
+            split = self.n_row > 1 and x.shape[0] % self.n_row == 0
+            return self._put(x, NamedSharding(
+                self.mesh, P(self._r if split else None)))
+
+        t = {"nbr": bands(p.nbr_idx), "send_up": bands(p.send_up),
+             "send_dn": bands(p.send_dn), "upd": bands(p.upd_masks),
+             "cols": bands(p.part_ids.astype(np.uint32)),
+             "edge_e0": bands(p.edge_e0), "edge_e1": bands(p.edge_e1),
+             "edge_inv": flat(p.edge_inv)}
+        if not self.band_resident:
+            t["part_ids"], t["inv_ids"] = flat(p.part_ids), flat(p.inv_ids)
+        if self.noise == "lfsr":
+            t["lfsr_perm"] = bands(p.lfsr_perm)
+            if not self.band_resident:
+                t["cell_ids"] = flat(p.cell_ids)
+                t["cell_inv"] = flat(p.cell_inv)
+            f = self.faults
+            if f is not None and f.lfsr_stuck:
+                n_cells = g.n_nodes // 8
                 s0 = np.zeros((n_cells,), np.uint32)
                 s1 = np.zeros((n_cells,), np.uint32)
-                for cell, m0, m1 in faults.lfsr_stuck:
+                for cell, m0, m1 in f.lfsr_stuck:
                     s0[int(cell)] |= np.uint32(m0)
                     s1[int(cell)] |= np.uint32(m1)
-                self._dev["lfsr_s0"] = jnp.asarray(s0[p.cell_ids])
-                self._dev["lfsr_s1"] = jnp.asarray(s1[p.cell_ids])
+                t["lfsr_s0"] = bands(s0[p.cell_ids])
+                t["lfsr_s1"] = bands(s1[p.cell_ids])
         if self._fused:
             # per-edge slot row into the kernel's (D, N_ext) correlation
             # scratch: edge q of band b lives at c_slots[edge_slot[b, q],
@@ -445,84 +538,291 @@ class ShardedEngine:
             for b in range(p.n_shards):
                 hit = p.nbr_idx[b][:, p.edge_e0[b]] == p.edge_e1[b][None, :]
                 es[b] = np.argmax(hit, axis=0)
-            self._dev["edge_slot"] = jnp.asarray(es)
+            t["edge_slot"] = bands(es)
+        if self.grid:
+            t["grid_slots"] = bands(self._grid_slots())
+        if self.band_resident:
+            t.update(self._program_tables())
+        return t
+
+    def _program_tables(self) -> dict:
+        """Band programming: each band's incident edges (its own, whose
+        endpoint 0 it holds, then those coming up from the band above),
+        and for each local slot the position of its edge in that list
+        (-1 on padding slots); the global neighbor table and its mask,
+        split over the node axis, which programmed chips carry."""
+        p, g = self.plan, self.graph
+        nbr_idx, nbr_mask, slot_ij, slot_ji = g._slot_tables()
+        D, n, n_loc = nbr_idx.shape[0], g.n_nodes, p.n_loc
+        e0 = g.edges[:, 0].astype(np.int64)
+        e1 = g.edges[:, 1].astype(np.int64)
+        slot_edge = np.full((D, n), -1, np.int32)
+        slot_edge.reshape(-1)[slot_ij * n + e0] = np.arange(e0.size)
+        slot_edge.reshape(-1)[slot_ji * n + e1] = np.arange(e0.size)
+        e_start = np.searchsorted(e0, p.node_starts)
+        lists = []
+        for d in range(p.n_shards):
+            s, e = int(p.node_starts[d]), int(p.node_starts[d + 1])
+            up = np.nonzero((e0 < s) & (e1 >= s) & (e1 < e))[0] \
+                if d else np.zeros((0,), np.int64)
+            lists.append(np.concatenate(
+                [np.arange(e_start[d], e_start[d + 1]), up]))
+        e_band = max(1, max(x.size for x in lists))
+        edge_ids = np.zeros((p.n_shards, e_band), np.int32)
+        local = np.full((p.n_shards, D, n_loc), -1, np.int32)
+        for d, ids in enumerate(lists):
+            edge_ids[d, :ids.size] = ids
+            s = int(p.node_starts[d])
+            se = slot_edge[:, s:s + n_loc]
+            own = (se >= e_start[d]) & (se < e_start[d + 1])
+            pos = np.where(own, se - e_start[d], -1)
+            n_own = int(e_start[d + 1] - e_start[d])
+            far = (se >= 0) & ~own
+            pos[far] = n_own + np.searchsorted(ids[n_own:], se[far])
+            local[d] = pos
+        self._edge_ids = edge_ids
+        nodes = band_sharding(self.mesh, self.partition, 2, 1)
+        band = NamedSharding(self.mesh, P(self._r, None, None))
+        return {"edge_ids": self._put(edge_ids, NamedSharding(
+                    self.mesh, P(self._r, None))),
+                "slot_edge": self._put(local, band),
+                "nbr_g": self._put(nbr_idx, nodes),
+                "nbr_ok": self._put(nbr_mask, nodes)}
+
+    # -- half-sweeps of a full band of cells, without gathers ------------
+    def _grid_slots(self) -> np.ndarray:
+        """(n_shards, K + 2, n_loc) int8: for each local spin, the slot of
+        its neighbour table that holds, in ascending neighbour order, the
+        spin before its cell (the vertical spin above, the horizontal spin
+        to the left), its K in-cell partners, and the spin after its cell
+        (below, to the right); -1 where that neighbour does not exist (the
+        lattice's edge).  These are all of a spin's neighbours, in the
+        table's own ascending order, so a term's slot counts the terms
+        before it that exist."""
+        g, p = self.graph, self.plan
+        vert = g.node_side == 0
+        first = np.where(vert, g.node_r == 0, g.node_c == 0)
+        last = np.where(vert, g.node_r == g.rows - 1,
+                        g.node_c == g.cols - 1)
+        exists = np.ones((g.k + 2, g.n_nodes), bool)
+        exists[0], exists[-1] = ~first, ~last
+        out = np.where(exists, np.cumsum(exists, axis=0) - 1, -1)
+        return np.ascontiguousarray(
+            out.astype(np.int8).reshape(g.k + 2, p.n_shards, p.n_loc)
+            .transpose(1, 0, 2))
+
+    def _grid_weights(self, slots, w):
+        """The band's slot couplings in the order of `_grid_slots`: a
+        select per slot, so every value is the program's own (0 where the
+        neighbour does not exist)."""
+        out = []
+        for t in range(slots.shape[0]):
+            wt = jnp.zeros(w.shape[1:], w.dtype)
+            for d in range(w.shape[0]):
+                wt = jnp.where(slots[t] == d, w[d], wt)
+            out.append(wt)
+        return jnp.stack(out)
+
+    def _to_grid(self, x):
+        """(..., n_loc) band vector -> (..., rows, side, k, cols)."""
+        g = self.graph
+        R = self.plan.n_loc // (2 * g.k * g.cols)
+        y = x.reshape(x.shape[:-1] + (R, g.cols, 2, g.k))
+        return jnp.moveaxis(y, -3, -1)
+
+    def _from_grid(self, y):
+        x = jnp.moveaxis(y, -1, -3)
+        return x.reshape(x.shape[:-4] + (self.plan.n_loc,))
+
+    def _grid_exchange(self, m):
+        """`halo_exchange` on the grid layout: a band's first and last
+        rows of vertical spins, (chains, k, cols), to its row neighbours;
+        zeros past the lattice's edge."""
+        up_src, dn_src = m[:, -1, 0], m[:, 0, 0]
+        if self.n_row <= 1:
+            return jnp.zeros_like(up_src), jnp.zeros_like(dn_src)
+        n = self.n_row
+        return (jax.lax.ppermute(up_src, self._row_name,
+                                 [(i, i + 1) for i in range(n - 1)]),
+                jax.lax.ppermute(dn_src, self._row_name,
+                                 [(i + 1, i) for i in range(n - 1)]))
+
+    def _grid_half_sweep(self, m, hu, hd, ws, h, gain, off, rg, co, mask,
+                         beta, u):
+        """`halo_half_sweep` on the grid layout (chains, rows, side, k,
+        cols): each term of the field is a shift of the band's spins —
+        the vertical spin above or below (across the halo at the band's
+        edge), the horizontal spin to the left or right, the in-cell
+        partners — so nothing is gathered and the columns fill the
+        lanes.  The terms are summed in ascending neighbour order from
+        zero, as `sparse_neuron_input` sums its slots (an absent
+        neighbour adds an exact zero), so the field is the same bit for
+        bit; the decision is `field_decision_update`.
+
+        The band goes in chunks of cell rows (`GRID_CHUNK_BYTES` of
+        spins each), updated in place: a half-sweep reads only the other
+        colour, so no chunk reads a spin another chunk writes, and the
+        temporaries are chunk-sized."""
+        K = self.graph.k
+        B, R, _, _, C = m.shape
+        step = max(1, min(R, GRID_CHUNK_BYTES // (4 * B * 2 * K * C)))
+        for r0 in range(0, R, step):
+            r1 = min(R, r0 + step)
+            v, hz = m[:, r0:r1, 0], m[:, r0:r1, 1]     # (B, rows, K, C)
+            above = hu[:, None] if r0 == 0 else m[:, r0 - 1:r0, 0]
+            below = hd[:, None] if r1 == R else m[:, r1:r1 + 1, 0]
+            zc = jnp.zeros(hz.shape[:-1] + (1,), m.dtype)
+            before = [jnp.concatenate([above, v[:, :-1]], axis=1),
+                      jnp.concatenate([zc, hz[..., :-1]], axis=-1)]
+            after = [jnp.concatenate([v[:, 1:], below], axis=1),
+                     jnp.concatenate([hz[..., 1:], zc], axis=-1)]
+            partner = [hz, v]
+            acc = []
+            for sd in (0, 1):
+                terms = ([before[sd]]
+                         + [jnp.broadcast_to(partner[sd][:, :, j:j + 1],
+                                             v.shape) for j in range(K)]
+                         + [after[sd]])
+                a = jnp.zeros(v.shape, jnp.float32)
+                for t, x in enumerate(terms):
+                    a = a + ws[t][r0:r1, sd][None] * x
+                acc.append(a)
+            I = jnp.stack(acc, axis=2) + h[r0:r1]
+            new = field_decision_update(
+                m[:, r0:r1], I, gain[r0:r1], off[r0:r1], rg[r0:r1],
+                co[r0:r1], mask[r0:r1], beta, u[:, r0:r1])
+            m = jax.lax.dynamic_update_slice_in_dim(m, new, r0, axis=1)
+        return m
 
     # -- spec helpers ----------------------------------------------------
-    def _dev_specs(self):
-        specs = {
-            "nbr": P(self._r, None, None),
-            "send_up": P(self._r, None),
-            "send_dn": P(self._r, None),
-            "upd": P(self._r, None, None),
-            "cols": P(self._r, None),
-            "edge_e0": P(self._r, None),
-            "edge_e1": P(self._r, None),
-        }
-        if self.noise == "lfsr":
-            specs["lfsr_perm"] = P(self._r, None)
-            if "lfsr_s0" in self._dev:
-                specs["lfsr_s0"] = P(self._r, None)
-                specs["lfsr_s1"] = P(self._r, None)
-        if self._fused:
-            specs["edge_slot"] = P(self._r, None)
-        return specs
+    def _dev_specs(self, dev):
+        """shard_map specs of the per-band tables in ``dev``."""
+        return {k: P(self._r, *([None] * (dev[k].ndim - 1)))
+                for k in _BAND_TABLES if k in dev}
 
     def _chip_specs(self):
+        if self.band_resident:
+            return {"w": P(None, self._r),
+                    **{k: P(self._r) for k in ("h", "gain", "off", "rg",
+                                               "co")}}
         return {"w": P(self._r, None, None),
                 **{k: P(self._r, None)
                    for k in ("h", "gain", "off", "rg", "co")}}
+
+    def _m_spec(self):
+        """Spins: global (B, N) split (chains, rows) on a band-resident
+        graph; else the (n_shards, B, n_loc) parts."""
+        if self.band_resident:
+            return P(self._c, self._r)
+        return P(self._r, self._c, None)
+
+    def _node_spec(self):
+        """A (N,) node vector (clamp masks, spin moments)."""
+        return P(self._r) if self.band_resident else P(self._r, None)
 
     def _shard_map(self, fn, in_specs, out_specs):
         return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
 
-    # -- global <-> parts layout ----------------------------------------
-    def _chip_parts(self, chip: EffectiveChip) -> dict:
-        """Slice the chip into per-device (n_shards, ...) shard layouts.
+    @property
+    def spin_sharding(self) -> NamedSharding | None:
+        """Where band-resident spins live: (B, N) split over (chains,
+        rows).  None on uneven bands."""
+        if not self.band_resident:
+            return None
+        return NamedSharding(self.mesh, self._m_spec())
 
-        Pure jnp gathers on static index tables, so this runs *inside*
-        the Session's jitted closures with the chip as a traced operand —
-        which is what threads runtime weight streaming through the
-        sharded engine for free: a `Program` programmed in-jit
-        (`Session.sample_program`) flows through here into the
-        shard_map'd sweep as sharded input, and a swapped program is a
-        new operand value, never a recompile.
+    @property
+    def noise_sharding(self) -> NamedSharding | None:
+        """Where band-resident noise state lives: the counter state is
+        two words, replicated; the LFSR state (B, n_cells) is split like
+        the spins (a band's cells are the even slice of the cells)."""
+        if not self.band_resident:
+            return None
+        if self.noise == "lfsr":
+            return NamedSharding(self.mesh, P(self._c, self._r))
+        return NamedSharding(self.mesh, P())
+
+    def holds(self, m) -> bool:
+        """Is ``m`` a concrete array already in the band layout (which
+        the sharded sample consumes in place)?"""
+        s = self.spin_sharding
+        return (s is not None and isinstance(m, jax.Array)
+                and not isinstance(m, jax.core.Tracer) and m.sharding == s)
+
+    # -- global <-> parts layout ----------------------------------------
+    def _blk(self, x):
+        """A device's block inside shard_map: band-resident arrays arrive
+        as the block itself, parts carry a leading shard axis of 1."""
+        return x if self.band_resident else x[0]
+
+    def _unblk(self, x):
+        return x if self.band_resident else x[None]
+
+    def _chip_parts(self, dev, chip: EffectiveChip) -> dict:
+        """The chip in the engine's layout: its own arrays on a
+        band-resident graph (each device reads its band of them), else
+        per-device (n_shards, ...) gathers on the static index tables.
+
+        Pure jnp, so this runs *inside* the Session's jitted closures
+        with the chip as a traced operand — which is what threads runtime
+        weight streaming through the sharded engine for free: a
+        `Program` programmed in-jit (`Session.sample_program`) flows
+        through here into the shard_map'd sweep as sharded input, and a
+        swapped program is a new operand value, never a recompile.
         """
         if chip.nbr_w is None or chip.nbr_idx is None:
             raise ValueError(
                 "sharded execution needs a chip carrying the slot layout "
                 "(program through the Session — e.g. Session.make_program "
                 "+ sample_program — or hardware.attach_sparse)")
-        ids = self._part_ids
-        return {
-            "w": jnp.moveaxis(chip.nbr_w[:, ids], 1, 0),
-            "h": chip.h[ids],
-            "gain": chip.tanh_gain[ids],
-            "off": chip.tanh_offset[ids],
-            "rg": chip.rand_gain[ids],
-            "co": chip.comp_offset[ids],
-        }
+        parts = {"w": chip.nbr_w, "h": chip.h, "gain": chip.tanh_gain,
+                 "off": chip.tanh_offset, "rg": chip.rand_gain,
+                 "co": chip.comp_offset}
+        if self.band_resident:
+            return parts
+        ids = dev["part_ids"]
+        return {k: (jnp.moveaxis(v[:, ids], 1, 0) if k == "w" else v[ids])
+                for k, v in parts.items()}
 
-    def _m_parts(self, m: jax.Array) -> jax.Array:
-        return jnp.moveaxis(jnp.take(m, self._part_ids, axis=1), 1, 0)
+    def _m_parts(self, dev, m: jax.Array) -> jax.Array:
+        if self.band_resident:
+            return m
+        return jnp.moveaxis(jnp.take(m, dev["part_ids"], axis=1), 1, 0)
 
-    def _m_global(self, parts: jax.Array) -> jax.Array:
+    def _m_global(self, dev, parts: jax.Array) -> jax.Array:
+        if self.band_resident:
+            return parts
         flat = jnp.moveaxis(parts, 0, 1).reshape(parts.shape[1], -1)
-        return jnp.take(flat, self._inv_ids, axis=1)
+        return jnp.take(flat, dev["inv_ids"], axis=1)
 
-    def _ns_parts(self, ns: jax.Array):
-        if self.noise == "lfsr":
-            return jnp.moveaxis(jnp.take(ns, self._cell_ids, axis=1), 1, 0)
-        return ns  # counter: replicated uint32[2]
+    def _ns_parts(self, dev, ns: jax.Array):
+        if self.noise == "lfsr" and not self.band_resident:
+            return jnp.moveaxis(jnp.take(ns, dev["cell_ids"], axis=1), 1, 0)
+        return ns  # counter: replicated uint32[2]; band-resident lfsr
 
-    def _ns_global(self, ns, parts):
-        if self.noise == "lfsr":
+    def _ns_global(self, dev, ns, parts):
+        if self.noise == "lfsr" and not self.band_resident:
             flat = jnp.moveaxis(parts, 0, 1).reshape(parts.shape[1], -1)
-            return jnp.take(flat, self._cell_inv, axis=1)
+            return jnp.take(flat, dev["cell_inv"], axis=1)
         return parts
 
     def _ns_spec(self):
-        return P(self._r, self._c, None) if self.noise == "lfsr" else P()
+        if self.noise != "lfsr":
+            return P()
+        return self._m_spec()
+
+    def _ns_local(self, ns_p):
+        return self._blk(ns_p) if self.noise == "lfsr" else ns_p
+
+    def _ns_out(self, ns_local):
+        return self._unblk(ns_local) if self.noise == "lfsr" else ns_local
+
+    def _part_cols(self, dev, x):
+        """(N,) node vector -> the engine's layout."""
+        if self.band_resident:
+            return x
+        return jnp.take(x, dev["part_ids"], axis=0)
 
     # -- device-local pieces --------------------------------------------
     def _chain_offset(self):
@@ -629,6 +929,13 @@ class ShardedEngine:
         k1_exact = sync.bit_exact
         use_fused = self._fused and not collect and hist_w is None
         fused_ex = use_fused and ex_pts != (0,)
+        # plain sampling of a band of whole cell rows runs on the band's
+        # grid layout (see `_grid_half_sweep`); the rest gathers
+        f = self.faults
+        grid = (self.grid and not (use_fused or clamped or collect
+                                   or accumulate or hist_w is not None)
+                and self.noise == "counter"
+                and (f is None or f.flip_prob <= 0.0))
         if use_fused or ex_pts == (0,):
             seg_sweeps = L                  # exchange at launch starts only
         elif isinstance(k, int) and k % 2 == 0 and (2 * L) % k == 0:
@@ -642,18 +949,32 @@ class ShardedEngine:
             nbr = dev["nbr"][0]
 
             def exchange(m):
+                if grid:
+                    return self._grid_exchange(m)
                 return halo_exchange(m, send_up, send_dn, self._row_name,
                                      self.n_row)
 
             nstep = self._noise_step(dev)
             fstep = self._flip_step(dev)
-            w, h = chip["w"][0], chip["h"][0]
-            gain, off = chip["gain"][0], chip["off"][0]
-            rg, co = chip["rg"][0], chip["co"][0]
+            w, h = chip["w"], chip["h"]
+            gain, off = chip["gain"], chip["off"]
+            rg, co = chip["rg"], chip["co"]
             chain0 = self._chain_offset()
             masks = [dev["upd"][0, c] for c in (0, 1)]
             if clamped:
                 masks = [mk & ~cm for mk in masks]
+            if grid:
+                # the band as (chains, rows, side, k, cols): cols last, so
+                # every array is lane-dense and each neighbour a shift
+                ws = self._to_grid(self._grid_weights(
+                    dev["grid_slots"][0], w))
+                h, gain, off, rg, co = (self._to_grid(x) for x in
+                                        (h, gain, off, rg, co))
+                masks = [self._to_grid(mk) for mk in masks]
+                cols = self._to_grid(dev["cols"][0])
+                rows = (chain0 + jnp.arange(self.b_loc, dtype=jnp.uint32)
+                        ).reshape(-1, 1, 1, 1, 1)
+                m = self._to_grid(m)
 
             S_total = int(betas.shape[0])
             if S_total % L:
@@ -662,6 +983,24 @@ class ShardedEngine:
                     f"{L} sweeps per launch, which must divide the "
                     f"schedule length (got {S_total} sweeps); pad the "
                     f"schedule or change the Sync policy")
+
+            def half(m, hu, hd, ns, mask, beta):
+                """One half-sweep of the scan paths: (m', noise state')."""
+                if grid:
+                    u = lfsr_mod.counter_uniform(ns[0], ns[1], rows, cols)
+                    beta = jnp.asarray(beta, jnp.float32)
+                    if beta.ndim == 1:
+                        beta = beta.reshape(-1, 1, 1, 1, 1)
+                    m = self._grid_half_sweep(m, hu, hd, ws, h, gain, off,
+                                              rg, co, mask, beta, u)
+                    return m, ns + jnp.array([0, 1], jnp.uint32)
+                ns0 = ns
+                ns, u = nstep(ns, chain0)
+                m = halo_half_sweep(m, hu, hd, nbr, w, h, gain, off, rg, co,
+                                    mask, beta, u)
+                if fstep is not None:
+                    m = jnp.where(mask & fstep(ns0, chain0), -m, m)
+                return m, ns
 
             def swap(m, hu, hd, pend):
                 """One exchange point: barrier consumes the fresh values;
@@ -818,14 +1157,7 @@ class ShardedEngine:
                         for c in (0, 1):
                             if 2 * s + c in ex_pts:
                                 hu, hd, pend = swap(m, hu, hd, pend)
-                            ns0 = ns
-                            ns, u = nstep(ns, chain0)
-                            m = halo_half_sweep(m, hu, hd, nbr, w, h,
-                                                gain, off, rg, co,
-                                                masks[c], beta_t, u)
-                            if fstep is not None:
-                                m = jnp.where(
-                                    masks[c] & fstep(ns0, chain0), -m, m)
+                            m, ns = half(m, hu, hd, ns, masks[c], beta_t)
                         if accumulate:
                             if k1_exact:
                                 # post-sweep refresh for boundary edges —
@@ -868,14 +1200,7 @@ class ShardedEngine:
                     if clamped and cv is not None:
                         m = jnp.where(cm, cv, m)
                     for c in (0, 1):
-                        ns0 = ns
-                        ns, u = nstep(ns, chain0)
-                        m = halo_half_sweep(m, hu, hd, nbr, w, h, gain,
-                                            off, rg, co, masks[c],
-                                            beta_t, u)
-                        if fstep is not None:
-                            m = jnp.where(
-                                masks[c] & fstep(ns0, chain0), -m, m)
+                        m, ns = half(m, hu, hd, ns, masks[c], beta_t)
                     out = None
                     if accumulate or hist_w is not None:
                         accs2 = tuple(sweep_stats(m, hu, hd, xs_s[1],
@@ -899,6 +1224,8 @@ class ShardedEngine:
             if measured is not None:
                 xs = (betas_l, measured.reshape(S_total // chunk, chunk))
             zh = jnp.zeros((m.shape[0], self.plan.halo), m.dtype)
+            if grid:
+                zh = jnp.zeros(m.shape[:1] + m.shape[3:], m.dtype)
             init = (m, ns, zh, zh)
             if async_:
                 # prime the in-flight buffer with the initial boundary —
@@ -918,59 +1245,76 @@ class ShardedEngine:
             if collect and traj is not None:
                 traj = traj.reshape((S_total,) + traj.shape[2:])
             base = 6 if async_ else 4
-            return (final[0], final[1]) + final[base:], traj
+            m_out = self._from_grid(final[0]) if grid else final[0]
+            return (m_out, final[1]) + final[base:], traj
 
         return run
 
     # ------------------------------------------------------------------
     # public impls (called inside the Session's jitted closures)
     # ------------------------------------------------------------------
-    def sample(self, chip, m, ns, betas, cm=None, cv=None, collect=False):
+    def _band_dev(self, dev) -> dict:
+        return {k: dev[k] for k in _BAND_TABLES if k in dev}
+
+    def _clamp_args(self, dev, cm, cv, in_specs, args):
+        if cm is not None:
+            in_specs.append(self._node_spec())
+            args.append(self._part_cols(dev, cm))
+            if cv is not None:
+                in_specs.append(self._m_spec())
+                args.append(self._m_parts(dev, cv))
+
+    def _clamp_kw(self, rest, clamped, has_cv) -> dict:
+        kw = {}
+        if clamped:
+            kw["cm"] = self._blk(rest[0])
+            if has_cv:
+                kw["cv"] = self._blk(rest[1])
+        return kw
+
+    def sample(self, dev, chip, m, ns, betas, cm=None, cv=None,
+               collect=False):
         clamped = cm is not None
         has_cv = cv is not None
         run = self._local_sweeps(clamped, collect, False, None)
 
-        def local(dev, chipp, m_p, ns_p, betas, *rest):
-            kw = {}
-            if clamped:
-                kw["cm"] = rest[0][0]
-                if has_cv:
-                    kw["cv"] = rest[1][0]
-            ns_l = ns_p[0] if self.noise == "lfsr" else ns_p
-            (m_o, ns_o, *_), traj = run(dev, chipp, m_p[0], ns_l, betas,
-                                        **kw)
-            outs = [m_o[None], self._ns_out(ns_o)]
+        def local(bdev, chipp, m_p, ns_p, betas, *rest):
+            (m_o, ns_o, *_), traj = run(
+                bdev, {k: self._blk(v) for k, v in chipp.items()},
+                self._blk(m_p), self._ns_local(ns_p), betas,
+                **self._clamp_kw(rest, clamped, has_cv))
+            outs = [self._unblk(m_o), self._ns_out(ns_o)]
             if collect:
-                outs.append(traj[None])
+                outs.append(self._unblk(traj))
             return tuple(outs)
 
         betas = jnp.asarray(betas, jnp.float32)
         beta_spec = P() if betas.ndim == 1 else P(None, self._c)
-        in_specs = [self._dev_specs(), self._chip_specs(),
-                    P(self._r, self._c, None), self._ns_spec(), beta_spec]
-        args = [self._dev, self._chip_parts(chip), self._m_parts(m),
-                self._ns_parts(ns), betas]
-        if clamped:
-            in_specs.append(P(self._r, None))
-            args.append(self._part_cols(cm))
-            if has_cv:
-                in_specs.append(P(self._r, self._c, None))
-                args.append(self._m_parts(cv))
-        out_specs = [P(self._r, self._c, None), self._ns_spec()]
+        bdev = self._band_dev(dev)
+        in_specs = [self._dev_specs(bdev), self._chip_specs(),
+                    self._m_spec(), self._ns_spec(), beta_spec]
+        args = [bdev, self._chip_parts(dev, chip), self._m_parts(dev, m),
+                self._ns_parts(dev, ns), betas]
+        self._clamp_args(dev, cm, cv, in_specs, args)
+        out_specs = [self._m_spec(), self._ns_spec()]
         if collect:
-            out_specs.append(P(self._r, None, self._c, None))
+            out_specs.append(P(None, self._c, self._r) if self.band_resident
+                             else P(self._r, None, self._c, None))
         out = self._shard_map(local, tuple(in_specs), tuple(out_specs))(
             *args)
-        m_o = self._m_global(out[0])
-        ns_o = self._ns_global(ns, out[1])
+        m_o = self._m_global(dev, out[0])
+        ns_o = self._ns_global(dev, ns, out[1])
         traj = None
         if collect:
-            t = jnp.moveaxis(out[2], 0, 2)          # (S, B, n_row, n_loc)
-            t = t.reshape(t.shape[0], t.shape[1], -1)
-            traj = jnp.take(t, self._inv_ids, axis=2)
+            traj = out[2]
+            if not self.band_resident:
+                t = jnp.moveaxis(traj, 0, 2)      # (S, B, n_row, n_loc)
+                t = t.reshape(t.shape[0], t.shape[1], -1)
+                traj = jnp.take(t, dev["inv_ids"], axis=2)
         return m_o, ns_o, traj
 
-    def stats(self, chip, m, ns, beta, n_sweeps, burn_in, cm=None, cv=None):
+    def stats(self, dev, chip, m, ns, beta, n_sweeps, burn_in, cm=None,
+              cv=None):
         clamped = cm is not None
         has_cv = cv is not None
         run = self._local_sweeps(clamped, False, True, None)
@@ -978,40 +1322,35 @@ class ShardedEngine:
         measured = (jnp.arange(n_sweeps) >= burn_in).astype(jnp.float32)
         denom = jnp.maximum(n_sweeps - burn_in, 1).astype(jnp.float32)
 
-        def local(dev, chipp, m_p, ns_p, betas, measured, *rest):
-            kw = {}
-            if clamped:
-                kw["cm"] = rest[0][0]
-                if has_cv:
-                    kw["cv"] = rest[1][0]
-            ns_l = ns_p[0] if self.noise == "lfsr" else ns_p
-            (m_o, ns_o, s_acc, c_acc), _ = run(dev, chipp, m_p[0], ns_l,
-                                               betas, measured, **kw)
+        def local(bdev, chipp, m_p, ns_p, betas, measured, *rest):
+            (m_o, ns_o, s_acc, c_acc), _ = run(
+                bdev, {k: self._blk(v) for k, v in chipp.items()},
+                self._blk(m_p), self._ns_local(ns_p), betas, measured,
+                **self._clamp_kw(rest, clamped, has_cv))
             if self.n_chain > 1:
                 s_acc = jax.lax.psum(s_acc, self._chain_name)
                 c_acc = jax.lax.psum(c_acc, self._chain_name)
-            return m_o[None], self._ns_out(ns_o), s_acc[None], c_acc[None]
+            return (self._unblk(m_o), self._ns_out(ns_o),
+                    self._unblk(s_acc), c_acc[None])
 
-        in_specs = [self._dev_specs(), self._chip_specs(),
-                    P(self._r, self._c, None), self._ns_spec(), P(), P()]
-        args = [self._dev, self._chip_parts(chip), self._m_parts(m),
-                self._ns_parts(ns), betas, measured]
-        if clamped:
-            in_specs.append(P(self._r, None))
-            args.append(self._part_cols(cm))
-            if has_cv:
-                in_specs.append(P(self._r, self._c, None))
-                args.append(self._m_parts(cv))
-        out_specs = (P(self._r, self._c, None), self._ns_spec(),
-                     P(self._r, None), P(self._r, None))
+        bdev = self._band_dev(dev)
+        in_specs = [self._dev_specs(bdev), self._chip_specs(),
+                    self._m_spec(), self._ns_spec(), P(), P()]
+        args = [bdev, self._chip_parts(dev, chip), self._m_parts(dev, m),
+                self._ns_parts(dev, ns), betas, measured]
+        self._clamp_args(dev, cm, cv, in_specs, args)
+        out_specs = (self._m_spec(), self._ns_spec(), self._node_spec(),
+                     P(self._r, None))
         m_o, ns_o, s_p, c_p = self._shard_map(
             local, tuple(in_specs), out_specs)(*args)
         scale = denom if self.n_chain == 1 else denom * self.chains
-        s = jnp.take(s_p.reshape(-1), self._inv_ids) / scale
-        c = jnp.take(c_p.reshape(-1), self._edge_inv) / scale
-        return s, c, self._m_global(m_o), self._ns_global(ns, ns_o)
+        s = s_p if self.band_resident else jnp.take(s_p.reshape(-1),
+                                                     dev["inv_ids"])
+        c = jnp.take(c_p.reshape(-1), dev["edge_inv"]) / scale
+        return s / scale, c, self._m_global(dev, m_o), \
+            self._ns_global(dev, ns, ns_o)
 
-    def visible_hist(self, chip, m, ns, betas, burn_in, visible_idx,
+    def visible_hist(self, dev, chip, m, ns, betas, burn_in, visible_idx,
                      cm=None, cv=None):
         clamped = cm is not None
         has_cv = cv is not None
@@ -1031,45 +1370,98 @@ class ShardedEngine:
         n_sweeps = betas.shape[0]
         measured = (jnp.arange(n_sweeps) >= burn_in).astype(jnp.float32)
 
-        def local(dev, chipp, m_p, ns_p, betas, measured, vi_p, vw_p,
+        def local(bdev, chipp, m_p, ns_p, betas, measured, vi_p, vw_p,
                   *rest):
-            kw = {}
-            if clamped:
-                kw["cm"] = rest[0][0]
-                if has_cv:
-                    kw["cv"] = rest[1][0]
-            ns_l = ns_p[0] if self.noise == "lfsr" else ns_p
-            (m_o, ns_o, hist), _ = run(dev, chipp, m_p[0], ns_l, betas,
-                                       measured, vis_idx=vi_p[0],
-                                       vis_w=vw_p[0], **kw)
+            (m_o, ns_o, hist), _ = run(
+                bdev, {k: self._blk(v) for k, v in chipp.items()},
+                self._blk(m_p), self._ns_local(ns_p), betas, measured,
+                vis_idx=vi_p[0], vis_w=vw_p[0],
+                **self._clamp_kw(rest, clamped, has_cv))
             if self.n_chain > 1:
                 hist = jax.lax.psum(hist, self._chain_name)
-            return m_o[None], self._ns_out(ns_o), hist
+            return self._unblk(m_o), self._ns_out(ns_o), hist
 
         beta_spec = P() if betas.ndim == 1 else P(None, self._c)
-        in_specs = [self._dev_specs(), self._chip_specs(),
-                    P(self._r, self._c, None), self._ns_spec(), beta_spec,
+        bdev = self._band_dev(dev)
+        in_specs = [self._dev_specs(bdev), self._chip_specs(),
+                    self._m_spec(), self._ns_spec(), beta_spec,
                     P(), P(self._r, None), P(self._r, None)]
-        args = [self._dev, self._chip_parts(chip), self._m_parts(m),
-                self._ns_parts(ns), betas, measured, vi_j, vw_j]
-        if clamped:
-            in_specs.append(P(self._r, None))
-            args.append(self._part_cols(cm))
-            if has_cv:
-                in_specs.append(P(self._r, self._c, None))
-                args.append(self._m_parts(cv))
-        out_specs = (P(self._r, self._c, None), self._ns_spec(), P())
+        args = [bdev, self._chip_parts(dev, chip), self._m_parts(dev, m),
+                self._ns_parts(dev, ns), betas, measured, vi_j, vw_j]
+        self._clamp_args(dev, cm, cv, in_specs, args)
+        out_specs = (self._m_spec(), self._ns_spec(), P())
         m_o, ns_o, hist = self._shard_map(
             local, tuple(in_specs), out_specs)(*args)
-        return hist, self._m_global(m_o), self._ns_global(ns, ns_o)
+        return hist, self._m_global(dev, m_o), self._ns_global(dev, ns,
+                                                               ns_o)
 
-    # -- small helpers ---------------------------------------------------
-    def _part_cols(self, x):
-        """(N,) node vector -> (n_shards, n_loc)."""
-        return jnp.take(x, self._part_ids, axis=0)
+    # -- band programming (band-resident graphs) -------------------------
+    def place_edge_codes(self, codes) -> jax.Array:
+        """Host (E,) edge codes -> each band's incident codes on its own
+        device: (n_shards, e_band), built shard by shard, so no device
+        ever holds the whole edge list."""
+        codes, ids = np.asarray(codes), self._edge_ids
+        return jax.make_array_from_callback(
+            ids.shape, self.tables["edge_ids"].sharding,
+            lambda index: codes[ids[index]])
 
-    def _ns_out(self, ns_local):
-        return ns_local[None] if self.noise == "lfsr" else ns_local
+    def place_edges(self, codes) -> jax.Array:
+        """Host (E,) edge codes split evenly over the rows axis (each
+        band gathers its incident edges from them in-jit)."""
+        codes = np.asarray(codes)
+        split = self.n_row > 1 and codes.shape[0] % self.n_row == 0
+        return jax.device_put(codes, NamedSharding(
+            self.mesh, P(self._r if split else None)))
+
+    def place_nodes(self, x) -> jax.Array:
+        """A host (N,) node vector, split over the rows axis."""
+        return jax.device_put(np.asarray(x), band_sharding(
+            self.mesh, self.partition, 1, 0))
+
+    def program(self, dev, mismatch, hw, w_scale, code_parts, h_codes):
+        """Program a band-resident chip from each band's incident edge
+        codes (`place_edge_codes`, or a gather of (E,) codes on
+        ``dev["edge_ids"]`` in-jit) and (N,) bias codes: the slot scatter,
+        DAC transfer and analog chain of `program_edges`, run by each
+        device on its own band.  Returns an `EffectiveChip` whose arrays
+        are split over the rows axis; ``nbr_idx`` is the global neighbor
+        table, split the same way."""
+        from repro.core.hardware import program_weights_sparse
+
+        def local(slot_edge, nbr_g, nbr_ok, codes, h, mism):
+            se, codes = slot_edge[0], codes[0]
+            J = jnp.where(se >= 0, jnp.take(codes, jnp.maximum(se, 0)),
+                          jnp.zeros((), codes.dtype))
+            chip = program_weights_sparse(J, h, jnp.abs(J) > 0, mism, hw,
+                                          nbr_g, nbr_ok)
+            return (chip.nbr_w * w_scale, chip.h * w_scale, chip.tanh_gain,
+                    chip.tanh_offset, chip.rand_gain, chip.comp_offset)
+
+        node, slots = P(self._r), P(None, self._r)
+        mm_specs = type(mismatch)(
+            P(None, self._r, None), P(self._r, None), slots, node, node,
+            node, node, slots)
+        w, h, gain, off, rg, co = self._shard_map(
+            local,
+            (P(self._r, None, None), slots, slots, P(self._r, None), node,
+             mm_specs),
+            (slots, node, node, node, node, node))(
+            dev["slot_edge"], dev["nbr_g"], dev["nbr_ok"], code_parts,
+            jnp.asarray(h_codes), mismatch)
+        return EffectiveChip(W=None, h=h, tanh_gain=gain, tanh_offset=off,
+                             rand_gain=rg, comp_offset=co,
+                             nbr_idx=dev["nbr_g"], nbr_w=w)
+
+
+# a band swept on its grid layout goes in chunks of cell rows holding at
+# most this many bytes of spins (`ShardedEngine._grid_half_sweep`)
+GRID_CHUNK_BYTES = 1 << 28
+
+# per-band tables the shard_map'd sweep reads (the rest of
+# `ShardedEngine.tables` serves the gathers and the band programming)
+_BAND_TABLES = ("nbr", "send_up", "send_dn", "upd", "cols", "edge_e0",
+                "edge_e1", "lfsr_perm", "lfsr_s0", "lfsr_s1", "edge_slot",
+                "grid_slots")
 
 
 # ---------------------------------------------------------------------------

@@ -287,26 +287,43 @@ class SparseMismatch:
 
 
 def sample_mismatch_sparse(
-    key: jax.Array, n_nodes: int, degree: int, cfg: HardwareConfig
+    key: jax.Array, n_nodes: int, degree: int, cfg: HardwareConfig,
+    out_shardings: SparseMismatch | None = None,
 ) -> SparseMismatch:
-    """Draw one chip instance's process variation, slot layout (O(D·N))."""
+    """Draw one chip instance's process variation, slot layout (O(D·N)).
+
+    ``out_shardings`` (a `SparseMismatch` of shardings, e.g.
+    `core.distributed.mismatch_shardings`) draws each device's part of
+    the instance on that device.  The draw is partitionable, and the
+    scaling by sigma runs as its own op as in the unsharded draw, so the
+    values equal the unsharded draw's bit for bit."""
     ks = jax.random.split(key, 8)
     n, d = n_nodes, degree
+    shards = ([None] * 8 if out_shardings is None
+              else jax.tree_util.tree_leaves(out_shardings))
 
-    def g(k, shape, sigma):
+    def g(i, shape, sigma):
+        s = shards[i]
         if sigma == 0.0:
-            return jnp.zeros(shape, dtype=jnp.float32)
-        return sigma * jax.random.normal(k, shape, dtype=jnp.float32)
+            if s is None:
+                return jnp.zeros(shape, dtype=jnp.float32)
+            return jax.jit(lambda: jnp.zeros(shape, jnp.float32),
+                           out_shardings=s)()
+        if s is None:
+            return sigma * jax.random.normal(ks[i], shape, dtype=jnp.float32)
+        return sigma * jax.jit(
+            lambda k: jax.random.normal(k, shape, dtype=jnp.float32),
+            out_shardings=s)(ks[i])
 
     return SparseMismatch(
-        dac_bit_j=g(ks[0], (d, n, 8), cfg.sigma_dac_bit),
-        dac_bit_h=g(ks[1], (n, 8), cfg.sigma_dac_bit),
-        edge_gain=g(ks[2], (d, n), cfg.sigma_edge_gain),
-        tanh_gain=g(ks[3], (n,), cfg.sigma_tanh_gain),
-        tanh_offset=g(ks[4], (n,), cfg.sigma_tanh_offset),
-        rand_gain=g(ks[5], (n,), cfg.sigma_rand_gain),
-        comp_offset=g(ks[6], (n,), cfg.sigma_comp_offset),
-        leak=jnp.abs(g(ks[7], (d, n), cfg.leak_frac)),
+        dac_bit_j=g(0, (d, n, 8), cfg.sigma_dac_bit),
+        dac_bit_h=g(1, (n, 8), cfg.sigma_dac_bit),
+        edge_gain=g(2, (d, n), cfg.sigma_edge_gain),
+        tanh_gain=g(3, (n,), cfg.sigma_tanh_gain),
+        tanh_offset=g(4, (n,), cfg.sigma_tanh_offset),
+        rand_gain=g(5, (n,), cfg.sigma_rand_gain),
+        comp_offset=g(6, (n,), cfg.sigma_comp_offset),
+        leak=jnp.abs(g(7, (d, n), cfg.leak_frac)),
     )
 
 
