@@ -108,6 +108,14 @@ def halo_half_sweep(m_loc, halo_up, halo_dn, nbr_idx, nbr_w, h, gain, off,
                                  update_mask, beta, u)
 
 
+def _halo_free(nbr_idx, pad2):
+    """The ext-local table widened over the halo columns, which take no
+    source (-1): they are never updated, and a table entry there would
+    cost every slot group of the kernel's gather its own lane rotations."""
+    return jnp.pad(jnp.asarray(nbr_idx, jnp.int32), ((0, 0), (0, pad2)),
+                   constant_values=-1)
+
+
 def fused_shard_sweeps(
     m_loc: jax.Array,            # (B, N_loc) local spins
     halo_up: jax.Array,          # (B, H) frozen for the whole launch
@@ -157,7 +165,8 @@ def fused_shard_sweeps(
     Returns (m', noise_state'), with ``measured``
     (m', noise_state', s_sum[N_loc], c_slots[D, N_ext]) — raw sums over
     (chains × measured sweeps); ``c_slots[d, i] = Σ m_i·m_ext[idx[d, i]]``
-    with i ext-local (boundary edges read the frozen halo) — or, with a
+    with i ext-local (boundary edges read the frozen halo; halo columns
+    read 0) — or, with a
     next program, (m', noise_state', staged_w[D, N_loc], staged_h[N_loc])
     ready to be the following launch's resident program slice.
 
@@ -177,7 +186,7 @@ def fused_shard_sweeps(
     def row(x):
         return jnp.concatenate([jnp.asarray(x, jnp.float32), zf])
 
-    idx_e = jnp.pad(jnp.asarray(nbr_idx, jnp.int32), ((0, 0), (0, pad2)))
+    idx_e = _halo_free(nbr_idx, pad2)
     w_e = jnp.pad(jnp.asarray(nbr_w, jnp.float32), ((0, 0), (0, pad2)))
     betas = jnp.asarray(betas, jnp.float32)
     if betas.ndim == 1:
@@ -270,7 +279,7 @@ def fused_shard_exchange_resident(
     zb = jnp.zeros((pad2,), bool)
     zf = jnp.zeros((pad2,), jnp.float32)
     row = lambda x: jnp.concatenate([jnp.asarray(x, jnp.float32), zf])
-    idx_e = jnp.pad(jnp.asarray(nbr_idx, jnp.int32), ((0, 0), (0, pad2)))
+    idx_e = _halo_free(nbr_idx, pad2)
     w_e = jnp.pad(jnp.asarray(nbr_w, jnp.float32), ((0, 0), (0, pad2)))
     betas = jnp.asarray(betas, jnp.float32)
     if betas.ndim == 1:

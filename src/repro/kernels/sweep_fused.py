@@ -28,8 +28,9 @@ Two weight layouts share the kernel body:
     resident engine to roughly N <= 1.5k fp32 on a 16 MB-VMEM core.
   * sparse (`sweep_sparse_pallas`) — the Chimera-native fixed-degree slot
     layout (ChimeraGraph.neighbor_table): nbr_idx/nbr_w (D, N) with D = 6
-    on the chip's graph.  Neuron input is D gathers (built from lane
-    rotations, `_rotate_gather`) + multiply-adds —
+    on the chip's graph.  Neuron input is D gathers (built from the lane
+    rotations of each slot group, `_gather_rows`) + multiply-adds, over
+    row blocks of the tile small enough to stay in vector registers —
     2·B·N·D FLOPs instead of 2·B·N², and 8·D·N weight bytes instead of
     4·N², so ≥32k-spin lattices stay VMEM-resident.  Slots accumulate in
     ascending-neighbor order, making the result bit-exact against both the
@@ -72,50 +73,101 @@ NOISE_COUNTER = "counter"
 NOISE_LFSR = "lfsr"
 
 MAX_HIST_VISIBLE = 12  # one-hot reduction over 2^nv bins; keep it VMEM-sane
+_GATHER_VREGS = 48     # vector registers (of 64) a row block's gather holds
+_ROLLS_PER_STEP = 16   # lane rotations per step of the sparse gather loops
 
 
-def _lane_rotations(idx, n_cols: int, Np: int) -> jax.Array:
-    """Distinct lane rotations that realize the column gather ``idx``.
+def _slot_groups(n_slots: int) -> tuple[tuple[int, ...], ...]:
+    """Slots gathered together, each rotation rolled once for the group:
+    the first and the second half of the slots, in slot order.
+
+    Sharing a rotation saves lane rotations, the cross-lane unit's work,
+    and costs each slot of the group a compare and a select per shared
+    rotation.  On the chip graph the halves need 14 and 13 rotations, 27
+    in all, against 53 one slot at a time and 19 for all six at once;
+    compiled for a v5e, halves take the fewest bundles (docs/kernels.md).
+    """
+    h = -(-n_slots // 2)
+    return tuple(g for g in (tuple(range(h)), tuple(range(h, n_slots))) if g)
+
+
+def _lane_rotations(idx, n_cols: int, Np: int, groups):
+    """Lane rotations that realize the column gathers ``idx``, per group
+    of rows.
 
     idx: (R, W) int source columns; only the first ``n_cols`` columns are
-    real.  Returns an int32 (1 + Np,) table ``[K, s_0, .., s_{K-1}, 0..]``:
-    the K distinct ``s = (i - idx[r, i]) mod Np`` for which
-    ``jnp.roll(x, s, axis=-1)[:, i] == x[:, idx[r, i]]``.  At most Np
-    rotations exist, so the table never truncates.  Chimera tables need
-    few: 19 on the chip graph (in-cell ±1..±7, ±8 across columns, ±64
-    across rows, and 0 for self-pointing padding slots).
+    real, and idx < 0 means the lane takes no source.  Since
+    ``jnp.roll(x, s, axis=-1)[:, i] == x[:, (i - s) mod Np]``, lane i of
+    row r wants the rotation ``s = (i - idx[r, i]) mod Np``.  Returns
+
+    * ``shifts``, int32 (R, W rounded up to 128): that s at every lane
+      with a source, -1 at every other lane, so no rotation selects it;
+    * ``table``, int32 (len(groups) * (1 + Np),): group g's row at offset
+      ``g * (1 + Np)`` is ``[K_g, s_0, .., s_{K_g - 1}, s_{K_g - 1}, ..]``,
+      the K_g distinct shifts its rows use, ascending, then the last one
+      repeated (a repeat selects the same lanes again).  At most Np
+      rotations exist, so a row never truncates.  On the chip graph the
+      six slots alone use 10, 10, 10, 10, 10 and 3; all six, 19.
     """
-    idx = jnp.asarray(idx, jnp.int32)[:, :n_cols]
-    s = (jnp.arange(n_cols, dtype=jnp.int32)[None, :] - idx) % Np
-    s = jnp.where(idx >= 0, s, 0)   # idx < 0: lane takes no source
-    present = jnp.zeros((Np,), bool).at[s.reshape(-1)].set(True)
-    shifts = jnp.nonzero(present, size=Np, fill_value=0)[0]
-    return jnp.concatenate([jnp.sum(present, dtype=jnp.int32)[None],
-                            shifts.astype(jnp.int32)])
+    idx = jnp.asarray(idx, jnp.int32)
+    R, W = idx.shape
+    lane = jnp.arange(W, dtype=jnp.int32)[None, :]
+    real = (idx >= 0) & (lane < n_cols)
+    shifts = jnp.where(real, (lane - idx) % Np, -1)
+    used = jnp.zeros((R, Np + 1), bool).at[
+        jnp.arange(R)[:, None], jnp.where(real, shifts, Np)].set(True)[:, :Np]
+    rows = []
+    for g in groups:
+        present = used[jnp.asarray(g)].any(0)
+        listed = jnp.nonzero(present, size=Np, fill_value=Np)[0]
+        last = jnp.max(jnp.where(present, jnp.arange(Np), 0))
+        rows.append(jnp.concatenate([
+            jnp.sum(present, dtype=jnp.int32)[None],
+            jnp.where(listed < Np, listed, last).astype(jnp.int32)]))
+    return _pad_axis(shifts, 128, 1, -1), jnp.concatenate(rows)
 
 
-def _rotate_gather(x, idx_rows, rot_ref):
-    """In-kernel column gather: ``out[r][:, i] = x[:, idx_rows[r][0, i]]``.
+def _gather_rows(x, sh_ref, rot_ref, g: int, rows, per_step: int = 1):
+    """In-kernel column gathers for the rows of group g of a
+    `_lane_rotations` table: ``out[r][:, i] = x[:, idx[r, i]]``, 0 where
+    lane i of row r has no source.
 
     Mosaic gathers only inside one 128-lane vreg, so a gather across a
-    multi-vreg row is built from whole-row lane rotations instead: for
-    each rotation in ``rot_ref`` (see `_lane_rotations`), rotate x and
-    keep the lanes whose rotated lane id equals the wanted source.  Pure
-    selection — every output element is one input element, bit for bit.
-    idx_rows: (1, W) int32 rows with W <= x's width (a multiple of 128).
+    multi-vreg row is built from whole-row lane rotations instead: each
+    of the group's rotations is rolled once, and every row keeps the
+    lanes whose shift it is.  Pure selection — every output element is
+    one input element, bit for bit.  x: (rows, Np); ``sh_ref``:
+    `_lane_rotations`' shifts, (R, W) with W <= Np.  Each loop step
+    issues ``per_step`` rotations (a divisor of 128, so the padded table
+    row is never overrun).  Returns one (x rows, W) gather per row.
     """
-    W = idx_rows[0].shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[-1]), 1)
+    Np = x.shape[-1]
+    base = g * (1 + Np)
+    W = sh_ref.shape[-1]
 
-    def body(k, gs):
-        s = rot_ref[k + 1]
-        src = pltpu.roll(lane, s, 1)[:, :W]
-        xr = pltpu.roll(x, s, 1)[:, :W]
-        return tuple(jnp.where(ix == src, xr, g)
-                     for ix, g in zip(idx_rows, gs))
+    def step(j, gs):
+        for u in range(per_step):
+            s = rot_ref[base + 1 + j * per_step + u]
+            xr = pltpu.roll(x, s, 1)[:, :W]
+            # compare the shifts broadcast to the block: comparing the
+            # (1, W) row and broadcasting its mask costs mask moves
+            gs = tuple(jnp.where(jnp.broadcast_to(sh_ref[pl.ds(r, 1), :],
+                                                  xr.shape) == s, xr, gr)
+                       for r, gr in zip(rows, gs))
+        return gs
 
-    init = tuple(jnp.zeros((x.shape[0], W), x.dtype) for _ in idx_rows)
-    return jax.lax.fori_loop(0, rot_ref[0], body, init)
+    n_steps = (rot_ref[base] + per_step - 1) // per_step
+    return jax.lax.fori_loop(
+        0, n_steps, step,
+        tuple(jnp.zeros((x.shape[0], W), x.dtype) for _ in rows))
+
+
+def _slot_gathers(x, sh_ref, rot_ref, D: int, per_step: int = 1):
+    """(slot, gather) pairs of x for the D slots, in slot order, each
+    `_slot_groups` group gathered by one `_gather_rows`."""
+    for gi, rows in enumerate(_slot_groups(D)):
+        yield from zip(rows, _gather_rows(x, sh_ref, rot_ref, gi, rows,
+                                          per_step))
 
 
 def _noise_state_out(noise_in, n_half):
@@ -126,7 +178,27 @@ def _noise_state_out(noise_in, n_half):
                                 jnp.uint32(0))
 
 
-def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
+def _block_rows(tb: int, Np: int, D: int) -> int:
+    """Rows of the half-sweep's row blocks on the sparse path.
+
+    The gather walks the (tb, Np) tile in (hb, Np) row blocks whose
+    working set — the spins, the rolled copy, a slot group's gathers and
+    the accumulator: G + 3 slabs of (hb, Np) f32, G the larger
+    `_slot_groups` group — takes at most `_GATHER_VREGS` vector
+    registers, so it is not spilled to VMEM.  hb is a multiple of 8 that
+    divides tb (the rows are independent chains), and 8 at the least.  A
+    tile whose height is no multiple of 8 (a ``block_b`` that is none,
+    over the whole batch) is one block."""
+    if tb % 8:
+        return tb
+    slabs = max(len(g) for g in _slot_groups(D)) + 3
+    per8 = slabs * (Np // 128)                # vregs per 8 rows
+    return 8 * max((k for k in range(1, tb // 8 + 1)
+                    if (tb // 8) % k == 0 and k * per8 <= _GATHER_VREGS),
+                   default=1)
+
+
+def _kernel(*refs, S: int, tb: int, hb: int, Np: int, n_b: int, B: int,
             noise_mode: str, has_clamp: bool, accumulate: bool,
             collect_hist: bool, decimation: int, sparse: bool, D: int,
             NBp: int, has_coords: bool, stream: bool = False,
@@ -134,13 +206,13 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
     it = iter(refs)
     m0_ref = next(it)
     if sparse:
-        idx_ref = next(it)                    # (Dp, Np) neighbor table
+        sh_ref = next(it)                     # (Dp, Np) lane shifts
         w_ref = next(it)                      # (Dp, Np) slot weights
-        rot_ref = next(it)                    # SMEM (1 + Np,) rotations
+        rot_ref = next(it)                    # SMEM per-slot rotations
     else:
         w_ref = next(it)                      # (Np, Np) dense couplings
     h_ref, g_ref, off_ref, rg_ref, co_ref = (next(it) for _ in range(5))
-    mask0_ref, mask1_ref = next(it), next(it)
+    mask_refs = (next(it), next(it))
     betas_ref = next(it)
     clampm_ref = next(it) if has_clamp else None
     clampv_ref = next(it) if has_clamp else None
@@ -152,7 +224,7 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
     if stream:
         next_w_ref = next(it)                 # (Dp, Np) next program weights
         next_h_ref = next(it)                 # (1, Np) next program biases
-    m_out_ref = next(it)
+    m_out_ref = next(it)                      # f32 spins, updated in place
     noise_out_ref = next(it)
     if accumulate:
         ssum_out_ref, csum_out_ref = next(it), next(it)
@@ -160,8 +232,11 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
         hist_out_ref = next(it)
     if stream:
         staged_w_out_ref, staged_h_out_ref = next(it), next(it)
+    bcol_ref = next(it)                       # (tb, 1) this sweep's betas
     if accumulate:
         ssum_ref, csum_ref = next(it), next(it)
+        if sparse:
+            corr_ref = next(it)               # (Dp, Np) one sweep's sums
     if collect_hist:
         hist_ref = next(it)
     if stream:
@@ -192,12 +267,10 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
             slot_w_ref[...] = next_w_ref[...]
             slot_h_ref[...] = next_h_ref[...]
 
-    if not sparse:
-        w = w_ref[...]
-    hrow, grow = h_ref[...], g_ref[...]
-    offrow, rgrow, corow = off_ref[...], rg_ref[...], co_ref[...]
-    masks = (mask0_ref[...] != 0, mask1_ref[...] != 0)
-
+    # the spins (and the LFSR registers) live in their output blocks for
+    # the whole launch; each half-sweep rewrites them one row block at a
+    # time, so no tile-sized value is carried through the loops
+    m_out_ref[...] = m0_ref[...].astype(jnp.float32)
     if noise_mode == NOISE_COUNTER:
         seed = noise_in_ref[0, 0]
         ctr0 = noise_in_ref[0, 1]
@@ -207,29 +280,32 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
         # exactly its columns of the single-device stream
         row0 = coords_ref[0, 0] if has_coords else jnp.uint32(0)
         col0 = coords_ref[0, 1] if has_coords else jnp.uint32(0)
-        rows = (jax.lax.broadcasted_iota(jnp.uint32, (tb, Np), 0)
-                + (i * tb).astype(jnp.uint32) + row0)
-        cols = jax.lax.broadcasted_iota(jnp.uint32, (tb, Np), 1) + col0
-        noise_carry0 = jnp.zeros((), jnp.uint32)  # unused
     else:
         # (tb, Np) LFSR states replicated onto each cell's nodes: every
         # node reads its own byte in place, so no gather is needed
-        noise_carry0 = noise_in_ref[...]
-        byte_sel = byte_ref[...]
-    if sparse:
-        idx_rows = [idx_ref[pl.ds(d, 1), :] for d in range(D)]
+        noise_out_ref[...] = noise_in_ref[...]
 
     def neuron_current(m):
-        """Eqn 1 over the resident tile: matmul (dense) or D-slot gather."""
+        """Eqn 1 over a row block: matmul (dense) or D slot gathers, each
+        multiply-added in slot order once its group is gathered."""
         if sparse:
-            acc = jnp.zeros((tb, Np), jnp.float32)
-            for d, g in enumerate(_rotate_gather(m, idx_rows, rot_ref)):
+            acc = jnp.zeros(m.shape, jnp.float32)
+            for d, g in _slot_gathers(m, sh_ref, rot_ref, D,
+                                      _ROLLS_PER_STEP):
                 acc = acc + w_ref[pl.ds(d, 1), :] * g
-            return acc + hrow
+            return acc + h_ref[...]
         return jax.lax.dot_general(
-            m, w, dimension_numbers=(((1,), (1,)), ((), ())),
+            m, w_ref[...], dimension_numbers=(((1,), (1,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32) + hrow
+            preferred_element_type=jnp.float32) + h_ref[...]
+
+    def row_blocks(body, carry):
+        """Run ``body(rows, r0, carry)`` over the tile's (hb, Np) row
+        blocks; ``rows`` slices the block, ``r0`` is its first row."""
+        def step(r, carry):
+            r0 = pl.multiple_of(r * hb, hb)
+            return body(pl.ds(r0, hb), r0, carry)
+        return jax.lax.fori_loop(0, tb // hb, step, carry)
 
     # Launch-relative half-sweep window.  The fused-exchange engine splits
     # one logical launch into segments at halo exchange points, so a
@@ -246,45 +322,71 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
     tail = max(n_half_eff - lead, 0) % 2
     s0 = (half_offset + lead) // 2
 
-    def impose_clamp(m):
+    def impose_clamp():
         if has_clamp:
-            return jnp.where(clampm_ref[...] != 0, clampv_ref[...], m)
-        return m
+            m_out_ref[...] = jnp.where(clampm_ref[...] != 0, clampv_ref[...],
+                                       m_out_ref[...])
 
-    def half_update(m, st, s_idx, c, half_j):
+    def half_update(s_idx, c, half_j):
         """One color half-sweep of (launch-relative) sweep s_idx."""
-        if noise_mode == NOISE_COUNTER:
-            ctr = ctr0 + half_j
-            u = lfsr_mod.counter_uniform(seed, ctr, rows, cols)
-        else:
-            st = lfsr_mod.lfsr_step_n(st, decimation)
-            u = lfsr_mod.node_byte_uniforms(st, byte_sel)
-        beta_col = betas_ref[pl.ds(s_idx, 1), :].reshape(tb, 1)
-        I = neuron_current(m)
-        act = jnp.tanh(beta_col * grow * (I + offrow))
-        decision = act + rgrow * u + corow
-        new = jnp.where(decision >= 0.0, 1.0, -1.0)
-        return jnp.where(masks[c], new, m), st
+        bcol_ref[...] = betas_ref[pl.ds(s_idx, 1), :].reshape(tb, 1)
 
-    def sweep_stats(m, s_idx):
-        """Accumulate moments/histogram after sweep s_idx completes."""
+        def block(rows, r0, carry):
+            m = m_out_ref[rows, :]
+            if noise_mode == NOISE_COUNTER:
+                shape = (hb, Np)
+                u = lfsr_mod.counter_uniform(
+                    seed, ctr0 + half_j,
+                    jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+                    + (i * tb + r0).astype(jnp.uint32) + row0,
+                    jax.lax.broadcasted_iota(jnp.uint32, shape, 1) + col0)
+            else:
+                st = lfsr_mod.lfsr_step_n(noise_out_ref[rows, :], decimation)
+                noise_out_ref[rows, :] = st
+                u = lfsr_mod.node_byte_uniforms(st, byte_ref[...])
+            I = neuron_current(m)
+            act = jnp.tanh(bcol_ref[rows, :] * g_ref[...]
+                           * (I + off_ref[...]))
+            decision = act + rg_ref[...] * u + co_ref[...]
+            new = jnp.where(decision >= 0.0, 1.0, -1.0)
+            m_out_ref[rows, :] = jnp.where(mask_refs[c][...] != 0, new, m)
+            return carry
+
+        row_blocks(block, 0)
+
+    def sweep_stats(s_idx):
+        """Accumulate moments/histogram after sweep s_idx completes.
+        Every per-sweep sum is of +-1 products, so it is exact in any
+        order; it is scaled by the sweep's weight once, whole."""
         wgt = meas_ref[s_idx]                                   # scalar
-        # padded batch rows update like real chains; keep them out of
-        # the statistics
-        row_ids = (jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0)
-                   + i * tb)
-        if accumulate:
+        if accumulate and sparse:
+            def block(rows, r0, spin_sum):
+                # padded batch rows update like real chains; keep them out
+                # of the statistics
+                row_ids = (jax.lax.broadcasted_iota(jnp.int32, (hb, 1), 0)
+                           + i * tb + r0)
+                mv = jnp.where(row_ids < B, m_out_ref[rows, :], 0.0)
+                for d, g in _slot_gathers(mv, sh_ref, rot_ref, D,
+                                          _ROLLS_PER_STEP):
+                    corr_ref[pl.ds(d, 1), :] += jnp.sum(mv * g, axis=0,
+                                                        keepdims=True)
+                return spin_sum + jnp.sum(mv, axis=0, keepdims=True)
+
+            corr_ref[...] = jnp.zeros_like(corr_ref)
+            spin_sum = row_blocks(block, jnp.zeros((1, Np), jnp.float32))
+            ssum_ref[...] += wgt * spin_sum
+            for d in range(D):
+                csum_ref[pl.ds(d, 1), :] += wgt * corr_ref[pl.ds(d, 1), :]
+        if not (collect_hist or accumulate and not sparse):
+            return
+        m = m_out_ref[...]
+        row_ids = (jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0) + i * tb)
+        if accumulate and not sparse:
             mv = jnp.where(row_ids < B, m, 0.0)
             ssum_ref[...] += wgt * jnp.sum(mv, axis=0, keepdims=True)
-            if sparse:
-                gathered = _rotate_gather(mv, idx_rows, rot_ref)
-                for d, g in enumerate(gathered):
-                    corr = jnp.sum(mv * g, axis=0, keepdims=True)  # (1, Np)
-                    csum_ref[pl.ds(d, 1), :] += wgt * corr
-            else:
-                csum_ref[...] += wgt * jax.lax.dot_general(
-                    mv, mv, dimension_numbers=(((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)          # m^T m
+            csum_ref[...] += wgt * jax.lax.dot_general(
+                mv, mv, dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)              # m^T m
         if collect_hist:
             # visible node k carries weight 2^k, every other node 0
             codes = jnp.sum(jnp.where(m > 0, binw_ref[...], 0),
@@ -294,40 +396,32 @@ def _kernel(*refs, S: int, tb: int, Np: int, n_b: int, B: int,
                       & (row_ids < B)).astype(jnp.float32)
             hist_ref[...] += wgt * jnp.sum(onehot, axis=0, keepdims=True)
 
-    m_cur = m0_ref[...].astype(jnp.float32)
-    st_cur = noise_carry0
     if lead:
         # clamp re-imposition is idempotent (clamped nodes are excluded
         # from the color masks), so repeating it at a mid-sweep segment
         # boundary is bit-identical to the unsplit launch
-        m_cur = impose_clamp(m_cur)
-        m_cur, st_cur = half_update(m_cur, st_cur, half_offset // 2, 1,
-                                    jnp.uint32(0))
+        impose_clamp()
+        half_update(half_offset // 2, 1, jnp.uint32(0))
         if accumulate or collect_hist:
-            sweep_stats(m_cur, half_offset // 2)
+            sweep_stats(half_offset // 2)
 
     def one_sweep(jj, carry):
-        m, st = carry
-        m = impose_clamp(m)
+        impose_clamp()
         for c in (0, 1):
             hj = (jnp.uint32(lead) + jnp.uint32(2) * jj.astype(jnp.uint32)
                   + jnp.uint32(c))
-            m, st = half_update(m, st, s0 + jj, c, hj)
+            half_update(s0 + jj, c, hj)
         if accumulate or collect_hist:
-            sweep_stats(m, s0 + jj)
-        return m, st
+            sweep_stats(s0 + jj)
+        return carry
 
-    m_fin, st_fin = jax.lax.fori_loop(0, n_full, one_sweep, (m_cur, st_cur))
+    jax.lax.fori_loop(0, n_full, one_sweep, 0)
     if tail:
-        m_fin = impose_clamp(m_fin)
-        m_fin, st_fin = half_update(m_fin, st_fin, s0 + n_full, 0,
-                                    jnp.uint32(lead + 2 * n_full))
-    m_out_ref[...] = m_fin.astype(m_out_ref.dtype)
+        impose_clamp()
+        half_update(s0 + n_full, 0, jnp.uint32(lead + 2 * n_full))
 
     if noise_mode == NOISE_COUNTER:
         noise_out_ref[...] = _noise_state_out(noise_in_ref[...], n_half_eff)
-    else:
-        noise_out_ref[...] = st_fin
 
     if accumulate:
         @pl.when(i == n_b - 1)
@@ -430,14 +524,13 @@ def _launch(
     args = [mp]
     if sparse:
         Dp = _round_up(D, 8)
-        idxp = _pad_axis(_pad_axis(
-            jnp.asarray(nbr_idx, jnp.int32), Dp, 0), 128, 1)
         wp = _pad_axis(_pad_axis(
             jnp.asarray(nbr_w, jnp.float32), Dp, 0), 128, 1)
-        in_specs += [pl.BlockSpec((Dp, Np), lambda i: (0, 0)),  # nbr_idx
+        shifts, rotations = _lane_rotations(nbr_idx, N, Np, _slot_groups(D))
+        in_specs += [pl.BlockSpec((Dp, Np), lambda i: (0, 0)),  # lane shifts
                      pl.BlockSpec((Dp, Np), lambda i: (0, 0)),  # nbr_w
                      _SMEM]                                     # rotations
-        args += [idxp, wp, _lane_rotations(nbr_idx, N, Np)]
+        args += [_pad_axis(shifts, Dp, 0, -1), wp, rotations]
     else:
         Wp = _pad_axis(_pad_axis(dense_W, 128, 0), 128, 1)
         in_specs.append(pl.BlockSpec((Np, Np), lambda i: (0, 0)))  # W
@@ -523,9 +616,10 @@ def _launch(
             row(next_h)]
         aliases = {i_next: 2, i_next + 1: 3}
 
-    out_shape = [jax.ShapeDtypeStruct((Bp, Np), out_dtype), noise_out_shape]
+    out_shape = [jax.ShapeDtypeStruct((Bp, Np), jnp.float32),
+                 noise_out_shape]
     out_specs = [pl.BlockSpec((tb, Np), lambda i: (i, 0)), noise_out_spec]
-    scratch = []
+    scratch = [_VMEM((tb, 1), jnp.float32)]
     if accumulate:
         c_shape = (Dp, Np) if sparse else (Np, Np)
         out_shape += [jax.ShapeDtypeStruct((1, Np), jnp.float32),
@@ -533,6 +627,8 @@ def _launch(
         out_specs += [pl.BlockSpec((1, Np), lambda i: (0, 0)),
                       pl.BlockSpec(c_shape, lambda i: (0, 0))]
         scratch += [_VMEM((1, Np), jnp.float32), _VMEM(c_shape, jnp.float32)]
+        if sparse:
+            scratch.append(_VMEM((Dp, Np), jnp.float32))
     if collect_hist:
         out_shape.append(jax.ShapeDtypeStruct((1, NBp), jnp.float32))
         out_specs.append(pl.BlockSpec((1, NBp), lambda i: (0, 0)))
@@ -547,7 +643,8 @@ def _launch(
 
     outs = pl.pallas_call(
         functools.partial(
-            _kernel, S=S, tb=tb, Np=Np, n_b=n_b, B=B,
+            _kernel, S=S, tb=tb,
+            hb=_block_rows(tb, Np, D) if sparse else tb, Np=Np, n_b=n_b, B=B,
             noise_mode=noise_mode, has_clamp=has_clamp,
             accumulate=accumulate, collect_hist=collect_hist,
             decimation=decimation, sparse=sparse,
@@ -564,7 +661,7 @@ def _launch(
         interpret=interpret,
     )(*args)
 
-    result = [outs[0][:B, :N]]
+    result = [outs[0][:B, :N].astype(out_dtype)]
     if noise_mode == NOISE_COUNTER:
         result.append(outs[1].reshape(2))
     else:
@@ -785,14 +882,14 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
                      collective_id, stream):
     it = iter(refs)
     m0_ref = next(it)                         # (tb, Np) [local|hu|hd]
-    idx_ref, w_ref = next(it), next(it)       # (Dp, Np)
-    rot_ref = next(it)                        # SMEM neighbor rotations
+    sh_ref, w_ref = next(it), next(it)        # (Dp, Np) lane shifts, weights
+    rot_ref = next(it)                        # SMEM per-slot rotations
     h_ref, g_ref, off_ref, rg_ref, co_ref = (next(it) for _ in range(5))
     mask0_ref, mask1_ref = next(it), next(it)
     betas_ref = next(it)                      # (S, tb)
-    sendu_ref, sendd_ref = next(it), next(it)  # (1, Hp) boundary gathers
+    send_ref = next(it)                       # (2, Hp) boundary lane shifts
     srot_ref = next(it)                       # SMEM send rotations
-    inst_ref = next(it)                       # (1, Np) halo lane sources
+    inst_ref = next(it)                       # (1, Np) halo lane shifts
     irot_ref = next(it)                       # SMEM install rotations
     clampm_ref = next(it) if has_clamp else None
     clampv_ref = next(it) if has_clamp else None
@@ -858,8 +955,11 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
 
     pltpu.semaphore_wait(barrier, n_nbr)
 
-    idx_rows = [idx_ref[pl.ds(d, 1), :] for d in range(D)]
-    send_rows = [sendu_ref[...], sendd_ref[...]]
+    def neuron_current(m):
+        acc = jnp.zeros((tb, Np), jnp.float32)
+        for d, g in _slot_gathers(m, sh_ref, rot_ref, D):
+            acc = acc + w_ref[pl.ds(d, 1), :] * g
+        return acc + hrow
 
     def copy(direction, parity):
         """My outgoing boundary copy: direction 0 sends my first-row
@@ -877,7 +977,7 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
 
     def start_exchange(m, parity):
         """Gather boundary spins and fire both neighbor RDMAs."""
-        up, dn = _rotate_gather(m, send_rows, srot_ref)
+        up, dn = _gather_rows(m, send_ref, srot_ref, 0, (0, 1))
         sbuf_ref[0, parity] = up
         sbuf_ref[1, parity] = dn
 
@@ -905,7 +1005,7 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
         src = jnp.concatenate(
             [hu, hd] + ([jnp.zeros((tb, Np - 2 * Hp), jnp.float32)]
                         if Np > 2 * Hp else []), axis=1)
-        (halos,) = _rotate_gather(src, [inst_ref[...]], irot_ref)
+        (halos,) = _gather_rows(src, inst_ref, irot_ref, 0, (0,))
         return jnp.where(halo_lanes, halos, m)
 
     def wait_sends(parity):
@@ -921,10 +1021,7 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
         ctr = ctr0 + half_j
         u = lfsr_mod.counter_uniform(seed, ctr, rows, cols)
         beta_col = betas_ref[pl.ds(s_idx, 1), :].reshape(tb, 1)
-        acc = jnp.zeros((tb, Np), jnp.float32)
-        for d, g in enumerate(_rotate_gather(m, idx_rows, rot_ref)):
-            acc = acc + w_ref[pl.ds(d, 1), :] * g
-        act = jnp.tanh(beta_col * grow * (acc + hrow + offrow))
+        act = jnp.tanh(beta_col * grow * (neuron_current(m) + offrow))
         decision = act + rgrow * u + corow
         new = jnp.where(decision >= 0.0, 1.0, -1.0)
         return jnp.where(masks[c], new, m)
@@ -939,7 +1036,7 @@ def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
         row_ids = jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0)
         mv = jnp.where(row_ids < B, m, 0.0)
         ssum_ref[...] += wgt * jnp.sum(mv, axis=0, keepdims=True)
-        for d, g in enumerate(_rotate_gather(mv, idx_rows, rot_ref)):
+        for d, g in _slot_gathers(mv, sh_ref, rot_ref, D):
             corr = jnp.sum(mv * g, axis=0, keepdims=True)
             csum_ref[pl.ds(d, 1), :] += wgt * corr
 
@@ -1081,8 +1178,11 @@ def sweep_sparse_exchange_pallas(
     row = lambda x: _pad_axis(
         jnp.asarray(x).reshape(1, -1).astype(jnp.float32), 128, 1)
     mp = _pad_axis(_pad_axis(m_ext, tb, 0), 128, 1)
-    idxp = _pad_axis(_pad_axis(jnp.asarray(nbr_idx, jnp.int32), Dp, 0),
-                     128, 1)
+    shifts, rotations = _lane_rotations(nbr_idx, N, Np, _slot_groups(D))
+    send_shifts, send_rotations = _lane_rotations(
+        jnp.stack([jnp.asarray(send_up, jnp.int32),
+                   jnp.asarray(send_dn, jnp.int32)]), H, Np, ((0, 1),))
+    inst_shifts, inst_rotations = _lane_rotations(inst, Np, Np, ((0,),))
     wp = _pad_axis(_pad_axis(jnp.asarray(nbr_w, jnp.float32), Dp, 0),
                    128, 1)
     m0p = _pad_axis(jnp.asarray(mask0).reshape(1, -1).astype(jnp.int8),
@@ -1090,21 +1190,15 @@ def sweep_sparse_exchange_pallas(
     m1p = _pad_axis(jnp.asarray(mask1).reshape(1, -1).astype(jnp.int8),
                     128, 1, 0)
     betasp = _pad_axis(jnp.asarray(betas, jnp.float32), tb, 1)
-    sup = _pad_axis(jnp.asarray(send_up, jnp.int32).reshape(1, -1), 128, 1)
-    sdn = _pad_axis(jnp.asarray(send_dn, jnp.int32).reshape(1, -1), 128, 1)
 
     full = lambda shape: pl.BlockSpec(shape, lambda: tuple(
         0 for _ in shape))
     in_specs = [full((tb, Np)), full((Dp, Np)), full((Dp, Np)), _SMEM]
-    args = [mp, idxp, wp, _lane_rotations(nbr_idx, N, Np)]
-    in_specs += [full((1, Np))] * 7 + [full((S, tb)),
-                                       full((1, Hp)), full((1, Hp)), _SMEM]
+    args = [mp, _pad_axis(shifts, Dp, 0, -1), wp, rotations]
+    in_specs += [full((1, Np))] * 7 + [full((S, tb)), full((2, Hp)), _SMEM]
     args += [row(h), row(gain), row(off), row(rand_gain), row(comp_off),
-             m0p, m1p, betasp, sup, sdn,
-             _lane_rotations(jnp.stack([jnp.asarray(send_up, jnp.int32),
-                                        jnp.asarray(send_dn, jnp.int32)]),
-                             H, Np),
-             jnp.asarray(inst), _lane_rotations(inst, Np, Np)]
+             m0p, m1p, betasp, send_shifts, send_rotations,
+             inst_shifts, inst_rotations]
     in_specs += [full((1, Np)), _SMEM]
     if has_clamp:
         in_specs += [full((1, Np)), full((tb, Np))]

@@ -8,10 +8,14 @@ Pallas kernel runs the identical op sequence as the sparse jnp ref.
 Covers masked graphs, clamped CD phases, per-chain (S, B) tempering betas,
 and all three noise kinds (philox / counter / lfsr).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import pbit, tasks
 from repro.core.cd import CDConfig, PBitMachine, make_cd_step
@@ -25,12 +29,30 @@ from repro.core.hardware import (
     program_weights_sparse,
     sample_mismatch,
 )
+from repro.kernels import ops as kernel_ops
+from repro.kernels.sweep_fused import (
+    _gather_rows,
+    _lane_rotations,
+    _slot_groups,
+)
 
 SPARSE_BACKENDS = ("sparse", "fused_sparse")
 
 
 def _graph(rows=2, cols=3, masked=((0, 1),)):
     return make_chimera(rows, cols, masked_cells=masked)
+
+
+_GRAPHS = {"small": _graph, "chip": make_chip_graph}
+
+
+def _tiles(monkeypatch, block_b):
+    """Run the fused engines at chain tiles of ``block_b`` (None: the
+    engines' default)."""
+    if block_b is not None:
+        for name in ("fused_sweeps", "fused_visible_hist"):
+            monkeypatch.setattr(kernel_ops, name, functools.partial(
+                getattr(kernel_ops, name), block_b=block_b))
 
 
 def _chip(g, seed=0, scale=0.3):
@@ -85,6 +107,54 @@ def test_attach_sparse_gathers_dense_weights():
     np.testing.assert_array_equal(np.asarray(chip.nbr_w), want)
 
 
+@pytest.mark.parametrize("graph,per_slot,union", [
+    pytest.param(make_chip_graph, [10, 10, 10, 10, 10, 3], 19, id="chip"),
+    pytest.param(functools.partial(make_chimera, 4, 5,
+                                   masked_cells=((1, 2), (3, 0))),
+                 None, None, id="masked"),
+    pytest.param(functools.partial(make_chimera, 16, 16), None, None,
+                 id="c16"),
+])
+def test_lane_rotations_reproduce_gather(graph, per_slot, union):
+    """Each slot's own lane rotations, alone or shared within the
+    kernel's slot groups, rebuild ``jnp.take(m, nbr_idx[d], axis=1)``
+    exactly inside a kernel (one rotation a step, or 16 with the padded
+    table)."""
+    g = graph()
+    idx = np.asarray(g.neighbor_table()[0])
+    D, n = idx.shape
+    Np = -(-n // 128) * 128
+    slots = tuple((d,) for d in range(D))
+    _, table = _lane_rotations(idx, n, Np, slots)
+    counts = np.asarray(table).reshape(D, 1 + Np)[:, 0]
+    if per_slot is not None:
+        assert counts.tolist() == per_slot
+        _, everything = _lane_rotations(idx, n, Np, (tuple(range(D)),))
+        assert int(everything[0]) == union
+    rng = np.random.default_rng(n)
+    m = jnp.asarray(rng.normal(size=(8, Np)), jnp.float32)  # all distinct
+    want = np.stack([np.take(np.asarray(m)[:, :n], idx[d], axis=1)
+                     for d in range(D)])
+    for groups, per_step in ((slots, 1), (_slot_groups(D), 16)):
+        shifts, table = _lane_rotations(idx, n, Np, groups)
+
+        def kernel(x_ref, sh_ref, rot_ref, o_ref, groups=groups,
+                   per_step=per_step):
+            for gi, rows in enumerate(groups):
+                gathers = _gather_rows(x_ref[...], sh_ref, rot_ref, gi,
+                                       rows, per_step)
+                for r, gr in zip(rows, gathers):
+                    o_ref[r] = gr
+
+        out = pl.pallas_call(
+            kernel, out_shape=jax.ShapeDtypeStruct((D, 8, Np), jnp.float32),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pltpu.VMEM),
+                      pl.BlockSpec(memory_space=pltpu.SMEM)],
+            interpret=True)(m, shifts, table)
+        np.testing.assert_array_equal(np.asarray(out)[:, :, :n], want)
+
+
 # ---------------------------------------------------------------------------
 # bit-exact sampling parity
 # ---------------------------------------------------------------------------
@@ -108,12 +178,21 @@ def test_sparse_ref_matches_dense_ref(kind, masked):
     np.testing.assert_array_equal(np.asarray(ns_s), np.asarray(ns_d))
 
 
-@pytest.mark.parametrize("kind", ["counter", "lfsr"])
-def test_fused_sparse_matches_ref(kind):
+@pytest.mark.parametrize("kind,graph,B,block_b", [
+    pytest.param("counter", "small", 12, None, id="counter"),
+    pytest.param("lfsr", "small", 12, None, id="lfsr"),
+    # the chip graph's 512-lane rows are walked in 16- or 8-row blocks:
+    # 36 chains in 32-chain tiles leave a last tile of 4 chains and 28
+    # padded rows; 40 chains in one tile split into five 8-row blocks
+    pytest.param("counter", "chip", 36, 32, id="counter-chip-B36-tile32"),
+    pytest.param("lfsr", "chip", 40, 128, id="lfsr-chip-B40-tile40"),
+    pytest.param("counter", "chip", 20, 8, id="counter-chip-B20-tile8"),
+])
+def test_fused_sparse_matches_ref(kind, graph, B, block_b, monkeypatch):
     """Sweep-resident sparse kernel == dense ref, multiple batch tiles."""
-    g = _graph()
+    _tiles(monkeypatch, block_b)
+    g = _GRAPHS[graph]()
     chip = _chip(g, seed=11)
-    B = 12
     m0 = pbit.random_spins(jax.random.PRNGKey(2), B, g.n_nodes)
     state, step = _noise(kind, g, B, jax.random.PRNGKey(3))
     betas = jnp.linspace(0.3, 2.0, 9)
@@ -126,13 +205,23 @@ def test_fused_sparse_matches_ref(kind):
     np.testing.assert_array_equal(np.asarray(ns_f), np.asarray(ns_d))
 
 
-@pytest.mark.parametrize("kind", ["philox", "counter", "lfsr"])
-def test_sparse_clamped_stats_match(kind):
+@pytest.mark.parametrize("kind,graph,B,block_b", [
+    pytest.param("philox", "small", 8, None, id="philox"),
+    pytest.param("counter", "small", 8, None, id="counter"),
+    pytest.param("lfsr", "small", 8, None, id="lfsr"),
+    # moments summed over uneven row blocks and padded rows (see
+    # test_fused_sparse_matches_ref)
+    pytest.param("counter", "chip", 36, 32, id="counter-chip-B36-tile32"),
+    pytest.param("lfsr", "chip", 40, 128, id="lfsr-chip-B40-tile40"),
+])
+def test_sparse_clamped_stats_match(kind, graph, B, block_b, monkeypatch):
     """Clamped (CD positive phase) gibbs_stats: spins bit-exact, moments
     exact on the scan path and fp-tolerance on the fused kernel."""
-    g = _graph(rows=1, cols=2, masked=())
+    _tiles(monkeypatch, block_b)
+    g = (_graph(rows=1, cols=2, masked=()) if graph == "small"
+         else _GRAPHS[graph]())
     chip = _chip(g, seed=13)
-    B, n = 8, g.n_nodes
+    n = g.n_nodes
     color = jnp.asarray(g.color)
     edges = jnp.asarray(g.edges)
     clamp_mask = jnp.zeros((n,), bool).at[jnp.array([0, 5, 9])].set(True)
@@ -333,16 +422,28 @@ def test_cd_step_matches_dense_backend(backend):
 # ---------------------------------------------------------------------------
 # streaming visible histogram
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", ["ref", "sparse", "fused",
-                                     "fused_sparse"])
-def test_streaming_hist_matches_trajectory(backend):
+@pytest.mark.parametrize("backend,graph,B,block_b", [
+    pytest.param("ref", "small", 16, None, id="ref"),
+    pytest.param("sparse", "small", 16, None, id="sparse"),
+    pytest.param("fused", "small", 16, None, id="fused"),
+    pytest.param("fused_sparse", "small", 16, None, id="fused_sparse"),
+    # five 8-row blocks in a 40-chain tile, then 24-chain tiles of three
+    pytest.param("fused_sparse", "chip", 40, 128,
+                 id="fused_sparse-chip-B40-tile40"),
+    pytest.param("fused_sparse", "chip", 40, 24,
+                 id="fused_sparse-chip-B40-tile24"),
+])
+def test_streaming_hist_matches_trajectory(backend, graph, B, block_b,
+                                           monkeypatch):
     """gibbs_visible_hist == histogramming the collected trajectory, for
     every backend (the fused ones accumulate in-kernel)."""
     from repro.core import energy
 
-    g = _graph(rows=1, cols=2, masked=())
+    _tiles(monkeypatch, block_b)
+    g = (_graph(rows=1, cols=2, masked=()) if graph == "small"
+         else _GRAPHS[graph]())
     chip = _chip(g, seed=21)
-    B, sweeps, burn_in = 16, 40, 8
+    sweeps, burn_in = 40, 8
     vis = np.array([0, 3, 9])
     color = jnp.asarray(g.color)
     m0 = pbit.random_spins(jax.random.PRNGKey(6), B, g.n_nodes)

@@ -16,8 +16,8 @@ from repro.core.chimera import make_chimera
 from repro.core.hardware import ideal_chip
 from repro.kernels.ops import ref_half_sweep
 from repro.kernels.pbit_update import pbit_half_sweep_pallas
-from repro.kernels.ref import pbit_half_sweep_ref
-from repro.kernels.sweep_fused import sweep_fused_pallas
+from repro.kernels.ref import pbit_half_sweep_ref, pbit_sparse_half_sweep_ref
+from repro.kernels.sweep_fused import sweep_fused_pallas, sweep_sparse_pallas
 
 
 def _chip_problem(seed=0, rows=2, cols=3, scale=0.3):
@@ -182,9 +182,19 @@ def test_in_kernel_lfsr_bitexact_states():
     assert (np.asarray(state_k) != 0).all()
 
 
-@pytest.mark.parametrize("B,N,block_b", [(3, 77, 8), (17, 130, 8),
-                                         (64, 440, 32)])
-def test_fused_counter_odd_shapes(B, N, block_b):
+@pytest.mark.parametrize("B,N,block_b,slots", [
+    pytest.param(3, 77, 8, False, id="3-77-8"),
+    pytest.param(17, 130, 8, False, id="17-130-8"),
+    pytest.param(64, 440, 32, False, id="64-440-32"),
+    # the slot layout on random degree-6 tables (hundreds of lane
+    # rotations a slot group): rows walked in blocks of 16 (512 lanes)
+    # or 8 (1,024 lanes), with chains that fill no block or tile evenly
+    pytest.param(37, 440, 32, True, id="slots-37-440-32"),
+    pytest.param(20, 1000, 128, True, id="slots-20-1000-128"),
+    # a 12-chain tile is no multiple of 8: one block of all its rows
+    pytest.param(12, 440, 12, True, id="slots-12-440-12"),
+])
+def test_fused_counter_odd_shapes(B, N, block_b, slots):
     """Non-aligned shapes pad cleanly; counter mode works off-Chimera."""
     rng = np.random.default_rng(B + N)
     m0 = jnp.asarray(rng.integers(0, 2, (B, N)) * 2 - 1, jnp.float32)
@@ -195,6 +205,20 @@ def test_fused_counter_odd_shapes(B, N, block_b):
     mask0, mask1 = jnp.asarray(color == 0), jnp.asarray(color == 1)
     betas = jnp.asarray(rng.uniform(0.2, 1.5, (3, B)), jnp.float32)
     state = jnp.asarray([42, 5], jnp.uint32)
+    if slots:
+        # neighbours ascending per node, a fifth of the slots unused
+        # (pointing home, weight 0)
+        idx = np.sort(rng.integers(0, N, (6, N)), axis=0)
+        idx = np.where(rng.random((6, N)) < 0.2, np.arange(N), idx)
+        w = np.where(idx == np.arange(N), 0.0, rng.normal(size=(6, N)) * 0.3)
+        idx, w = jnp.asarray(idx, jnp.int32), jnp.asarray(w, jnp.float32)
+
+        def half_sweep(m, mk, beta, u):
+            return pbit_sparse_half_sweep_ref(m, idx, w, h, g, o, rg, co, mk,
+                                              beta, u)
+    else:
+        def half_sweep(m, mk, beta, u):
+            return pbit_half_sweep_ref(m, W, h, g, o, rg, co, mk, beta, u)
 
     rows = jnp.arange(B, dtype=jnp.uint32)[:, None]
     cols = jnp.arange(N, dtype=jnp.uint32)[None, :]
@@ -203,11 +227,16 @@ def test_fused_counter_odd_shapes(B, N, block_b):
         for c, mk in ((0, mask0), (1, mask1)):
             u = lfsr.counter_uniform(jnp.uint32(42), jnp.uint32(ctr), rows,
                                      cols)
-            m = pbit_half_sweep_ref(m, W, h, g, o, rg, co, mk, betas[s], u)
+            m = half_sweep(m, mk, betas[s], u)
             ctr += 1
-    m_k, state_k = sweep_fused_pallas(
-        m0, W, h, g, o, rg, co, mask0, mask1, betas, state,
-        noise_mode="counter", block_b=block_b, interpret=True)
+    if slots:
+        m_k, state_k = sweep_sparse_pallas(
+            m0, idx, w, h, g, o, rg, co, mask0, mask1, betas, state,
+            noise_mode="counter", block_b=block_b, interpret=True)
+    else:
+        m_k, state_k = sweep_fused_pallas(
+            m0, W, h, g, o, rg, co, mask0, mask1, betas, state,
+            noise_mode="counter", block_b=block_b, interpret=True)
     np.testing.assert_array_equal(np.asarray(m_k), np.asarray(m))
     assert int(state_k[1]) == ctr
 

@@ -99,6 +99,31 @@ def test_sweep_sparse_compiles(one_chip, chip_graph, variant):
                    .compile())
 
 
+# The scoped VMEM the kernel needed before its gather moved into row
+# blocks, as the least `vmem_limit_bytes` it compiled under (4 KiB steps):
+# the sample cell's call, and a band tile near `band_vmem_feasible`'s
+# calibrated limit (1,024 spins + 128 halo lanes x 128 chains).
+@pytest.mark.parametrize("chains,n,sweeps,band,before", [
+    pytest.param(256, 440, 512, False, 5_443_584, id="sample"),
+    pytest.param(128, 1152, 8, True, 12_349_440, id="band"),
+])
+def test_sweep_sparse_scoped_vmem_no_larger(one_chip, monkeypatch, chains, n,
+                                            sweeps, band, before):
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(pltpu, "CompilerParams", functools.partial(
+        pltpu.CompilerParams, vmem_limit_bytes=before))
+    jax.clear_caches()     # no trace from another limit
+    sh = functools.partial(_shape, one_chip)
+    args = [sh((chains, n), jnp.float32), sh((6, n), jnp.int32),
+            sh((6, n), jnp.float32), *[sh((n,), jnp.float32)] * 5,
+            *[sh((n,), jnp.bool_)] * 2, sh((sweeps, chains), jnp.float32),
+            sh((2,), jnp.uint32)]
+    if band:
+        args += [None, None, None, None, sh((2,), jnp.uint32)]
+    _assert_kernel(sweep_fused.sweep_sparse_pallas.lower(
+        *args, interpret=False).compile())
+
+
 @pytest.mark.parametrize("noise", ["counter", "lfsr"])
 def test_sweep_fused_dense_compiles(one_chip, chip_graph, noise):
     args = _common(one_chip, chip_graph.n_nodes)
