@@ -22,11 +22,6 @@ Reported per configuration:
     sweep from the partition plan and the TPU ICI-vs-HBM napkin ratio
     (docs/sharding.md).  Never run concurrently with the test suite on
     a small box — timings distort.
-  * `weight_streaming` (N = 440): runtime program swaps into a warm
-    Session (`sample_program`) vs a fresh-Session recompile, the
-    double-buffered upload kernel vs serialized launches, and
-    `sample_fleet` throughput vs K stacked programs
-    (docs/api.md §Program lifecycle).
   * `sync_policies` (N = 440, 2048; k in {1, 4, inf}): the first-class
     `api.Sync` policies on a forced 2-device host — measured us/sweep
     for the per-sweep-launch baseline (one 1-sweep Session call per
@@ -54,11 +49,7 @@ from benchmarks.common import emit, save_json, timed, timer
 from repro.core.chimera import make_chimera, make_chip_graph
 from repro.kernels.pbit_update import pbit_half_sweep_pallas
 from repro.kernels.ref import pbit_half_sweep_ref
-from repro.kernels.sweep_fused import (
-    sweep_fused_pallas,
-    sweep_sparse_pallas,
-    sweep_sparse_stream_pallas,
-)
+from repro.kernels.sweep_fused import sweep_fused_pallas, sweep_sparse_pallas
 from repro.launch.mesh import HBM_BW
 from repro.launch.mesh import PEAK_FLOPS_BF16 as PEAK_FLOPS
 
@@ -714,148 +705,6 @@ def bench_psl_embed(quick: bool = False) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# runtime weight streaming: program swaps, double-buffered uploads, fleets
-# ---------------------------------------------------------------------------
-def bench_weight_streaming(quick: bool = False) -> dict:
-    """The `weight_streaming` section (docs/api.md §Program lifecycle).
-
-    * ``program_swap_ms`` vs ``session_recompile_ms`` at the paper chip
-      (N=440): retargeting a warm Session to fresh couplings through
-      `Session.make_program` + `sample_program` — an O(E) operand copy
-      into the compiled executable — against building a new
-      `api.Session` and paying its first-call XLA compile, which is what
-      a value-keyed fingerprint used to force per chip instance.
-    * ``double_buffered`` vs ``serialized``: an L-launch program chain
-      through `sweep_sparse_stream_pallas` (the NEXT program stages into
-      a second VMEM slot while the CURRENT one sweeps — the SpikeHard
-      DMA overlap) vs the same chain as plain `sweep_sparse_pallas`
-      launches with the program swapped on the host between launches.
-      CPU-interpret wall times; ``staged_bytes_per_launch`` is the
-      modeled upload the overlap hides on a real accelerator.
-    * ``fleet`` — `Session.sample_fleet` throughput vs K stacked
-      programs (mismatch draws / tenants / CD replicas) through ONE
-      vmapped executable, against K sequential `sample_program` calls.
-    """
-    from repro import api
-    from repro.core.cd import PBitMachine
-
-    B, S, L = (8, 4, 3) if quick else (16, 8, 4)
-    g = make_chip_graph()
-    mach = PBitMachine.create(g, jax.random.PRNGKey(0), sparse=True,
-                              noise="counter")
-    spec = mach.sampler_spec(schedule=api.Constant(0.7, n_sweeps=S),
-                             chains=B)
-    ses = api.Session(spec)
-
-    def codes(seed):
-        r = np.random.default_rng(seed)
-        return (jnp.asarray(r.integers(-60, 60, g.n_edges), jnp.int32),
-                jnp.asarray(r.integers(-15, 15, g.n_nodes), jnp.int32))
-
-    m0 = ses.random_spins(jax.random.PRNGKey(1))
-    ns = ses.noise_state(jax.random.PRNGKey(2))
-
-    # -- program swap vs Session recompile
-    timed(lambda: ses.sample_program(ses.make_program(*codes(0)), m0,
-                                     ns)[0])  # compile once
-    swaps = []
-    for seed in range(1, 4 if quick else 6):
-        J, h = codes(seed)
-        t, _ = timed(lambda: ses.sample_program(ses.make_program(J, h),
-                                                m0, ns)[0])
-        swaps.append(t)
-    swap_s = sorted(swaps)[len(swaps) // 2]
-
-    recompiles = []
-    for _ in range(1 if quick else 2):
-        fresh = api.Session(spec)
-        chip = fresh.program_edges(*codes(1))
-        t, _ = timed(lambda: fresh.sample(chip, m0, ns)[0])
-        recompiles.append(t)
-    recompile_s = min(recompiles)
-
-    out = {
-        "note": "runtime weight streaming: O(E) program swaps into a "
-                "compiled executable vs per-problem Session recompiles, "
-                "the double-buffered upload kernel, and the vmapped "
-                "K-program fleet axis (docs/api.md §Program lifecycle)",
-        "N": int(g.n_nodes), "B": B, "S": S, "backend": "sparse",
-        "program_swap_ms": swap_s * 1e3,
-        "session_recompile_ms": recompile_s * 1e3,
-        "swap_speedup": recompile_s / swap_s,
-    }
-
-    # -- double-buffered vs serialized upload (kernel-level, L launches)
-    chips = [ses.program_edges(*codes(40 + i)) for i in range(L)]
-    c0 = chips[0]
-    masks = (jnp.asarray(g.color == 0), jnp.asarray(g.color == 1))
-    betas = jnp.full((S, B), 0.7, jnp.float32)
-    ns0 = jnp.asarray([1234, 0], jnp.uint32)
-    block_b = min(128, B)
-
-    def serialized():
-        m, st = m0, ns0
-        for chip in chips:
-            m, st = sweep_sparse_pallas(
-                m, c0.nbr_idx, chip.nbr_w, chip.h, chip.tanh_gain,
-                chip.tanh_offset, chip.rand_gain, chip.comp_offset,
-                *masks, betas, st, noise_mode="counter",
-                block_b=block_b, interpret=True)
-        return m
-
-    def double_buffered():
-        m, st = m0, ns0
-        w, h = chips[0].nbr_w, chips[0].h
-        for i, chip in enumerate(chips):
-            nxt = chips[(i + 1) % L]
-            m, st, w, h = sweep_sparse_stream_pallas(
-                m, c0.nbr_idx, w, h, chip.tanh_gain, chip.tanh_offset,
-                chip.rand_gain, chip.comp_offset, *masks, betas, st,
-                nxt.nbr_w, nxt.h, block_b=block_b, interpret=True)
-        return m
-
-    iters = 1 if quick else 3
-    t_ser = timer(serialized, iters=iters)
-    t_db = timer(double_buffered, iters=iters)
-    out["upload"] = {
-        "launches": L, "sweeps_per_launch": S,
-        "serialized_us_per_launch": t_ser / L * 1e6,
-        "double_buffered_us_per_launch": t_db / L * 1e6,
-        "staged_bytes_per_launch": int(c0.nbr_w.size * 4 + c0.h.size * 4),
-    }
-
-    # -- fleet axis: K programs through one vmapped executable
-    fleet_rows = []
-    for K in (1, 2, 4) if quick else (1, 2, 4, 8):
-        progs = api.stack_programs(
-            [ses.make_program(*codes(70 + k)) for k in range(K)])
-        mK = jnp.broadcast_to(m0, (K, *m0.shape))
-        nsK = jnp.stack([ses.noise_state(jax.random.PRNGKey(90 + k))
-                         for k in range(K)])
-        t_fleet = timer(lambda: ses.sample_fleet(progs, mK, nsK)[0],
-                        iters=iters)
-
-        def sequential():
-            outs = []
-            for k in range(K):
-                p = jax.tree_util.tree_map(lambda x, k=k: x[k], progs)
-                outs.append(ses.sample_program(p, mK[k], nsK[k])[0])
-            return outs
-
-        t_seq = timer(sequential, iters=iters)
-        fleet_rows.append({
-            "K": K,
-            "fleet_us_per_call": t_fleet * 1e6,
-            "sequential_us_per_call": t_seq * 1e6,
-            "fleet_chain_sweeps_per_sec": K * B * S / t_fleet,
-            "sequential_chain_sweeps_per_sec": K * B * S / t_seq,
-            "fleet_speedup": t_seq / t_fleet,
-        })
-    out["fleet"] = fleet_rows
-    return out
-
-
-# ---------------------------------------------------------------------------
 # dense vs Chimera-native block-sparse
 # ---------------------------------------------------------------------------
 def dense_vs_sparse_model(B: int, N: int, S: int,
@@ -946,24 +795,8 @@ def _write_root_merge(results: dict) -> None:
     root.write_text(json.dumps(merged, indent=1))
 
 
-def _emit_streaming(ws: dict) -> None:
-    emit("kernel_program_swap_ms_N440", ws["program_swap_ms"],
-         f"recompile={ws['session_recompile_ms']:.0f}ms "
-         f"({ws['swap_speedup']:.0f}x)")
-    up = ws["upload"]
-    emit("kernel_stream_double_buffered_us",
-         up["double_buffered_us_per_launch"],
-         f"serialized={up['serialized_us_per_launch']:.0f}us, "
-         f"staged={up['staged_bytes_per_launch']}B")
-    top = ws["fleet"][-1]
-    emit(f"kernel_fleet_k{top['K']}_chain_sweeps_per_sec",
-         top["fleet_chain_sweeps_per_sec"],
-         f"sequential={top['sequential_chain_sweeps_per_sec']:.0f} "
-         f"({top['fleet_speedup']:.2f}x)")
-
-
 def run(quick: bool = False, psl_only: bool = False,
-        streaming_only: bool = False, halo_only: bool = False) -> dict:
+        halo_only: bool = False) -> dict:
     if halo_only:
         # regenerate just the kernel-resident halo-exchange section
         # (cheap next to the full kernel sweeps) and merge it into the
@@ -983,16 +816,6 @@ def run(quick: bool = False, psl_only: bool = False,
             emit(f"psl_{row['circuit']}_correct_rate", row["correct_rate"],
                  f"chain_len={row['chain_length']}, "
                  f"valid={row['clause_valid_fraction']:.2%}")
-        if not quick:
-            _write_root_merge(results)
-        return results
-
-    if streaming_only:
-        # regenerate just the weight-streaming section (cheap next to the
-        # full kernel sweeps) and merge it into the tracked root JSON
-        results = {"weight_streaming": bench_weight_streaming(quick)}
-        _emit_streaming(results["weight_streaming"])
-        save_json("weight_streaming", results["weight_streaming"])
         if not quick:
             _write_root_merge(results)
         return results
@@ -1037,9 +860,6 @@ def run(quick: bool = False, psl_only: bool = False,
     # PSL compiler: embedding overhead + forward correct-answer rate
     results["psl_embed"] = bench_psl_embed(quick)
 
-    # runtime weight streaming: swaps, double-buffered uploads, fleets
-    results["weight_streaming"] = bench_weight_streaming(quick)
-
     chip = results["configs"][0]
     emit("kernel_session_dispatch_N440",
          results["session_dispatch"]["session_us_per_call"],
@@ -1074,7 +894,6 @@ def run(quick: bool = False, psl_only: bool = False,
         emit(f"psl_{row['circuit']}_correct_rate", row["correct_rate"],
              f"chain_len={row['chain_length']}, "
              f"valid={row['clause_valid_fraction']:.2%}")
-    _emit_streaming(results["weight_streaming"])
 
     save_json("kernel_pbit_update", results)
     if not quick:
@@ -1092,10 +911,7 @@ if __name__ == "__main__":
                     help="small shapes / single iteration (CI smoke)")
     ap.add_argument("--psl-only", action="store_true",
                     help="regenerate only the psl_embed section")
-    ap.add_argument("--streaming-only", action="store_true",
-                    help="regenerate only the weight_streaming section")
     ap.add_argument("--halo-only", action="store_true",
                     help="regenerate only the halo_fused section")
     args = ap.parse_args()
-    run(quick=args.quick, psl_only=args.psl_only,
-        streaming_only=args.streaming_only, halo_only=args.halo_only)
+    run(quick=args.quick, psl_only=args.psl_only, halo_only=args.halo_only)

@@ -140,4 +140,4 @@ if plan is not None:
           f"TPUv5e napkin: ICI/HBM time ratio "
           f"{napkin['ici_over_hbm']:.3f} per device, "
           f"{napkin['ici_latency_share']:.0%} of ICI time is per-exchange "
-          f"latency (the cost the kernel-resident exchange amortizes)")
+          f"latency (the cost a larger halo_every amortizes)")

@@ -12,8 +12,8 @@ backend, noise) bucket then serves every program bit-exactly:
     m, ns, _ = session.sample_program(prog, m, ns, betas)   # zero retrace
 
 Swapping problems is a host->device copy of O(E) codes, not an XLA
-compile — `benchmarks/bench_kernel.py`'s ``weight_streaming`` section
-measures the gap.  Stacking programs along a leading axis
+compile — the benchmark cell `chip440.sample` sends a fresh program
+every call.  Stacking programs along a leading axis
 (`stack_programs`) gives the **fleet axis**: `Session.sample_fleet`
 vmaps one executable over K mismatch draws / tenants / CD replicas.
 

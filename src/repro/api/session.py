@@ -640,7 +640,8 @@ class Session:
 
         One executable per optional-field structure serves every program
         on this Session's spec — swapping problems is an O(E) host→device
-        copy, never a retrace (benchmarks `weight_streaming` section).
+        copy, never a retrace (benchmark cell `chip440.sample` sends a
+        fresh program every call).
         Beta priority: explicit ``betas`` arg > ``prog.betas`` > the
         spec's schedule.
         """

@@ -75,9 +75,12 @@ def band_vmem_feasible(spec: "SamplerSpec") -> bool:
     The kernel keeps about two dozen f32 tiles of (chain block, band
     plus halos) live in VMEM at once — spins in and out, double-buffered,
     noise, fields, lane rotations — so a tile may take at most 1/24 of
-    the 16 MB core.  The chain block is 128 chains (all of a device's
-    chains when the kernel owns mid-launch exchanges).  Calibrated by
-    compiling for a v5e: 147k-element tiles compile, 263k do not."""
+    the 16 MB core.  The chain block is 128 chains.  With mid-launch
+    exchanges the rule assumes a tile of all of a device's chains, which
+    the exchange windows (ordinary 128-chain launches) never need: a
+    conservative margin until the rule is recalibrated.
+    Calibrated by compiling for a v5e: 147k-element tiles compile, 263k
+    do not."""
     from repro.core.distributed import partition_size, plan_row_partition
     part, sync = spec.partitioning(), spec.sync_policy()
     plan = plan_row_partition(spec.graph,
@@ -214,9 +217,9 @@ class Partition:
     execution runs the slot-layout scan path ("sparse" backend
     semantics) or, with counter noise and a `Sync` whose exchange
     cadence the kernel can own (``halo_every <= sweeps_per_launch``, or
-    no mid-launch exchange at all), the sweep-resident fused kernel with
-    kernel-resident halo exchange (docs/kernels.md §In-kernel halo
-    exchange).  Either way it needs noise that regenerates per
+    no mid-launch exchange at all), the sweep-resident fused kernel, one
+    call per window between exchange points (docs/kernels.md §Fused
+    exchange windows).  Either way it needs noise that regenerates per
     (chain, node) coordinate, so ``noise`` must be "counter" or "lfsr".
     """
 
@@ -259,11 +262,11 @@ class Sync:
       launch between exchange points.  With counter noise the engine
       runs the launch through the sweep-resident Pallas kernel
       (`kernels/shard_sweep.py::fused_shard_sweeps`) — spins
-      VMEM-resident, in-kernel RNG.  Mid-launch exchange points no
-      longer break the fusion: any ``halo_every <= sweeps_per_launch``
-      runs with the halo refresh INSIDE the kernel (RDMA on TPU meshes,
-      a bit-exact segmented emulation elsewhere — docs/kernels.md
-      §In-kernel halo exchange).
+      VMEM-resident, in-kernel RNG.  Mid-launch exchange points keep
+      the fusion: with ``halo_every <= sweeps_per_launch`` the launch
+      runs as half-sweep windows of the kernel, one per exchange point,
+      with a ppermute between them, in one jitted graph (docs/kernels.md
+      §Fused exchange windows).
 
     ``halo_every=1`` keeps the sharded == single-device bit-exactness
     contract; anything looser is a *documented, measured* approximation —
@@ -321,9 +324,10 @@ class Sync:
     @property
     def fused_compatible(self) -> bool:
         """Can a fused backend run this policy?  True when there is no
-        mid-launch exchange (`kernel_fusible`) or when the kernel can own
-        the refresh itself — the kernel-resident halo exchange supports
-        any ``halo_every <= sweeps_per_launch``.  The infeasible window
+        mid-launch exchange (`kernel_fusible`) or when
+        ``halo_every <= sweeps_per_launch``: the launch then runs as
+        half-sweep windows of the kernel split at the exchange points
+        (`kernels/ref.py::halo_exchange_segments`).  The infeasible window
         is ``sweeps_per_launch < halo_every < 2 * sweeps_per_launch``:
         exchange points too sparse for the resident segments yet not at
         launch boundaries only."""
@@ -557,13 +561,13 @@ class SamplerSpec:
             if not sync.fused_compatible:
                 S = sync.sweeps_per_launch
                 raise ValueError(
-                    f"backend 'fused_sparse' runs whole launches inside one "
-                    f"kernel; the kernel-resident halo exchange supports "
+                    f"backend 'fused_sparse' runs each launch as kernel "
+                    f"windows between exchange points, which supports "
                     f"halo_every <= sweeps_per_launch, but sync={sync} has "
                     f"halo_every={sync.halo_every} with sweeps_per_launch="
                     f"{S} (exchange points {sync.exchange_points()}); "
                     f"nearest legal Sync: lower halo_every to {S} "
-                    f"(kernel-resident exchange), raise it to >= {2 * S} "
+                    f"(exchange windows), raise it to >= {2 * S} "
                     f"or math.inf (launch-boundary exchange only), or use "
                     f"backend='sparse'")
             if self.noise != "counter":

@@ -30,17 +30,10 @@ wants.  This module is the sharded execution layer behind
     runtime weight streaming works through the sharded path unchanged:
     one compiled executable per (graph-shape, partition, sync) bucket
     serves every `api.Program` (`Session.sample_program`).
-
-The old structure-of-arrays pod lattice (`LatticeSpec`/`make_sk_lattice`)
-remains as the O(N) *instance generator* for SK-style lattices, but its
-private update loop is gone: `lattice_to_chip` converts the SoA couplings
-into the shared `EffectiveChip` slot layout and `make_lattice_anneal`
-drives the same `api.Session` engine every other workload uses.
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Any
 
 import jax
@@ -49,15 +42,14 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import lfsr as lfsr_mod
-from repro.core.chimera import ChimeraGraph, make_chimera
-from repro.core.hardware import EffectiveChip, HardwareConfig
+from repro.core.chimera import ChimeraGraph
+from repro.core.hardware import EffectiveChip
 from repro.kernels.ref import (
     field_decision_update,
     halo_exchange_segments,
     sparse_neuron_input,
 )
 from repro.kernels.shard_sweep import (
-    fused_shard_exchange_resident,
     fused_shard_sweeps,
     halo_exchange,
     halo_half_sweep,
@@ -419,10 +411,10 @@ class ShardedEngine:
     between exchange points every band samples against a *stale* halo —
     the deterministic, seeded emulation of the chip's clockless fabric.
     ``Sync()`` (barrier, halo_every=1) reproduces the single-device
-    trajectory bit for bit; under a launch-resident counter-noise policy
-    the whole launch runs inside the sweep-resident Pallas kernel
-    (`kernels/shard_sweep.py::fused_shard_sweeps`, backend
-    "fused_sparse").
+    trajectory bit for bit.  On backend "fused_sparse" (counter noise)
+    the launch runs in the sweep-resident Pallas kernel
+    (`kernels/shard_sweep.py::fused_shard_sweeps`), one call per window
+    between exchange points, with a ppermute between windows.
     """
 
     def __init__(self, graph: ChimeraGraph, mesh: Mesh, partition,
@@ -458,19 +450,6 @@ class ShardedEngine:
             raise ValueError(f"chains={chains} not divisible by the "
                              f"chain-axis size {self.n_chain}")
         self.b_loc = chains // self.n_chain
-        # fused-resident-exchange: with mid-launch exchange points the
-        # KERNEL owns the halo refresh.  On a real TPU mesh (single named
-        # rows axis, compiled mode) one RDMA launch runs the whole
-        # schedule; everywhere else (interpret mode, CPU hosts, or
-        # REPRO_HALO_EMULATE=1) the engine emulates the same launch
-        # bit-exactly: half-sweep windows of the resident kernel with a
-        # ppermute between windows, inside one jitted graph.
-        self._fused_exchange = self._fused and not sync.kernel_fusible
-        self._halo_rdma = bool(
-            self._fused_exchange and not interpret
-            and jax.default_backend() == "tpu"
-            and len(self.rows_axes) == 1
-            and not os.environ.get("REPRO_HALO_EMULATE"))
         self.plan = plan_row_partition(graph, self.n_row,
                                        with_lfsr=(noise == "lfsr"))
         self.band_resident = band_resident(graph, self.n_row)
@@ -893,22 +872,16 @@ class ShardedEngine:
         hides behind a traced conditional.  Async mode double-buffers the
         exchange: the values consumed at an exchange point were sent at
         the previous one, so the ppermute overlaps the intervening
-        interior compute.  Four loop shapes, picked at compile:
+        interior compute.  Three loop shapes, picked at compile:
 
-          * fused — launch-resident counter-noise policies with
-            launch-boundary-only exchange run each launch as one
-            `fused_shard_sweeps` Pallas call (sample and stats paths;
-            collect/hist fall back to the segment scan).
-          * fused-resident-exchange — fused backends whose policy has
-            mid-launch exchange points: the kernel owns the halo
-            refresh.  TPU meshes run one `fused_shard_exchange_resident`
-            RDMA launch per schedule chunk; interpret/CPU hosts run the
-            bit-exact emulation — the same launch split at the exchange
-            points into `half_offset`/`n_half` windows of the resident
-            kernel with a ppermute between windows, all inside one
-            jitted graph (no host round-trip).  Replaces the segment
-            scan whenever the fused kernel is active (see
-            docs/kernels.md, "In-kernel halo exchange").
+          * fused windows — backend "fused_sparse" (sample and stats
+            paths; collect/hist fall back to the scan shapes): each
+            launch splits at its exchange points
+            (`halo_exchange_segments`) into half-sweep windows, each one
+            `fused_shard_sweeps` Pallas call (`half_offset`/`n_half`)
+            after a swap, all inside one jitted graph.  Exchange at the
+            launch boundary only is the one-window case (docs/kernels.md,
+            "Fused exchange windows").
           * segment scan — exchanges uniformly spaced at full-sweep
             boundaries (``halo_every`` even or inf): outer scan over
             inter-exchange segments, inner scan over the uniform sweeps
@@ -928,7 +901,6 @@ class ShardedEngine:
         async_ = sync.mode == "async"
         k1_exact = sync.bit_exact
         use_fused = self._fused and not collect and hist_w is None
-        fused_ex = use_fused and ex_pts != (0,)
         # plain sampling of a band of whole cell rows runs on the band's
         # grid layout (see `_grid_half_sweep`); the rest gathers
         f = self.faults
@@ -1037,9 +1009,8 @@ class ShardedEngine:
                 return accs
 
             def launch(carry, xs_t):
-                """Fused kernel launch (boundary-only or kernel-resident
-                exchange), or L statically-unrolled sweeps (the
-                odd-``halo_every`` non-fused shapes, incl. k=1)."""
+                """Fused kernel windows, or L statically-unrolled sweeps
+                (the odd-``halo_every`` non-fused shapes, incl. k=1)."""
                 m, ns, hu, hd = carry[0], carry[1], carry[2], carry[3]
                 base = 4
                 pend = ()
@@ -1050,105 +1021,47 @@ class ShardedEngine:
                 meas_t = xs_t[1] if len(xs_t) > 1 else None
                 outs = []
 
-                if use_fused and not fused_ex:
-                    if clamped and cv is not None:
-                        m = jnp.where(cm, cv, m)
-                    hu, hd, pend = swap(m, hu, hd, pend)
-                    kwc = {}
-                    if clamped and cv is not None:
-                        kwc = dict(clamp_mask=cm, clamp_values=cv)
-                    res = fused_shard_sweeps(
-                        m, hu, hd, nbr, w, h, gain, off, rg, co,
-                        masks[0], masks[1], betas_t, ns, chain0,
-                        dev["cols"][0][0],
-                        measured=meas_t if accumulate else None,
-                        interpret=self.interpret, **kwc)
-                    m, ns = res[0], res[1]
-                    if accumulate:
-                        s_k = res[2]
-                        c_k = res[3][dev["edge_slot"][0],
-                                     dev["edge_e0"][0]]
-                        if self.n_chain == 1:
-                            b = jnp.float32(m.shape[0])
-                            s_k, c_k = s_k / b, c_k / b
-                        accs[0] = accs[0] + s_k
-                        accs[1] = accs[1] + c_k
-                elif fused_ex:
-                    # fused-resident-exchange: the kernel owns the halo
-                    # refresh.  k=1 barrier (bit_exact) keeps the host
+                if use_fused:
+                    # the launch split at its exchange points into
+                    # half-sweep windows of the resident kernel, a swap
+                    # before each.  k=1 barrier (bit_exact) keeps the
                     # post-sweep stats refresh, so the kernel only
                     # sweeps; every other policy accumulates in-kernel.
-                    if clamped and cv is not None:
-                        m = jnp.where(cm, cv, m)
                     kwc = {}
                     if clamped and cv is not None:
+                        m = jnp.where(cm, cv, m)
                         kwc = dict(clamp_mask=cm, clamp_values=cv)
                     exact_stats = accumulate and k1_exact
                     kern_meas = meas_t \
                         if (accumulate and not exact_stats) else None
-                    if self._halo_rdma and not exact_stats:
-                        # one RDMA launch per chunk; halos refresh via
-                        # remote async copies inside the kernel.  Async
-                        # consumes the pend buffer at point 0 and the
-                        # kernel's drained final exchange refills it.
-                        hu_in, hd_in = pend if async_ else (hu, hd)
-                        res = fused_shard_exchange_resident(
-                            m, hu_in, hd_in, nbr, w, h, gain, off, rg,
-                            co, masks[0], masks[1], betas_t, ns, chain0,
-                            dev["cols"][0][0], send_up, send_dn,
-                            measured=kern_meas, ex_pts=ex_pts,
-                            mode=sync.mode, axis_name=self._row_name,
-                            n_row=self.n_row, **kwc)
-                        m, ns, hu, hd = res[0], res[1], res[2], res[3]
-                        if async_:
-                            pend = (hu, hd)
+                    s_l = c_l = None
+                    for h0, h1 in halo_exchange_segments(ex_pts, 2 * L):
+                        hu, hd, pend = swap(m, hu, hd, pend)
+                        res = fused_shard_sweeps(
+                            m, hu, hd, nbr, w, h, gain, off, rg, co,
+                            masks[0], masks[1], betas_t, ns, chain0,
+                            dev["cols"][0][0], measured=kern_meas,
+                            interpret=self.interpret, half_offset=h0,
+                            n_half=h1 - h0, **kwc)
+                        m, ns = res[0], res[1]
                         if kern_meas is not None:
-                            s_k = res[4]
-                            c_k = res[5][dev["edge_slot"][0],
+                            # raw sums of the windows, added
+                            c_w = res[3][dev["edge_slot"][0],
                                          dev["edge_e0"][0]]
-                            if self.n_chain == 1:
-                                b = jnp.float32(m.shape[0])
-                                s_k, c_k = s_k / b, c_k / b
-                            accs[0] = accs[0] + s_k
-                            accs[1] = accs[1] + c_k
-                    else:
-                        # bit-exact emulation: split the launch at the
-                        # exchange points into half-sweep windows of the
-                        # same resident kernel, ppermute between them —
-                        # one jitted graph, no host round-trip
-                        s_l = c_l = None
-                        if kern_meas is not None:
-                            s_l = jnp.zeros((n_loc,), jnp.float32)
-                            c_l = jnp.zeros(
-                                (dev["edge_e0"].shape[1],), jnp.float32)
-                        for h0, h1 in halo_exchange_segments(
-                                ex_pts, 2 * L):
-                            hu, hd, pend = swap(m, hu, hd, pend)
-                            res = fused_shard_sweeps(
-                                m, hu, hd, nbr, w, h, gain, off, rg,
-                                co, masks[0], masks[1], betas_t, ns,
-                                chain0, dev["cols"][0][0],
-                                measured=kern_meas,
-                                interpret=self.interpret,
-                                half_offset=h0, n_half=h1 - h0, **kwc)
-                            m, ns = res[0], res[1]
-                            if kern_meas is not None:
-                                s_l = s_l + res[2]
-                                c_l = c_l + res[3][dev["edge_slot"][0],
-                                                   dev["edge_e0"][0]]
-                            if exact_stats and h1 % 2 == 0:
-                                # post-sweep refresh for boundary edges
-                                # — part of the bit-exact contract
-                                ru, rd = exchange(m)
-                                accs = sweep_stats(
-                                    m, ru, rd, meas_t[h1 // 2 - 1],
-                                    accs)
-                        if kern_meas is not None:
-                            if self.n_chain == 1:
-                                b = jnp.float32(m.shape[0])
-                                s_l, c_l = s_l / b, c_l / b
-                            accs[0] = accs[0] + s_l
-                            accs[1] = accs[1] + c_l
+                            s_l, c_l = ((res[2], c_w) if s_l is None
+                                        else (s_l + res[2], c_l + c_w))
+                        if exact_stats and h1 % 2 == 0:
+                            # post-sweep refresh for boundary edges —
+                            # part of the bit-exact contract
+                            ru, rd = exchange(m)
+                            accs = sweep_stats(m, ru, rd,
+                                               meas_t[h1 // 2 - 1], accs)
+                    if kern_meas is not None:
+                        if self.n_chain == 1:
+                            b = jnp.float32(m.shape[0])
+                            s_l, c_l = s_l / b, c_l / b
+                        accs[0] = accs[0] + s_l
+                        accs[1] = accs[1] + c_l
                 else:
                     for s in range(L):
                         beta_t = betas_t[s]
@@ -1464,211 +1377,10 @@ _BAND_TABLES = ("nbr", "send_up", "send_dn", "upd", "cols", "edge_e0",
                 "grid_slots")
 
 
-# ---------------------------------------------------------------------------
-# Pod-scale SK lattices (SoA instance generator + Session-backed anneal)
-# ---------------------------------------------------------------------------
-@dataclasses.dataclass(frozen=True)
-class LatticeSpec:
-    cell_rows: int
-    cell_cols: int
-    k: int = 4
-    beta: float = 1.0
-    chains: int = 1   # Gibbs replicas per device tile: couplings are read
-                      # from HBM once per half-sweep and serve all chains
-                      # (arithmetic intensity x chains — §Perf pbit cell)
-
-    @property
-    def n_spins(self) -> int:
-        return self.cell_rows * self.cell_cols * 2 * self.k
-
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
-class LatticeChip:
-    """SK-lattice couplings + neuron params, structure-of-arrays (O(N)).
-
-    This is the *instance description*; `lattice_to_chip` converts it
-    into the shared `EffectiveChip` slot layout the backends sample."""
-    W_vh: jax.Array
-    W_hv: jax.Array
-    Wv_dn: jax.Array
-    Wv_up: jax.Array
-    Wh_rt: jax.Array
-    Wh_lt: jax.Array
-    h_v: jax.Array
-    h_h: jax.Array
-    gain_v: jax.Array
-    gain_h: jax.Array
-    off_v: jax.Array
-    off_h: jax.Array
-
-    def tree_flatten(self):
-        f = dataclasses.fields(self)
-        return tuple(getattr(self, x.name) for x in f), None
-
-    @classmethod
-    def tree_unflatten(cls, aux, ch):
-        return cls(*ch)
-
-
-def make_sk_lattice(spec: LatticeSpec, key: jax.Array,
-                    hw: HardwareConfig | None = None,
-                    dtype=jnp.float32) -> LatticeChip:
-    """Random SK-style lattice instance with per-site mismatch baked in.
-
-    Pure function of (spec, key) — under pjit each device materializes only
-    its own shard (random bits are generated sharded).
-    """
-    hw = hw or HardwareConfig()
-    R, C, k = spec.cell_rows, spec.cell_cols, spec.k
-    ks = jax.random.split(key, 12)
-
-    def g(i, shape, scale=1.0):
-        return scale * jax.random.normal(ks[i], shape, dtype)
-
-    W_cell = g(0, (R, C, k, k), 0.8)                      # shared edge DAC
-    mis = lambda i, shape: 1.0 + hw.sigma_edge_gain * g(i, shape)
-    Wv = g(1, (R, C, k), 0.8)
-    Wh = g(2, (R, C, k), 0.8)
-    row = jnp.arange(R)[:, None, None]
-    col = jnp.arange(C)[None, :, None]
-    # no couplers past the lattice edge
-    Wv = Wv * (row < R - 1)
-    Wh = Wh * (col < C - 1)
-    return LatticeChip(
-        W_vh=W_cell * mis(3, (R, C, k, k)),
-        W_hv=jnp.swapaxes(W_cell, -1, -2) * mis(4, (R, C, k, k)),
-        Wv_dn=Wv * (1.0 + hw.sigma_edge_gain * g(5, (R, C, k))),
-        Wv_up=Wv * (1.0 + hw.sigma_edge_gain * g(6, (R, C, k))),
-        Wh_rt=Wh * (1.0 + hw.sigma_edge_gain * g(7, (R, C, k))),
-        Wh_lt=Wh * (1.0 + hw.sigma_edge_gain * g(8, (R, C, k))),
-        h_v=jnp.zeros((R, C, k), dtype),
-        h_h=jnp.zeros((R, C, k), dtype),
-        gain_v=1.0 + hw.sigma_tanh_gain * g(9, (R, C, k)),
-        gain_h=1.0 + hw.sigma_tanh_gain * g(10, (R, C, k)),
-        off_v=hw.sigma_tanh_offset * 0.01 * g(11, (R, C, k)),
-        off_h=jnp.zeros((R, C, k), dtype),
-    )
-
-
-def lattice_to_chip(spec: LatticeSpec, lat: LatticeChip,
-                    graph: ChimeraGraph | None = None,
-                    tables=None) -> EffectiveChip:
-    """SoA lattice arrays -> the shared `EffectiveChip` slot layout.
-
-    Directional: ``nbr_w[d, i] = W[i, nbr_idx[d, i]]`` (current INTO node
-    i), so the converted chip samples the identical physics as the old
-    SoA update loop — tests/test_lattice.py checks it against the dense
-    reconstruction bit for bit.  O(D·N); no dense matrix anywhere.  The
-    lattice's dtype carries through (dryrun's --pbit-dtype knob).
-    """
-    g = graph if graph is not None else make_chimera(
-        spec.cell_rows, spec.cell_cols, spec.k)
-    if tables is None:
-        nbr_idx, _ = g.neighbor_table()
-        slot_ij, slot_ji = g.edge_slots(nbr_idx)
-    else:
-        nbr_idx, slot_ij, slot_ji = tables
-    dtype = lat.W_vh.dtype
-    r_, c_, s_, k_ = g.node_r, g.node_c, g.node_side, g.node_k
-    h = jnp.where(s_ == 0, lat.h_v[r_, c_, k_], lat.h_h[r_, c_, k_])
-    gain = jnp.where(s_ == 0, lat.gain_v[r_, c_, k_], lat.gain_h[r_, c_, k_])
-    off = jnp.where(s_ == 0, lat.off_v[r_, c_, k_], lat.off_h[r_, c_, k_])
-
-    e0, e1 = g.edges[:, 0], g.edges[:, 1]
-    r0, c0, k0 = r_[e0], c_[e0], k_[e0]
-    k1 = k_[e1]
-    incell = (r_[e1] == r0) & (c_[e1] == c0)
-    vert = (s_[e0] == 0) & (s_[e1] == 0)
-    # current INTO e0 from e1 / INTO e1 from e0 (see tests/test_lattice.py
-    # for the dense index conventions these reproduce)
-    w_in0 = jnp.where(
-        incell, lat.W_vh[r0, c0, k0, k1],
-        jnp.where(vert, lat.Wv_up[r0, c0, k0], lat.Wh_lt[r0, c0, k0]))
-    w_in1 = jnp.where(
-        incell, lat.W_hv[r0, c0, k1, k0],
-        jnp.where(vert, lat.Wv_dn[r0, c0, k0], lat.Wh_rt[r0, c0, k0]))
-    D = nbr_idx.shape[0]
-    nbr_w = (jnp.zeros((D, g.n_nodes), dtype)
-             .at[slot_ij, e0].set(w_in0)
-             .at[slot_ji, e1].set(w_in1))
-    ones = jnp.ones((g.n_nodes,), dtype)
-    return EffectiveChip(
-        W=None, h=h.astype(dtype), tanh_gain=gain.astype(dtype),
-        tanh_offset=off.astype(dtype), rand_gain=ones,
-        comp_offset=0.0 * ones, nbr_idx=jnp.asarray(nbr_idx, jnp.int32),
-        nbr_w=nbr_w)
-
-
 def sparse_energy(chip: EffectiveChip, m: jax.Array) -> jax.Array:
     """Symmetrized Ising energy per chain from the slot layout, O(B·N·D):
     E = -1/2 Σ_i m_i Σ_j W_ij m_j - Σ_i h_i m_i (directional W averaged
-    over its two directions, exactly the old `lattice_energy`)."""
+    over its two directions)."""
     I = sparse_neuron_input(m, chip.nbr_idx, chip.nbr_w,
                             jnp.float32(0.0))
     return -0.5 * jnp.sum(m * I, axis=1) - m @ chip.h
-
-
-def make_lattice_anneal(
-    spec: LatticeSpec,
-    mesh: Mesh | None,
-    *,
-    row_axes: tuple[str, ...] = ("data",),
-    col_axes: tuple[str, ...] = ("model",),
-    n_sweeps: int = 100,
-    record_every: int = 10,
-):
-    """Build the (optionally mesh-sharded) annealing step over the shared
-    engine: cell rows partition over ``row_axes`` with ppermute halo
-    exchange, exactly like every other sharded `api.Session` workload
-    (the old private SoA update loop is retired; ``col_axes`` is accepted
-    for signature compatibility — the spatial cut is 1-D over cell rows).
-
-    Returns jitted run(lattice_chip, key, betas) ->
-    (final_m (chains, N), energies (n_sweeps // record_every,)).
-    """
-    from repro import api
-
-    if n_sweeps % record_every:
-        raise ValueError(f"n_sweeps={n_sweeps} must be a multiple of "
-                         f"record_every={record_every}")
-    del col_axes
-    g = make_chimera(spec.cell_rows, spec.cell_cols, spec.k)
-    nbr_idx, _ = g.neighbor_table()
-    tables = (nbr_idx, *g.edge_slots(nbr_idx))
-    from repro.core.hardware import sample_mismatch_sparse
-    sp = api.SamplerSpec(
-        graph=g, hw=HardwareConfig.ideal(),
-        mismatch=sample_mismatch_sparse(jax.random.PRNGKey(0), g.n_nodes,
-                                        nbr_idx.shape[0],
-                                        HardwareConfig.ideal()),
-        noise="counter", backend="sparse", chains=spec.chains,
-        beta=spec.beta, mesh=mesh,
-        partition=(api.Partition(rows=row_axes) if mesh is not None
-                   else None))
-    session = api.Session(sp)
-    n_rec = n_sweeps // record_every
-
-    from repro.core import pbit
-
-    def run(lat: LatticeChip, key: jax.Array, betas: jax.Array):
-        chip = lattice_to_chip(spec, lat, g, tables)
-        k1, k2 = jax.random.split(key)
-        m = pbit.random_spins(k1, spec.chains, g.n_nodes)
-        ns = session.noise_state(k2)
-        segs = betas[:n_rec * record_every].reshape(n_rec, record_every)
-
-        def seg(carry, b):
-            m, ns = carry
-            m, ns, _ = session.sample(chip, m, ns, b)
-            return (m, ns), sparse_energy(chip, m).mean()
-
-        (m, ns), energies = jax.lax.scan(seg, (m, ns), segs)
-        return m, energies
-
-    return jax.jit(run)
-
-
-def lattice_input_sharding(mesh: Mesh, row_axes=("data",),
-                           col_axes=("model",)):
-    return NamedSharding(auto_axes(mesh), P(row_axes, col_axes))

@@ -96,13 +96,11 @@ def pbit_sparse_half_sweep_ref(m, nbr_idx, nbr_w, h, gain, off, rand_gain,
 def halo_exchange_segments(ex_pts, n_half):
     """Exchange points -> half-sweep windows [(h0, h1), ...] of a launch.
 
-    THE segmentation rule of the fused-resident-exchange loop shape: a
-    launch of ``n_half`` half-sweeps splits at its `Sync.exchange_points()`
-    into contiguous windows, each preceded by one halo refresh.  The
-    in-kernel RDMA path (`sweep_sparse_exchange_pallas`) and the host
-    emulation (`ShardedEngine._local_sweeps` windows of
-    `fused_shard_sweeps`) both consume this, which is what makes their
-    exchange placement identical by construction.
+    The segmentation rule of the sharded engine's fused loop: a launch
+    of ``n_half`` half-sweeps splits at its `Sync.exchange_points()` into
+    contiguous windows, each preceded by one halo refresh and run as one
+    `fused_shard_sweeps` call (``half_offset=h0, n_half=h1 - h0``).  A
+    launch with exchanges at its boundary only, ``(0,)``, is one window.
     """
     pts = tuple(ex_pts)
     if not pts or pts[0] != 0:
@@ -112,14 +110,3 @@ def halo_exchange_segments(ex_pts, n_half):
             f"exchange points {pts} outside the launch's {n_half} "
             f"half-sweeps")
     return tuple(zip(pts, pts[1:] + (n_half,)))
-
-
-def lattice_vertical_update_ref(m_v, m_h, m_v_up, m_v_dn, W_vh, wv_up,
-                                wv_dnin, h, gain, u, parity, color):
-    """Oracle for kernels/lattice_update.py (pure jnp)."""
-    I = (jnp.einsum("rcij,brcj->brci", W_vh, m_h)
-         + wv_dnin * m_v_up + wv_up * m_v_dn + h)
-    act = jnp.tanh(gain * I)
-    new = jnp.where(act + u >= 0.0, 1.0, -1.0)
-    upd = (parity == color)[None, :, :, None]
-    return jnp.where(upd, new, m_v).astype(m_v.dtype)

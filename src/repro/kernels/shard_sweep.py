@@ -23,15 +23,11 @@ backends:
     (chain, node) coordinates via ``coord_offset``, so the kernel
     consumes exactly the columns of the noise stream the scan path
     would.  This is the per-shard engine behind launch-resident
-    `api.Sync` policies (docs/sharding.md §Sync policies).
-  * `fused_shard_exchange_resident` goes one step further on real TPU
-    meshes: the halo exchange itself moves INSIDE the launch
-    (`sweep_sparse_exchange_pallas` RDMA refresh at every exchange
-    point), so `halo_every < sweeps_per_launch` no longer forces the
-    engine back to per-segment dispatch.  Host CI proves the identical
-    contract through the segmented emulation (`fused_shard_sweeps` with
-    ``half_offset``/``n_half`` windows + ppermute between windows, one
-    jitted graph — docs/kernels.md §In-kernel halo exchange).
+    `api.Sync` policies (docs/sharding.md §Sync policies).  A policy
+    with exchange points inside the launch runs it as half-sweep
+    windows (``half_offset``/``n_half``), one per exchange point, with a
+    ppermute between windows, all in one jitted graph (docs/kernels.md
+    §Fused exchange windows).
 """
 from __future__ import annotations
 
@@ -39,10 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.ref import field_decision_update, sparse_neuron_input
-from repro.kernels.sweep_fused import (
-    sweep_sparse_pallas,
-    sweep_sparse_stream_pallas,
-)
+from repro.kernels.sweep_fused import sweep_sparse_pallas
 
 
 def halo_exchange(
@@ -118,7 +111,7 @@ def _halo_free(nbr_idx, pad2):
 
 def fused_shard_sweeps(
     m_loc: jax.Array,            # (B, N_loc) local spins
-    halo_up: jax.Array,          # (B, H) frozen for the whole launch
+    halo_up: jax.Array,          # (B, H) frozen for the whole call
     halo_dn: jax.Array,          # (B, H)
     nbr_idx: jax.Array,          # (D, N_loc) ext-local neighbor table
     nbr_w: jax.Array,            # (D, N_loc) slot weights
@@ -136,8 +129,6 @@ def fused_shard_sweeps(
     clamp_mask: jax.Array | None = None,    # (N_loc,) bool
     clamp_values: jax.Array | None = None,  # (B, N_loc)
     measured: jax.Array | None = None,      # (S,) moment weights
-    next_nbr_w: jax.Array | None = None,    # (D, N_loc) next program weights
-    next_h: jax.Array | None = None,        # (N_loc,) next program biases
     *,
     block_b: int = 128,
     interpret: bool = True,
@@ -151,30 +142,23 @@ def fused_shard_sweeps(
     global (chain, node) coordinates, and (optionally) CD moments
     accumulate in the kernel's scratch.  Halo columns ride along in the
     extended array but are excluded from every update mask, so they stay
-    frozen at the launch-boundary exchange values — exactly the staleness
-    the launch-resident `api.Sync` policies define.  Bands are contiguous
-    global id ranges, so a single scalar ``col0`` places the whole block
-    in the global noise grid.
-
-    ``next_nbr_w``/``next_h`` switch the launch to the double-buffered
-    weight-streaming engine (`sweep_sparse_stream_pallas`): each shard's
-    slice of the NEXT program stages into a second VMEM slot while the
-    current program's sweeps run (mutually exclusive with ``measured`` —
-    a swapped program invalidates mid-grid moments).
+    frozen at the values of the exchange before the call — exactly the
+    staleness the launch-resident `api.Sync` policies define.  Bands are
+    contiguous global id ranges, so a single scalar ``col0`` places the
+    whole block in the global noise grid.
 
     Returns (m', noise_state'), with ``measured``
     (m', noise_state', s_sum[N_loc], c_slots[D, N_ext]) — raw sums over
     (chains × measured sweeps); ``c_slots[d, i] = Σ m_i·m_ext[idx[d, i]]``
     with i ext-local (boundary edges read the frozen halo; halo columns
-    read 0) — or, with a
-    next program, (m', noise_state', staged_w[D, N_loc], staged_h[N_loc])
-    ready to be the following launch's resident program slice.
+    read 0).
 
     ``half_offset``/``n_half`` run only that half-sweep window of the
-    launch (`sweep_sparse_pallas` segmented-window contract): the fused-
-    resident-exchange loop shape calls one window per halo segment,
-    re-exchanging halos in between, all inside one jitted graph — the
-    bit-exact emulation of the in-kernel RDMA refresh.
+    launch (`sweep_sparse_pallas` segmented-window contract): the
+    engine's fused loop calls one window per halo segment of
+    `kernels/ref.py::halo_exchange_segments`, re-exchanging halos in
+    between, all inside one jitted graph.  Chained windows equal the
+    unsplit launch bit for bit.
     """
     B, n_loc = m_loc.shape
     H = halo_up.shape[1]
@@ -198,22 +182,6 @@ def fused_shard_sweeps(
                        ((0, 0), (0, pad2)))
     coords = jnp.stack([jnp.asarray(row0, jnp.uint32),
                         jnp.asarray(col0, jnp.uint32)])
-    if next_nbr_w is not None:
-        if measured is not None:
-            raise ValueError(
-                "program streaming excludes in-kernel moment "
-                "accumulation (see sweep_sparse_stream_pallas)")
-        nw_e = jnp.pad(jnp.asarray(next_nbr_w, jnp.float32),
-                       ((0, 0), (0, pad2)))
-        m_out, ns, staged_w, staged_h = sweep_sparse_stream_pallas(
-            m_ext, idx_e, w_e, row(h), row(gain), row(off), row(rand_gain),
-            row(comp_off), jnp.concatenate([mask0, zb]),
-            jnp.concatenate([mask1, zb]), betas, noise_state,
-            nw_e, row(next_h), clamp_mask=cm_e, clamp_values=cv_e,
-            coord_offset=coords, block_b=block_b, interpret=interpret,
-            half_offset=half_offset, n_half=n_half)
-        return (m_out[:, :n_loc], ns, staged_w[:, :n_loc],
-                staged_h[:n_loc])
     outs = sweep_sparse_pallas(
         m_ext, idx_e, w_e, row(h), row(gain), row(off), row(rand_gain),
         row(comp_off), jnp.concatenate([mask0, zb]),
@@ -226,93 +194,3 @@ def fused_shard_sweeps(
     if measured is None:
         return m_out, outs[1]
     return m_out, outs[1], outs[2][:n_loc], outs[3]
-
-
-def fused_shard_exchange_resident(
-    m_loc: jax.Array,            # (B, N_loc) local spins
-    halo_up: jax.Array,          # (B, H) primed pre-launch values
-    halo_dn: jax.Array,          # (B, H)
-    nbr_idx: jax.Array,          # (D, N_loc) ext-local neighbor table
-    nbr_w: jax.Array,            # (D, N_loc)
-    h: jax.Array,
-    gain: jax.Array,
-    off: jax.Array,
-    rand_gain: jax.Array,
-    comp_off: jax.Array,
-    mask0: jax.Array,
-    mask1: jax.Array,
-    betas: jax.Array,            # (S,) or (S, B)
-    noise_state: jax.Array,      # (2,) uint32
-    row0: jax.Array,
-    col0: jax.Array,
-    send_up: jax.Array,          # (H,) local cols of the first-row verts
-    send_dn: jax.Array,          # (H,) local cols of the last-row verts
-    clamp_mask: jax.Array | None = None,
-    clamp_values: jax.Array | None = None,
-    measured: jax.Array | None = None,
-    next_nbr_w: jax.Array | None = None,
-    next_h: jax.Array | None = None,
-    *,
-    ex_pts: tuple,
-    mode: str = "barrier",
-    axis_name: str = "row",
-    n_row: int,
-    interpret: bool = False,
-):
-    """`fused_shard_sweeps` with the halo exchange INSIDE the kernel.
-
-    The hardware path of the fused-resident-exchange loop shape: one
-    `sweep_sparse_exchange_pallas` launch runs the whole schedule and
-    refreshes halos at every `ex_pts` half-sweep over RDMA, so nothing
-    leaves the kernel between exchanges.  Bit-for-bit the same contract
-    as the segmented emulation (`fused_shard_sweeps` windows + ppermute):
-    identical noise counters, identical exchange-point staleness.  TPU
-    meshes only — interpret mode raises, CI proves the contract through
-    the emulation; `chip_smoke.py --chips 4` checks it on 4 chips.
-    """
-    from repro.kernels.sweep_fused import sweep_sparse_exchange_pallas
-
-    B, n_loc = m_loc.shape
-    H = halo_up.shape[1]
-    pad2 = 2 * H
-    m_ext = jnp.concatenate([m_loc, halo_up, halo_dn], axis=1)
-    zb = jnp.zeros((pad2,), bool)
-    zf = jnp.zeros((pad2,), jnp.float32)
-    row = lambda x: jnp.concatenate([jnp.asarray(x, jnp.float32), zf])
-    idx_e = _halo_free(nbr_idx, pad2)
-    w_e = jnp.pad(jnp.asarray(nbr_w, jnp.float32), ((0, 0), (0, pad2)))
-    betas = jnp.asarray(betas, jnp.float32)
-    if betas.ndim == 1:
-        betas = jnp.broadcast_to(betas[:, None], (betas.shape[0], B))
-    cm_e = cv_e = None
-    if clamp_mask is not None and clamp_values is not None:
-        cm_e = jnp.concatenate([clamp_mask, zb])
-        cv_e = jnp.pad(jnp.asarray(clamp_values, jnp.float32),
-                       ((0, 0), (0, pad2)))
-    coords = jnp.stack([jnp.asarray(row0, jnp.uint32),
-                        jnp.asarray(col0, jnp.uint32)])
-    nw_e = nh_e = None
-    if next_nbr_w is not None:
-        nw_e = jnp.pad(jnp.asarray(next_nbr_w, jnp.float32),
-                       ((0, 0), (0, pad2)))
-        nh_e = row(next_h)
-    outs = sweep_sparse_exchange_pallas(
-        m_ext, idx_e, w_e, row(h), row(gain), row(off), row(rand_gain),
-        row(comp_off), jnp.concatenate([mask0, zb]),
-        jnp.concatenate([mask1, zb]), betas, noise_state,
-        send_up, send_dn, clamp_mask=cm_e, clamp_values=cv_e,
-        measured=measured, coord_offset=coords, next_nbr_w=nw_e,
-        next_h=nh_e, n_loc=n_loc, halo=H, ex_pts=ex_pts, mode=mode,
-        axis_name=axis_name, n_row=n_row, interpret=interpret)
-    m_out = outs[0][:, :n_loc]
-    # halo columns as the kernel left them: barrier — the last-installed
-    # exchange; async — the drained final exchange, i.e. the engine's
-    # pend buffer for the next launch's first consume
-    hu_out = outs[0][:, n_loc:n_loc + H]
-    hd_out = outs[0][:, n_loc + H:n_loc + 2 * H]
-    head = (m_out, outs[1], hu_out, hd_out)
-    if measured is not None:
-        return head + (outs[2][:n_loc], outs[3])
-    if next_nbr_w is not None:
-        return head + (outs[2][:, :n_loc], outs[3][:n_loc])
-    return head

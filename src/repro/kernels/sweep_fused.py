@@ -36,13 +36,6 @@ Two weight layouts share the kernel body:
     ascending-neighbor order, making the result bit-exact against both the
     sparse jnp ref and (zeros being additive identities) the dense path.
 
-`sweep_sparse_stream_pallas` adds runtime weight streaming to the sparse
-engine: the NEXT program's (D, N)/(N,) weights ride the same launch,
-stage into a second VMEM slot at grid step 0 (overlapping the current
-program's S sweeps — the SpikeHard DMA model), and come back as staged
-outputs aliased in place over the inputs, ready to be the next launch's
-resident program.
-
 Grid: (B/tb,) over batch tiles; each program owns its rows for all S
 sweeps.  Moment/histogram scratch accumulates across the (sequential)
 batch-tile grid and is flushed to the output on the last program, the same
@@ -201,8 +194,8 @@ def _block_rows(tb: int, Np: int, D: int) -> int:
 def _kernel(*refs, S: int, tb: int, hb: int, Np: int, n_b: int, B: int,
             noise_mode: str, has_clamp: bool, accumulate: bool,
             collect_hist: bool, decimation: int, sparse: bool, D: int,
-            NBp: int, has_coords: bool, stream: bool = False,
-            half_offset: int = 0, n_half: int | None = None):
+            NBp: int, has_coords: bool, half_offset: int = 0,
+            n_half: int | None = None):
     it = iter(refs)
     m0_ref = next(it)
     if sparse:
@@ -221,17 +214,12 @@ def _kernel(*refs, S: int, tb: int, hb: int, Np: int, n_b: int, B: int,
     byte_ref = next(it) if noise_mode == NOISE_LFSR else None  # (1, Np)
     coords_ref = next(it) if has_coords else None
     noise_in_ref = next(it)
-    if stream:
-        next_w_ref = next(it)                 # (Dp, Np) next program weights
-        next_h_ref = next(it)                 # (1, Np) next program biases
     m_out_ref = next(it)                      # f32 spins, updated in place
     noise_out_ref = next(it)
     if accumulate:
         ssum_out_ref, csum_out_ref = next(it), next(it)
     if collect_hist:
         hist_out_ref = next(it)
-    if stream:
-        staged_w_out_ref, staged_h_out_ref = next(it), next(it)
     bcol_ref = next(it)                       # (tb, 1) this sweep's betas
     if accumulate:
         ssum_ref, csum_ref = next(it), next(it)
@@ -239,8 +227,6 @@ def _kernel(*refs, S: int, tb: int, hb: int, Np: int, n_b: int, B: int,
             corr_ref = next(it)               # (Dp, Np) one sweep's sums
     if collect_hist:
         hist_ref = next(it)
-    if stream:
-        slot_w_ref, slot_h_ref = next(it), next(it)
 
     i = pl.program_id(0)
 
@@ -253,19 +239,6 @@ def _kernel(*refs, S: int, tb: int, hb: int, Np: int, n_b: int, B: int,
         @pl.when(i == 0)
         def _zero_hist():
             hist_ref[...] = jnp.zeros_like(hist_ref)
-    if stream:
-        # double-buffered program upload (the SpikeHard DMA model): the
-        # NEXT program's weights stream into the second VMEM slot up
-        # front, before this launch's S resident sweeps touch the loop —
-        # independent of the sweep dataflow, so the copy overlaps compute
-        # on hardware.  Flushed to the staged outputs on the last block;
-        # the host feeds them straight back as the following launch's
-        # resident program (zero-copy: the next-program inputs alias the
-        # staged outputs via input_output_aliases).
-        @pl.when(i == 0)
-        def _stage_next_program():
-            slot_w_ref[...] = next_w_ref[...]
-            slot_h_ref[...] = next_h_ref[...]
 
     # the spins (and the LFSR registers) live in their output blocks for
     # the whole launch; each half-sweep rewrites them one row block at a
@@ -432,11 +405,6 @@ def _kernel(*refs, S: int, tb: int, hb: int, Np: int, n_b: int, B: int,
         @pl.when(i == n_b - 1)
         def _flush_hist():
             hist_out_ref[...] = hist_ref[...]
-    if stream:
-        @pl.when(i == n_b - 1)
-        def _flush_staged_program():
-            staged_w_out_ref[...] = slot_w_ref[...]
-            staged_h_out_ref[...] = slot_h_ref[...]
 
 
 def _launch(
@@ -444,8 +412,7 @@ def _launch(
     mask0, mask1, betas, noise_state, clamp_mask, clamp_values, measured,
     visible_idx, *, sparse, noise_mode, decimation, gather_perm,
     accumulate, collect_hist, n_visible, block_b, interpret,
-    coord_offset=None, next_nbr_w=None, next_h=None,
-    half_offset=0, n_half=None,
+    coord_offset=None, half_offset=0, n_half=None,
 ):
     """Shared plumbing for the dense and sparse sweep-resident engines."""
     B, N = m.shape
@@ -458,19 +425,6 @@ def _launch(
             f"half-sweep window [{half_offset}, {half_offset + n_half}) "
             f"falls outside the launch's 2*S={2 * S} half-sweeps")
     out_dtype = m.dtype
-    stream = next_nbr_w is not None
-    if stream:
-        if not sparse or noise_mode != NOISE_COUNTER:
-            raise ValueError(
-                "program streaming runs on the sparse counter-noise "
-                "engine (the launch-resident serving configuration)")
-        if next_h is None:
-            raise ValueError("next_nbr_w without next_h")
-        if accumulate or collect_hist or measured is not None:
-            raise ValueError(
-                "program streaming excludes in-kernel moment/histogram "
-                "accumulation — a swapped program invalidates the "
-                "accumulators mid-grid")
     # clamp_mask alone (freeze nodes at their current spins) is fully
     # handled by excluding the nodes from mask0/mask1; the kernel only
     # needs the clamp inputs when values are re-imposed every sweep
@@ -498,9 +452,6 @@ def _launch(
                      jnp.zeros(c_shape, jnp.float32)]
         if collect_hist:
             outs.append(jnp.zeros((NB,), jnp.float32))
-        if stream:
-            outs += [jnp.asarray(next_nbr_w, jnp.float32),
-                     jnp.asarray(next_h, jnp.float32)]
         return tuple(outs)
 
     Np = _round_up(N, 128)
@@ -601,21 +552,6 @@ def _launch(
         noise_out_shape = jax.ShapeDtypeStruct((Bp, Np), jnp.uint32)
         noise_out_spec = pl.BlockSpec((tb, Np), lambda i: (i, 0))
 
-    aliases = {}
-    if stream:
-        # the next program rides the SAME launch as the current sweeps:
-        # two O(D·N) operands appended after the noise state, aliased to
-        # the staged outputs (in-place buffer handoff — the upload costs
-        # no extra HBM round-trip, matching the chip's SPI-write-during-
-        # anneal overlap)
-        i_next = len(args)
-        in_specs += [pl.BlockSpec((Dp, Np), lambda i: (0, 0)),
-                     pl.BlockSpec((1, Np), lambda i: (0, 0))]
-        args += [_pad_axis(_pad_axis(
-            jnp.asarray(next_nbr_w, jnp.float32), Dp, 0), 128, 1),
-            row(next_h)]
-        aliases = {i_next: 2, i_next + 1: 3}
-
     out_shape = [jax.ShapeDtypeStruct((Bp, Np), jnp.float32),
                  noise_out_shape]
     out_specs = [pl.BlockSpec((tb, Np), lambda i: (i, 0)), noise_out_spec]
@@ -633,13 +569,6 @@ def _launch(
         out_shape.append(jax.ShapeDtypeStruct((1, NBp), jnp.float32))
         out_specs.append(pl.BlockSpec((1, NBp), lambda i: (0, 0)))
         scratch.append(_VMEM((1, NBp), jnp.float32))
-    if stream:
-        out_shape += [jax.ShapeDtypeStruct((Dp, Np), jnp.float32),
-                      jax.ShapeDtypeStruct((1, Np), jnp.float32)]
-        out_specs += [pl.BlockSpec((Dp, Np), lambda i: (0, 0)),
-                      pl.BlockSpec((1, Np), lambda i: (0, 0))]
-        scratch += [_VMEM((Dp, Np), jnp.float32),
-                    _VMEM((1, Np), jnp.float32)]
 
     outs = pl.pallas_call(
         functools.partial(
@@ -649,13 +578,12 @@ def _launch(
             accumulate=accumulate, collect_hist=collect_hist,
             decimation=decimation, sparse=sparse,
             D=D if sparse else 0, NBp=NBp, has_coords=has_coords,
-            stream=stream, half_offset=half_offset, n_half=n_half),
+            half_offset=half_offset, n_half=n_half),
         grid=(n_b,),
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
-        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -673,10 +601,6 @@ def _launch(
         k += 2
     if collect_hist:
         result.append(outs[k][0, :NB])
-        k += 1
-    if stream:
-        result.append(outs[k][:D, :N])
-        result.append(outs[k + 1][0, :N])
     return tuple(result)
 
 
@@ -795,480 +719,3 @@ def sweep_sparse_pallas(
         collect_hist=collect_hist, n_visible=n_visible, block_b=block_b,
         interpret=interpret, coord_offset=coord_offset,
         half_offset=half_offset, n_half=n_half)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("decimation", "block_b", "interpret",
-                     "half_offset", "n_half"),
-)
-def sweep_sparse_stream_pallas(
-    m: jax.Array,                 # (B, N) spins in {-1, +1}
-    nbr_idx: jax.Array,           # (D, N) int32 neighbor table
-    nbr_w: jax.Array,             # (D, N) CURRENT program's slot weights
-    h: jax.Array,                 # (N,)   CURRENT program's biases
-    gain: jax.Array,
-    off: jax.Array,
-    rand_gain: jax.Array,
-    comp_off: jax.Array,
-    mask0: jax.Array,
-    mask1: jax.Array,
-    betas: jax.Array,             # (S, B)
-    noise_state: jax.Array,       # (2,) uint32 counter state
-    next_nbr_w: jax.Array,        # (D, N) NEXT program's slot weights
-    next_h: jax.Array,            # (N,)   NEXT program's biases
-    clamp_mask: jax.Array | None = None,
-    clamp_values: jax.Array | None = None,
-    coord_offset: jax.Array | None = None,
-    *,
-    decimation: int = 8,
-    block_b: int = 128,
-    interpret: bool = True,
-    half_offset: int = 0,
-    n_half: int | None = None,
-):
-    """`sweep_sparse_pallas` with a double-buffered program upload: run S
-    resident sweeps of the CURRENT program while the NEXT program's
-    weights stream into a second VMEM slot.
-
-    Returns ``(m', noise_state', staged_w, staged_h)`` where
-    ``staged_w``/``staged_h`` are the next program, already device-
-    resident: feed them back as this call's ``nbr_w``/``h`` on the next
-    launch.  The next-program inputs alias the staged outputs
-    (`input_output_aliases`), so the handoff is an in-place buffer swap,
-    and the stage copy runs at grid step 0 — independent of the sweep
-    loop, overlapping compute on hardware (the SpikeHard DMA model: the
-    chip accepts the next problem's SPI write while the current anneal
-    runs).  Counter noise only, no in-kernel accumulation (a swapped
-    program would invalidate mid-grid moments).  Per-program results are
-    bit-identical to serialized `sweep_sparse_pallas` launches — the
-    benchmark ``weight_streaming`` section measures the upload overlap.
-    """
-    return _launch(
-        m, None, nbr_idx, nbr_w, h, gain, off, rand_gain, comp_off,
-        mask0, mask1, betas, noise_state, clamp_mask, clamp_values,
-        None, None,
-        sparse=True, noise_mode=NOISE_COUNTER, decimation=decimation,
-        gather_perm=None, accumulate=False, collect_hist=False,
-        n_visible=0, block_b=block_b, interpret=interpret,
-        coord_offset=coord_offset, next_nbr_w=next_nbr_w, next_h=next_h,
-        half_offset=half_offset, n_half=n_half)
-
-
-# ---------------------------------------------------------------------------
-# Kernel-resident halo exchange (hardware RDMA path)
-# ---------------------------------------------------------------------------
-#
-# One resident launch per shard refreshes its halos MID-FLIGHT: at every
-# `Sync.exchange_points()` half-sweep the kernel gathers its O(√N) boundary
-# spins into a VMEM send buffer and `pltpu.make_async_remote_copy`s them
-# into the row neighbor's second halo VMEM slot, double-buffered on
-# exchange parity exactly like the PR-9 program stream.  `mode="barrier"`
-# waits for the incoming copy before the next half-sweep consumes it;
-# `mode="async"` installs the PREVIOUS exchange's values and lets the
-# in-flight copy overlap the segment's compute — the same staleness
-# contract as the host engine's pend-buffer.  Host CI cannot run RDMA:
-# interpret mode runs the bit-exact emulation instead
-# (ShardedEngine's fused-resident-exchange loop shape: the same launch
-# split at exchange points via `half_offset`/`n_half`, ppermute between
-# segments, one jitted graph).  This kernel compiles only for TPU meshes
-# (tests/test_tpu_compile.py).
-
-_HALO_UP, _HALO_DN = 0, 1  # recv-buffer direction slots
-
-
-def _exchange_kernel(*refs, S, tb, Np, B, n_loc, H, Hp, segments, mode,
-                     has_clamp, accumulate, D, axis_name, n_row,
-                     collective_id, stream):
-    it = iter(refs)
-    m0_ref = next(it)                         # (tb, Np) [local|hu|hd]
-    sh_ref, w_ref = next(it), next(it)        # (Dp, Np) lane shifts, weights
-    rot_ref = next(it)                        # SMEM per-slot rotations
-    h_ref, g_ref, off_ref, rg_ref, co_ref = (next(it) for _ in range(5))
-    mask0_ref, mask1_ref = next(it), next(it)
-    betas_ref = next(it)                      # (S, tb)
-    send_ref = next(it)                       # (2, Hp) boundary lane shifts
-    srot_ref = next(it)                       # SMEM send rotations
-    inst_ref = next(it)                       # (1, Np) halo lane shifts
-    irot_ref = next(it)                       # SMEM install rotations
-    clampm_ref = next(it) if has_clamp else None
-    clampv_ref = next(it) if has_clamp else None
-    meas_ref = next(it) if accumulate else None
-    coords_ref = next(it)
-    noise_in_ref = next(it)
-    if stream:
-        next_w_ref, next_h_ref = next(it), next(it)
-    m_out_ref = next(it)
-    noise_out_ref = next(it)
-    if accumulate:
-        ssum_out_ref, csum_out_ref = next(it), next(it)
-    if stream:
-        staged_w_out_ref, staged_h_out_ref = next(it), next(it)
-    sbuf_ref = next(it)                       # (2, 2, tb, Hp) send slots
-    rbuf_ref = next(it)                       # (2, 2, tb, Hp) recv slots
-    send_sem = next(it)                       # DMA (2, 2) [dir, parity]
-    recv_sem = next(it)                       # DMA (2, 2)
-    if accumulate:
-        ssum_ref, csum_ref = next(it), next(it)
-    if stream:
-        slot_w_ref, slot_h_ref = next(it), next(it)
-
-    my = jax.lax.axis_index(axis_name)
-    up_ok = my > 0                  # row above exists
-    dn_ok = my < n_row - 1          # row below exists
-    n_nbr = up_ok.astype(jnp.int32) + dn_ok.astype(jnp.int32)
-
-    if accumulate:
-        ssum_ref[...] = jnp.zeros_like(ssum_ref)
-        csum_ref[...] = jnp.zeros_like(csum_ref)
-    if stream:
-        # double-buffered program upload staged up front, overlapping the
-        # resident sweeps (shared launch with the halo refresh)
-        slot_w_ref[...] = next_w_ref[...]
-        slot_h_ref[...] = next_h_ref[...]
-
-    hrow, grow = h_ref[...], g_ref[...]
-    offrow, rgrow, corow = off_ref[...], rg_ref[...], co_ref[...]
-    masks = (mask0_ref[...] != 0, mask1_ref[...] != 0)
-    seed = noise_in_ref[0, 0]
-    ctr0 = noise_in_ref[0, 1]
-    row0 = coords_ref[0, 0]
-    col0 = coords_ref[0, 1]
-    rows = jax.lax.broadcasted_iota(jnp.uint32, (tb, Np), 0) + row0
-    cols = jax.lax.broadcasted_iota(jnp.uint32, (tb, Np), 1) + col0
-
-    # neighbor barrier before the first RDMA: nobody writes into a peer
-    # still draining its previous launch
-    barrier = pltpu.get_barrier_semaphore()
-
-    @pl.when(up_ok)
-    def _sig_up():
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis_name: my - 1},
-            device_id_type=pltpu.DeviceIdType.MESH)
-
-    @pl.when(dn_ok)
-    def _sig_dn():
-        pltpu.semaphore_signal(
-            barrier, inc=1, device_id={axis_name: my + 1},
-            device_id_type=pltpu.DeviceIdType.MESH)
-
-    pltpu.semaphore_wait(barrier, n_nbr)
-
-    def neuron_current(m):
-        acc = jnp.zeros((tb, Np), jnp.float32)
-        for d, g in _slot_gathers(m, sh_ref, rot_ref, D):
-            acc = acc + w_ref[pl.ds(d, 1), :] * g
-        return acc + hrow
-
-    def copy(direction, parity):
-        """My outgoing boundary copy: direction 0 sends my first-row
-        boundary UP (it becomes that neighbor's halo_dn), 1 sends my
-        last-row boundary DOWN (that neighbor's halo_up).  The same
-        descriptor waits the matching local semaphores."""
-        slot = _HALO_DN if direction == 0 else _HALO_UP
-        return pltpu.make_async_remote_copy(
-            src_ref=sbuf_ref.at[direction, parity],
-            dst_ref=rbuf_ref.at[slot, parity],
-            send_sem=send_sem.at[direction, parity],
-            recv_sem=recv_sem.at[slot, parity],
-            device_id={axis_name: my - 1 if direction == 0 else my + 1},
-            device_id_type=pltpu.DeviceIdType.MESH)
-
-    def start_exchange(m, parity):
-        """Gather boundary spins and fire both neighbor RDMAs."""
-        up, dn = _gather_rows(m, send_ref, srot_ref, 0, (0, 1))
-        sbuf_ref[0, parity] = up
-        sbuf_ref[1, parity] = dn
-
-        @pl.when(up_ok)
-        def _send_up():
-            copy(0, parity).start()
-
-        @pl.when(dn_ok)
-        def _send_dn():
-            copy(1, parity).start()
-
-    halo_lanes = inst_ref[...] >= 0
-
-    def install_halos(m, parity):
-        """Wait the incoming copies of `parity` and refresh halo columns."""
-        @pl.when(up_ok)
-        def _wait_up():
-            copy(1, parity).wait_recv()    # lands in my _HALO_UP slot
-
-        @pl.when(dn_ok)
-        def _wait_dn():
-            copy(0, parity).wait_recv()    # lands in my _HALO_DN slot
-        hu = jnp.where(up_ok, rbuf_ref[_HALO_UP, parity], 0.0)
-        hd = jnp.where(dn_ok, rbuf_ref[_HALO_DN, parity], 0.0)
-        src = jnp.concatenate(
-            [hu, hd] + ([jnp.zeros((tb, Np - 2 * Hp), jnp.float32)]
-                        if Np > 2 * Hp else []), axis=1)
-        (halos,) = _gather_rows(src, inst_ref, irot_ref, 0, (0,))
-        return jnp.where(halo_lanes, halos, m)
-
-    def wait_sends(parity):
-        @pl.when(up_ok)
-        def _ws_up():
-            copy(0, parity).wait_send()
-
-        @pl.when(dn_ok)
-        def _ws_dn():
-            copy(1, parity).wait_send()
-
-    def half_update(m, s_idx, c, half_j):
-        ctr = ctr0 + half_j
-        u = lfsr_mod.counter_uniform(seed, ctr, rows, cols)
-        beta_col = betas_ref[pl.ds(s_idx, 1), :].reshape(tb, 1)
-        act = jnp.tanh(beta_col * grow * (neuron_current(m) + offrow))
-        decision = act + rgrow * u + corow
-        new = jnp.where(decision >= 0.0, 1.0, -1.0)
-        return jnp.where(masks[c], new, m)
-
-    def impose_clamp(m):
-        if has_clamp:
-            return jnp.where(clampm_ref[...] != 0, clampv_ref[...], m)
-        return m
-
-    def sweep_stats(m, s_idx):
-        wgt = meas_ref[s_idx]
-        row_ids = jax.lax.broadcasted_iota(jnp.int32, (tb, 1), 0)
-        mv = jnp.where(row_ids < B, m, 0.0)
-        ssum_ref[...] += wgt * jnp.sum(mv, axis=0, keepdims=True)
-        for d, g in _slot_gathers(mv, sh_ref, rot_ref, D):
-            corr = jnp.sum(mv * g, axis=0, keepdims=True)
-            csum_ref[pl.ds(d, 1), :] += wgt * corr
-
-    m = m0_ref[...].astype(jnp.float32)
-    n_ex = len(segments)
-    for e, (h0, h1) in enumerate(segments):
-        parity = e % 2
-        if e >= 2:
-            # reusing this parity's send slots: previous copy must be out
-            wait_sends(parity)
-        start_exchange(m, parity)
-        if mode == "barrier":
-            m = install_halos(m, parity)
-        elif e > 0:
-            # async: consume the PREVIOUS exchange's values; exchange e
-            # stays in flight behind this segment's compute
-            m = install_halos(m, (e - 1) % 2)
-        # run the [h0, h1) half-sweep window (lead / full / tail — the
-        # same structure as _kernel's segmented window)
-        lead = h0 % 2
-        n_full = (h1 - h0 - lead) // 2
-        tail = (h1 - h0 - lead) % 2
-        s0 = (h0 + lead) // 2
-        if lead:
-            m = impose_clamp(m)
-            m = half_update(m, h0 // 2, 1, jnp.uint32(h0))
-            if accumulate:
-                sweep_stats(m, h0 // 2)
-
-        def one_sweep(jj, m, s0=s0, base=h0 + lead):
-            m = impose_clamp(m)
-            for c in (0, 1):
-                hj = (jnp.uint32(base)
-                      + jnp.uint32(2) * jj.astype(jnp.uint32)
-                      + jnp.uint32(c))
-                m = half_update(m, s0 + jj, c, hj)
-            if accumulate:
-                sweep_stats(m, s0 + jj)
-            return m
-
-        m = jax.lax.fori_loop(0, n_full, one_sweep, m)
-        if tail:
-            m = impose_clamp(m)
-            m = half_update(m, s0 + n_full, 0, jnp.uint32(h1 - 1))
-
-    # drain every DMA still in flight before the kernel exits
-    if mode != "barrier":
-        # async: the final exchange is the NEXT launch's first consume
-        # (the engine's pend buffer) — install it into the halo columns
-        # so m_out carries it across the launch boundary
-        m = install_halos(m, (n_ex - 1) % 2)
-    for parity in range(min(n_ex, 2)):
-        # sends not yet retired by the e>=2 slot-reuse waits: the last
-        # exchange on each parity
-        wait_sends(parity)
-
-    m_out_ref[...] = m.astype(m_out_ref.dtype)
-    noise_out_ref[...] = _noise_state_out(noise_in_ref[...], 2 * S)
-    if accumulate:
-        ssum_out_ref[...] = ssum_ref[...]
-        csum_out_ref[...] = csum_ref[...]
-    if stream:
-        staged_w_out_ref[...] = slot_w_ref[...]
-        staged_h_out_ref[...] = slot_h_ref[...]
-
-
-def sweep_sparse_exchange_pallas(
-    m_ext: jax.Array,             # (B, N_ext) [local | halo_up | halo_dn]
-    nbr_idx: jax.Array,           # (D, N_ext) ext-local neighbor table
-    nbr_w: jax.Array,             # (D, N_ext)
-    h: jax.Array,
-    gain: jax.Array,
-    off: jax.Array,
-    rand_gain: jax.Array,
-    comp_off: jax.Array,
-    mask0: jax.Array,             # (N_ext,) halo columns excluded
-    mask1: jax.Array,
-    betas: jax.Array,             # (S, B)
-    noise_state: jax.Array,       # (2,) uint32
-    send_up: jax.Array,           # (H,) local cols of the first-row verts
-    send_dn: jax.Array,           # (H,) local cols of the last-row verts
-    clamp_mask: jax.Array | None = None,
-    clamp_values: jax.Array | None = None,
-    measured: jax.Array | None = None,
-    coord_offset: jax.Array | None = None,
-    next_nbr_w: jax.Array | None = None,
-    next_h: jax.Array | None = None,
-    *,
-    n_loc: int,
-    halo: int,
-    ex_pts: tuple,                # launch-relative half-sweep indices
-    mode: str = "barrier",
-    axis_name: str = "row",
-    n_row: int,
-    collective_id: int = 7,
-    interpret: bool = False,
-):
-    """S resident sweeps with IN-KERNEL halo refresh at every exchange
-    point — the hardware twin of the engine's fused-resident-exchange
-    emulation (identical noise counters, identical exchange-point
-    staleness); `chip_smoke.py --chips 4` checks the match on 4 chips.
-
-    Must run under ``shard_map`` over a mesh whose ``axis_name`` axis
-    holds the ``n_row`` row bands.  Single batch tile (the exchange needs the whole
-    shard's boundary at once).  Raises in interpret mode: host CI runs
-    the segmented emulation (`ShardedEngine._local_sweeps`), which this
-    kernel must match bit-for-bit on hardware.
-    """
-    if interpret:
-        raise NotImplementedError(
-            "in-kernel RDMA halo exchange needs a real TPU mesh; "
-            "interpret mode runs the bit-exact segmented emulation "
-            "(ShardedEngine's fused-resident-exchange loop shape)")
-    from repro.kernels.ref import halo_exchange_segments
-
-    B, N = m_ext.shape
-    S = betas.shape[0]
-    H = halo
-    D = nbr_idx.shape[0]
-    segments = halo_exchange_segments(ex_pts, 2 * S)
-    accumulate = measured is not None
-    has_clamp = clamp_mask is not None and clamp_values is not None
-    stream = next_nbr_w is not None
-    if stream and accumulate:
-        raise ValueError("program streaming excludes in-kernel moments")
-
-    Np = _round_up(N, 128)
-    Hp = _round_up(max(H, 1), 128)
-    tb = _round_up(B, 8)
-    Dp = _round_up(D, 8)
-    if 2 * Hp > Np:
-        raise ValueError(f"halo width {H} too wide for a {N}-column shard")
-    # halo installs gather from the [recv_up | recv_dn] buffers: ext
-    # column n_loc + j reads up-lane j, n_loc + H + j reads dn-lane j
-    inst = np.full((1, Np), -1, np.int32)
-    inst[0, n_loc:n_loc + H] = np.arange(H)
-    inst[0, n_loc + H:n_loc + 2 * H] = Hp + np.arange(H)
-
-    row = lambda x: _pad_axis(
-        jnp.asarray(x).reshape(1, -1).astype(jnp.float32), 128, 1)
-    mp = _pad_axis(_pad_axis(m_ext, tb, 0), 128, 1)
-    shifts, rotations = _lane_rotations(nbr_idx, N, Np, _slot_groups(D))
-    send_shifts, send_rotations = _lane_rotations(
-        jnp.stack([jnp.asarray(send_up, jnp.int32),
-                   jnp.asarray(send_dn, jnp.int32)]), H, Np, ((0, 1),))
-    inst_shifts, inst_rotations = _lane_rotations(inst, Np, Np, ((0,),))
-    wp = _pad_axis(_pad_axis(jnp.asarray(nbr_w, jnp.float32), Dp, 0),
-                   128, 1)
-    m0p = _pad_axis(jnp.asarray(mask0).reshape(1, -1).astype(jnp.int8),
-                    128, 1, 0)
-    m1p = _pad_axis(jnp.asarray(mask1).reshape(1, -1).astype(jnp.int8),
-                    128, 1, 0)
-    betasp = _pad_axis(jnp.asarray(betas, jnp.float32), tb, 1)
-
-    full = lambda shape: pl.BlockSpec(shape, lambda: tuple(
-        0 for _ in shape))
-    in_specs = [full((tb, Np)), full((Dp, Np)), full((Dp, Np)), _SMEM]
-    args = [mp, _pad_axis(shifts, Dp, 0, -1), wp, rotations]
-    in_specs += [full((1, Np))] * 7 + [full((S, tb)), full((2, Hp)), _SMEM]
-    args += [row(h), row(gain), row(off), row(rand_gain), row(comp_off),
-             m0p, m1p, betasp, send_shifts, send_rotations,
-             inst_shifts, inst_rotations]
-    in_specs += [full((1, Np)), _SMEM]
-    if has_clamp:
-        in_specs += [full((1, Np)), full((tb, Np))]
-        args += [_pad_axis(jnp.asarray(clamp_mask).reshape(1, -1)
-                           .astype(jnp.int8), 128, 1, 0),
-                 _pad_axis(_pad_axis(
-                     jnp.asarray(clamp_values, jnp.float32), tb, 0),
-                     128, 1)]
-    if accumulate:
-        in_specs.append(_SMEM)
-        args.append(jnp.asarray(measured, jnp.float32).reshape(S))
-    in_specs.append(full((1, 2)))
-    args.append(jnp.zeros((1, 2), jnp.uint32) if coord_offset is None
-                else jnp.asarray(coord_offset, jnp.uint32).reshape(1, 2))
-    in_specs.append(full((1, 2)))
-    args.append(jnp.asarray(noise_state, jnp.uint32).reshape(1, 2))
-    if stream:
-        in_specs += [full((Dp, Np)), full((1, Np))]
-        args += [_pad_axis(_pad_axis(
-            jnp.asarray(next_nbr_w, jnp.float32), Dp, 0), 128, 1),
-            row(next_h)]
-
-    out_shape = [jax.ShapeDtypeStruct((tb, Np), m_ext.dtype),
-                 jax.ShapeDtypeStruct((1, 2), jnp.uint32)]
-    out_specs = [full((tb, Np)), full((1, 2))]
-    if accumulate:
-        out_shape += [jax.ShapeDtypeStruct((1, Np), jnp.float32),
-                      jax.ShapeDtypeStruct((Dp, Np), jnp.float32)]
-        out_specs += [full((1, Np)), full((Dp, Np))]
-    if stream:
-        out_shape += [jax.ShapeDtypeStruct((Dp, Np), jnp.float32),
-                      jax.ShapeDtypeStruct((1, Np), jnp.float32)]
-        out_specs += [full((Dp, Np)), full((1, Np))]
-
-    scratch = [_VMEM((2, 2, tb, Hp), jnp.float32),   # send slots
-               _VMEM((2, 2, tb, Hp), jnp.float32),   # recv slots
-               pltpu.SemaphoreType.DMA((2, 2)),
-               pltpu.SemaphoreType.DMA((2, 2))]
-    if accumulate:
-        scratch += [_VMEM((1, Np), jnp.float32), _VMEM((Dp, Np),
-                                                       jnp.float32)]
-    if stream:
-        scratch += [_VMEM((Dp, Np), jnp.float32), _VMEM((1, Np),
-                                                        jnp.float32)]
-
-    # stream excludes accumulate, so staged outputs sit at 2/3
-    aliases = {len(args) - 2: 2, len(args) - 1: 3} if stream else {}
-    outs = pl.pallas_call(
-        functools.partial(
-            _exchange_kernel, S=S, tb=tb, Np=Np, B=B, n_loc=n_loc, H=H,
-            Hp=Hp, segments=segments, mode=mode, has_clamp=has_clamp,
-            accumulate=accumulate, D=D, axis_name=axis_name, n_row=n_row,
-            collective_id=collective_id, stream=stream),
-        grid=(),
-        in_specs=in_specs,
-        out_specs=tuple(out_specs),
-        out_shape=tuple(out_shape),
-        scratch_shapes=scratch,
-        input_output_aliases=aliases,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(), has_side_effects=True,
-            collective_id=collective_id),
-        interpret=False,
-    )(*args)
-
-    result = [outs[0][:B, :N], outs[1].reshape(2)]
-    k = 2
-    if accumulate:
-        result += [outs[k][0, :N], outs[k + 1][:D, :N]]
-        k += 2
-    if stream:
-        result += [outs[k][:D, :N], outs[k + 1][0, :N]]
-    return tuple(result)

@@ -17,7 +17,6 @@ Results are cached as JSON per cell under --out (reruns skip clean cells).
 Usage:
   python -m repro.launch.dryrun --arch gemma2-9b --shape train_4k --mesh pod
   python -m repro.launch.dryrun --all --mesh both        # the full sweep
-  python -m repro.launch.dryrun --pbit pbit-pod-2m       # paper's own arch
 """
 import argparse
 import json
@@ -25,11 +24,8 @@ import time
 import traceback
 from pathlib import Path
 
-import jax
-import numpy as np
-
 from repro.configs.base import LM_SHAPES, shape_applicable
-from repro.configs.registry import ARCH_IDS, PBIT_CONFIGS, get_config
+from repro.configs.registry import ARCH_IDS, get_config
 from repro.launch import mesh as mesh_mod
 from repro.launch.steps import make_step
 
@@ -124,70 +120,10 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     return rec
 
 
-def run_pbit(name: str, multi_pod: bool, out_dir: Path,
-             force: bool = False, chains: int = 1,
-             dtype: str = "float32") -> dict:
-    """Dry-run the paper's own architecture: a distributed Chimera lattice."""
-    from repro.core.distributed import (
-        LatticeChip, LatticeSpec, make_lattice_anneal, make_sk_lattice,
-        lattice_input_sharding)
-    import jax.numpy as jnp
-
-    mesh_tag = "multipod" if multi_pod else "pod"
-    out_path = out_dir / f"{name}__anneal__{mesh_tag}.json"
-    if out_path.exists() and not force:
-        rec = json.loads(out_path.read_text())
-        if rec.get("status") == "ok":
-            print(f"[cached] {name} x {mesh_tag}: ok")
-            return rec
-    spec_d = PBIT_CONFIGS[name]
-    spec = LatticeSpec(spec_d["cell_rows"], spec_d["cell_cols"],
-                       chains=chains)
-    mesh = mesh_mod.make_production_mesh(multi_pod=multi_pod)
-    # the spatial cut is 1-D over cell rows (docs/sharding.md): use every
-    # mesh axis so all chips hold a row band (512 rows >= 512 chips)
-    row_axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    rec = {"arch": name, "shape": "anneal_1k_sweeps", "mesh": mesh_tag,
-           "n_spins": spec.n_spins, "chains": chains, "dtype": dtype}
-    t0 = time.time()
-    try:
-        run = make_lattice_anneal(spec, mesh, row_axes=row_axes,
-                                  n_sweeps=1000, record_every=100)
-        chip_a = jax.eval_shape(
-            lambda k: make_sk_lattice(spec, k, dtype=jnp.dtype(dtype)),
-            jax.random.PRNGKey(0))
-        betas_a = jax.ShapeDtypeStruct((1000,), jnp.float32)
-        key_a = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        with mesh:
-            lowered = run.lower(chip_a, key_a, betas_a)
-            compiled = lowered.compile()
-        from benchmarks.roofline import (collective_bytes_from_hlo,
-                                         dot_flops_from_hlo)
-        hlo = compiled.as_text()
-        rec.update(
-            status="ok",
-            compile_s=round(time.time() - t0, 2),
-            n_devices=mesh_mod.n_chips(mesh),
-            memory=_mem_stats(compiled),
-            cost=_cost_stats(compiled),
-            collectives=collective_bytes_from_hlo(hlo),
-            dot_flops=dot_flops_from_hlo(hlo),
-        )
-        print(f"[ok]     {name} ({spec.n_spins/1e6:.1f}M spins) x "
-              f"{mesh_tag}: compile={rec['compile_s']}s")
-    except Exception as e:
-        rec.update(status="fail", error=repr(e),
-                   trace=traceback.format_exc()[-4000:])
-        print(f"[FAIL]   {name} x {mesh_tag}: {e!r}")
-    out_path.write_text(json.dumps(rec, indent=1))
-    return rec
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(LM_SHAPES))
-    ap.add_argument("--pbit", choices=list(PBIT_CONFIGS))
     ap.add_argument("--mesh", choices=["pod", "multipod", "both"],
                     default="pod")
     ap.add_argument("--all", action="store_true",
@@ -196,8 +132,6 @@ def main() -> None:
     ap.add_argument("--force", action="store_true")
     ap.add_argument("--opt-bits", type=int, default=32, choices=[8, 32])
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--chains", type=int, default=1)
-    ap.add_argument("--pbit-dtype", default="float32")
     args = ap.parse_args()
 
     out_dir = Path(args.out)
@@ -206,12 +140,7 @@ def main() -> None:
               "both": [False, True]}[args.mesh]
 
     n_fail = 0
-    if args.pbit:
-        for mp in meshes:
-            rec = run_pbit(args.pbit, mp, out_dir, args.force,
-                           args.chains, args.pbit_dtype)
-            n_fail += rec["status"] == "fail"
-    elif args.all:
+    if args.all:
         for arch in ARCH_IDS:
             for shape_name in LM_SHAPES:
                 for mp in meshes:
@@ -220,7 +149,7 @@ def main() -> None:
                                    args.microbatches)
                     n_fail += rec["status"] == "fail"
     else:
-        assert args.arch and args.shape, "--arch/--shape or --all or --pbit"
+        assert args.arch and args.shape, "--arch/--shape or --all"
         for mp in meshes:
             rec = run_cell(args.arch, args.shape, mp, out_dir, args.force,
                            args.opt_bits, args.microbatches)

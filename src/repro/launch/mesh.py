@@ -68,10 +68,9 @@ def halo_vs_hbm_seconds(halo_bytes: int, hbm_bytes: int,
     ``exchanges`` is the policy's per-sweep transfer count
     (`Sync.exchanges_per_sweep()`); each transfer pays a fixed
     ``ICI_LAT_S`` hop-setup latency on top of the bandwidth term.  Small
-    halos are latency-bound — the cost the kernel-resident exchange
-    amortizes by keeping the refresh inside one launch —
-    ``ici_latency_share`` says how much of the ICI time that fixed cost
-    is."""
+    halos are latency-bound — the cost a sparser exchange schedule
+    (larger ``halo_every``) amortizes — ``ici_latency_share`` says how
+    much of the ICI time that fixed cost is."""
     t_bw = halo_bytes / ICI_BW
     t_lat = exchanges * ICI_LAT_S
     t_ici = t_bw + t_lat

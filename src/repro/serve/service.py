@@ -622,8 +622,8 @@ class SamplerService:
         # every Session compiled against the dead mesh is garbage now;
         # survivors recompile lazily on the re-planned mesh.  That
         # recompile rebuilds the whole engine closure — including the
-        # fused-resident-exchange loop shape when the sync policy has
-        # mid-launch exchange points — and the numpy row plan itself
+        # fused exchange windows when the sync policy has mid-launch
+        # exchange points — and the numpy row plan itself
         # comes from the memoized plan_row_partition cache, so a re-plan
         # onto a previously-seen shard count never recomputes it
         self.metrics["cache_invalidated"] += self.cache.invalidate(

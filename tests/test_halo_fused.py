@@ -1,19 +1,16 @@
-"""Kernel-resident halo exchange (docs/kernels.md §In-kernel halo exchange).
+"""Fused exchange windows (docs/kernels.md §Fused exchange windows).
 
-The fused-resident-exchange loop shape lets a fused launch refresh halos
-MID-FLIGHT: on TPU meshes the kernel itself RDMAs the O(√N) boundary at
-every `Sync.exchange_points()` half-sweep; on host CI the engine runs the
-bit-exact emulation — the same launch split at the exchange points into
-`half_offset`/`n_half` windows of the resident kernel with a ppermute
-between windows, one jitted graph.  This file pins the contracts the
-hardware path must reproduce:
+A fused launch refreshes halos mid-launch: the engine splits it at the
+`Sync.exchange_points()` half-sweeps into `half_offset`/`n_half` windows
+of the resident kernel with a ppermute between windows, one jitted graph,
+on CPU hosts and TPU meshes alike.  This file pins its contracts:
 
   * the half-sweep-window kernel parameters chain bit-exactly (a launch
     split at arbitrary cuts equals the unsplit launch, spins + noise +
-    moments + staged program uploads);
-  * fused kernel-resident exchange under `Sync(halo_every=1,
-    mode="barrier")` equals the single-device Session bit for bit on a
-    forced 2-device host, chained program streams included;
+    moments);
+  * fused exchange windows under `Sync(halo_every=1, mode="barrier")`
+    equal the single-device Session bit for bit on a forced 2-device
+    host, chained program streams included;
   * relaxed policies (halo_every=k, async) equal the existing sparse
     segment-scan engine bit for bit under the same seeds;
   * `plan_row_partition` memoizes (serving's shard-loss re-plan hits the
@@ -126,7 +123,7 @@ def test_exchange_points_edges():
 
 
 def test_fused_compatible_windows():
-    # kernel-resident exchange: any halo_every <= sweeps_per_launch
+    # exchange windows: any halo_every <= sweeps_per_launch
     assert api.Sync(halo_every=1, sweeps_per_launch=4).fused_compatible
     assert api.Sync(halo_every=4, sweeps_per_launch=4).fused_compatible
     assert api.Sync(halo_every=1, sweeps_per_launch=1).fused_compatible
@@ -203,41 +200,6 @@ def test_window_chaining_matches_single_launch(cuts):
     np.testing.assert_array_equal(np.asarray(csum), np.asarray(whole[3]))
 
 
-def test_stream_window_chaining_keeps_staged_upload():
-    """A program upload and a segmented launch share one resident stream:
-    `sweep_sparse_stream_pallas` windows chain bit-exactly AND every
-    window's staged output is the next program's weights — so a halo
-    refresh and a weight upload ride the same launch."""
-    from repro.kernels.sweep_fused import (
-        sweep_sparse_pallas,
-        sweep_sparse_stream_pallas,
-    )
-
-    g, ses, chip, m0, masks, betas, ns0 = _sparse_setup()
-    rng = np.random.default_rng(7)
-    nxt = ses.program_edges(
-        jnp.asarray(rng.integers(-60, 60, g.n_edges), jnp.int32),
-        jnp.asarray(rng.integers(-15, 15, g.n_nodes), jnp.int32))
-    S = betas.shape[0]
-    plain = sweep_sparse_pallas(
-        m0, chip.nbr_idx, chip.nbr_w, chip.h, chip.tanh_gain,
-        chip.tanh_offset, chip.rand_gain, chip.comp_offset, *masks,
-        betas, ns0, noise_mode="counter", block_b=8, interpret=True)
-    m_c, ns_c = m0, ns0
-    for h0, h1 in halo_exchange_segments((0, 3, 8), 2 * S):
-        m_c, ns_c, w_next, h_next = sweep_sparse_stream_pallas(
-            m_c, chip.nbr_idx, chip.nbr_w, chip.h, chip.tanh_gain,
-            chip.tanh_offset, chip.rand_gain, chip.comp_offset, *masks,
-            betas, ns_c, nxt.nbr_w, nxt.h, block_b=8, interpret=True,
-            half_offset=h0, n_half=h1 - h0)
-        np.testing.assert_array_equal(
-            np.asarray(w_next), np.asarray(nxt.nbr_w, np.float32))
-        np.testing.assert_array_equal(
-            np.asarray(h_next), np.asarray(nxt.h, np.float32))
-    np.testing.assert_array_equal(np.asarray(m_c), np.asarray(plain[0]))
-    np.testing.assert_array_equal(np.asarray(ns_c), np.asarray(plain[1]))
-
-
 def test_window_validation():
     from repro.kernels.sweep_fused import sweep_sparse_pallas
 
@@ -294,7 +256,7 @@ def test_one_shard_fused_exchange_bit_exact(sync):
 # forced 2-device host: the acceptance contracts
 # ---------------------------------------------------------------------------
 def test_two_device_fused_exchange_k1_bit_exact():
-    """Fused kernel-resident exchange under Sync(halo_every=1, barrier)
+    """Fused exchange windows under Sync(halo_every=1, barrier)
     == the single-device Session bit for bit — spins, noise state, AND
     moments — including a chained program stream (two programs through
     sample_program on the same executable)."""
@@ -357,7 +319,7 @@ def test_two_device_fused_exchange_k1_bit_exact():
 
 def test_two_device_fused_exchange_relaxed_matches_segment_scan():
     """Relaxed policies (halo_every=k barrier, async) through the
-    kernel-owned exchange == the existing sparse segment-scan engine bit
+    fused exchange windows == the existing sparse segment-scan engine bit
     for bit under the same seeds (spins and noise state)."""
     rec = _run_forced("""
     import json
@@ -423,7 +385,7 @@ def test_halo_napkin_latency_term():
     assert two["ici_s"] == pytest.approx(halo / ICI_BW + 2.0 * ICI_LAT_S)
     assert 0.0 < two["ici_latency_share"] < 1.0
     # small halos are latency-bound: the fixed cost dominates the wire
-    # time — exactly what the kernel-resident exchange amortizes
+    # time — what a sparser exchange schedule amortizes
     small = halo_vs_hbm_seconds(128, hbm, exchanges=2.0)
     assert small["ici_latency_share"] > 0.9
     assert small["ici_over_hbm"] > base["ici_over_hbm"]

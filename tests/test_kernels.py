@@ -73,30 +73,3 @@ def test_kernel_wrapper_integrates_with_sampler():
     m_r, _, _ = pbit.gibbs_sample(chip, jnp.asarray(g.color), m0, betas,
                                   jax.random.PRNGKey(1), noise)
     np.testing.assert_array_equal(np.asarray(m_k), np.asarray(m_r))
-
-
-@pytest.mark.parametrize("B,R,C,br", [(2, 8, 8, 4), (4, 16, 4, 8),
-                                      (1, 8, 32, 8)])
-def test_lattice_kernel_matches_ref(B, R, C, br):
-    from repro.kernels.lattice_update import lattice_vertical_update_pallas
-    from repro.kernels.ref import lattice_vertical_update_ref
-
-    rng = np.random.default_rng(B * R + C)
-    k = 4
-    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
-    sp = lambda *s: jnp.asarray(rng.integers(0, 2, s) * 2 - 1, jnp.float32)
-    m_v, m_h = sp(B, R, C, k), sp(B, R, C, k)
-    up, dn = sp(B, R, C, k), sp(B, R, C, k)
-    W = mk(R, C, k, k) * 0.5
-    wu, wd, h = mk(R, C, k), mk(R, C, k), mk(R, C, k) * 0.3
-    g = 1 + 0.1 * mk(R, C, k)
-    u = jnp.asarray(rng.uniform(-1, 1, (B, R, C, k)), jnp.float32)
-    par = jnp.asarray(
-        np.add.outer(np.arange(R), np.arange(C)) % 2, jnp.int32)
-    for color in (0, 1):
-        ref = lattice_vertical_update_ref(m_v, m_h, up, dn, W, wu, wd, h,
-                                          g, u, par, color)
-        out = lattice_vertical_update_pallas(
-            m_v, m_h, up, dn, W, wu, wd, h, g, u, par, color=color,
-            block_r=br, interpret=True)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

@@ -1,87 +1,69 @@
-"""SoA lattice -> shared slot-layout engine: converter + anneal parity.
+"""Chimera spin-glass lattices through `api.Session`: the slot layout
+against a dense reference, and an anneal that lowers the energy.
 
-The old private SoA update loop is retired (PR: mesh-sharded sparse
-lattice); `lattice_to_chip` converts the structure-of-arrays couplings to
-the shared `EffectiveChip` slot layout and the lattice anneal runs the
-same engine as every other workload.  These tests pin the conversion
-against an explicit dense reconstruction of the directional W — sampling
-through the converted chip must match the dense reference bit for bit.
+A lattice is a `PBitMachine` on `make_chimera` with ideal hardware,
+programmed by `program_edges` from edge codes drawn like an SK instance
+(couplings ~ N(0, 0.8) at the default coupling LSB), and scored by
+`core.distributed.sparse_energy`.  Sampling the slot chip must match the
+dense reference bit for bit.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import pbit
+from repro import api
+from repro.core.cd import PBitMachine
 from repro.core.chimera import make_chimera
-from repro.core.distributed import (
-    LatticeChip,
-    LatticeSpec,
-    lattice_to_chip,
-    make_lattice_anneal,
-    make_sk_lattice,
-    sparse_energy,
-)
-from repro.core.hardware import EffectiveChip, HardwareConfig
+from repro.core.distributed import sparse_energy
+from repro.core.hardware import HardwareConfig
 
 
-def _dense_from_lattice(spec: LatticeSpec, chip: LatticeChip):
-    """Dense directional W (N, N) + h from the SoA lattice arrays."""
-    R, C, k = spec.cell_rows, spec.cell_cols, spec.k
-    N = R * C * 2 * k
-
-    def nid(r, c, s, i):
-        return (((r * C) + c) * 2 + s) * k + i
-
-    W = np.zeros((N, N), np.float32)
-    h = np.zeros((N,), np.float32)
-    cv = np.asarray
-    for r in range(R):
-        for c in range(C):
-            for i in range(k):
-                h[nid(r, c, 0, i)] = cv(chip.h_v)[r, c, i]
-                h[nid(r, c, 1, i)] = cv(chip.h_h)[r, c, i]
-                for j in range(k):
-                    # current INTO vertical i from horizontal j
-                    W[nid(r, c, 0, i), nid(r, c, 1, j)] = \
-                        cv(chip.W_vh)[r, c, i, j]
-                    W[nid(r, c, 1, i), nid(r, c, 0, j)] = \
-                        cv(chip.W_hv)[r, c, i, j]
-                if r + 1 < R:
-                    W[nid(r + 1, c, 0, i), nid(r, c, 0, i)] = \
-                        cv(chip.Wv_dn)[r, c, i]
-                    W[nid(r, c, 0, i), nid(r + 1, c, 0, i)] = \
-                        cv(chip.Wv_up)[r, c, i]
-                if c + 1 < C:
-                    W[nid(r, c + 1, 1, i), nid(r, c, 1, i)] = \
-                        cv(chip.Wh_rt)[r, c, i]
-                    W[nid(r, c, 1, i), nid(r, c + 1, 1, i)] = \
-                        cv(chip.Wh_lt)[r, c, i]
-    return W, h
+def lattice_codes(g, seed, sigma=16.0):
+    """SK-style edge codes (zero biases): N(0, sigma) codes, rounded and
+    clipped to the 8-bit DAC range."""
+    rng = np.random.default_rng(seed)
+    J = np.clip(np.rint(rng.normal(0.0, sigma, g.n_edges)), -127, 127)
+    return (jnp.asarray(J, jnp.int32),
+            jnp.zeros((g.n_nodes,), jnp.int32))
 
 
-def _dense_chip(spec, lat):
-    """Dense EffectiveChip with the same gains/offsets as the converter."""
-    W, h = _dense_from_lattice(spec, lat)
-    gain = np.stack([np.asarray(lat.gain_v), np.asarray(lat.gain_h)],
-                    axis=2).reshape(-1)
-    off = np.stack([np.asarray(lat.off_v), np.asarray(lat.off_h)],
-                   axis=2).reshape(-1)
-    N = spec.n_spins
-    ones = jnp.ones((N,), jnp.float32)
-    return EffectiveChip(
-        W=jnp.asarray(W), h=jnp.asarray(h), tanh_gain=jnp.asarray(gain),
-        tanh_offset=jnp.asarray(off), rand_gain=ones,
-        comp_offset=0.0 * ones)
+def lattice_machine(g, sparse=True, **kw):
+    kw.setdefault("backend", "sparse" if sparse else "ref")
+    return PBitMachine.create(g, jax.random.PRNGKey(0),
+                              HardwareConfig.ideal(), sparse=sparse,
+                              noise="counter", **kw)
 
 
-def test_lattice_to_chip_matches_dense_reference():
-    """Converted slot weights == a gather of the dense directional W, and
-    sampling through the converted chip is bit-exact vs the dense ref."""
-    spec = LatticeSpec(3, 2, chains=2)
-    lat = make_sk_lattice(spec, jax.random.PRNGKey(0), HardwareConfig())
-    g = make_chimera(spec.cell_rows, spec.cell_cols, spec.k)
-    chip_s = lattice_to_chip(spec, lat, g)
-    chip_d = _dense_chip(spec, lat)
+def anneal(ses, chip, key, betas, record_every, score=None):
+    """Sample ``betas`` in segments of ``record_every`` sweeps; return the
+    final spins and each segment's mean energy (``score(m)``, per chain;
+    `sparse_energy` on ``chip`` by default)."""
+    if score is None:
+        score = functools.partial(sparse_energy, chip)
+    k1, k2 = jax.random.split(key)
+    m, ns = ses.random_spins(k1), ses.noise_state(k2)
+    energies = []
+    for seg in jnp.reshape(betas, (-1, record_every)):
+        m, ns, _ = ses.sample(chip, m, ns, seg)
+        energies.append(float(score(m).mean()))
+    return m, np.asarray(energies)
+
+
+def test_lattice_slot_chip_matches_dense_reference():
+    """The slot chip's weights are a gather of the densely programmed W,
+    sampling the slot chip (backend "sparse") is bit-exact against the
+    dense ref, and `sparse_energy` equals the dense quadratic form."""
+    g = make_chimera(3, 2)
+    J, h = lattice_codes(g, 0)
+    B = 4
+    ses_s = api.Session(lattice_machine(g).sampler_spec(chains=B))
+    ses_d = api.Session(lattice_machine(g, sparse=False).sampler_spec(
+        chains=B))
+    chip_s = ses_s.program_edges(J, h)
+    chip_d = ses_d.program_edges(J, h)
+    assert chip_s.W is None
 
     nbr_idx = np.asarray(chip_s.nbr_idx)
     rows = np.arange(g.n_nodes)[None, :]
@@ -92,18 +74,12 @@ def test_lattice_to_chip_matches_dense_reference():
     np.testing.assert_array_equal(np.asarray(chip_s.tanh_gain),
                                   np.asarray(chip_d.tanh_gain))
 
-    # full Gibbs parity: sparse slot path on the converted chip vs the
-    # dense ref path on the reconstruction, same noise stream
-    B = 4
-    m0 = pbit.random_spins(jax.random.PRNGKey(1), B, g.n_nodes)
-    init, step = pbit.make_counter_noise(B, g.n_nodes)
-    state = init(jax.random.PRNGKey(2))
+    # full Gibbs parity under one noise stream
+    m0 = ses_s.random_spins(jax.random.PRNGKey(1))
+    ns = ses_s.noise_state(jax.random.PRNGKey(2))
     betas = jnp.linspace(0.3, 1.2, 7)
-    color = jnp.asarray(g.color)
-    m_s, _, _ = pbit.gibbs_sample(chip_s, color, m0, betas, state, step,
-                                  backend="sparse")
-    m_d, _, _ = pbit.gibbs_sample(chip_d, color, m0, betas, state, step,
-                                  backend="ref")
+    m_s, _, _ = ses_s.sample(chip_s, m0, ns, betas)
+    m_d, _, _ = ses_d.sample(chip_d, m0, ns, betas)
     np.testing.assert_array_equal(np.asarray(m_s), np.asarray(m_d))
 
     # energy parity vs the explicit dense quadratic form
@@ -116,11 +92,11 @@ def test_lattice_to_chip_matches_dense_reference():
 
 
 def test_chain_batched_anneal_energy_decreases():
-    spec = LatticeSpec(6, 6, chains=8)
-    chip = make_sk_lattice(spec, jax.random.PRNGKey(0),
-                           HardwareConfig.ideal())
-    run = make_lattice_anneal(spec, None, n_sweeps=80, record_every=20)
-    m, e = run(chip, jax.random.PRNGKey(1), jnp.linspace(0.05, 2.5, 80))
-    e = np.asarray(e)
-    assert m.shape == (spec.chains, spec.n_spins)
+    g = make_chimera(6, 6)
+    chains = 8
+    ses = api.Session(lattice_machine(g).sampler_spec(chains=chains))
+    chip = ses.program_edges(*lattice_codes(g, 0))
+    m, e = anneal(ses, chip, jax.random.PRNGKey(1),
+                  jnp.linspace(0.05, 2.5, 80), record_every=20)
+    assert m.shape == (chains, g.n_nodes)
     assert e[-1] < 0 and e[-1] < 0.8 * e[0]

@@ -338,29 +338,35 @@ def test_four_device_2d_rows_x_chains():
 
 
 def test_lattice_anneal_sharded_matches_single():
-    """make_lattice_anneal through the shared engine: the sharded run is
-    bit-identical to the single-device run (same key => same counter
-    stream), not merely the same energy scale."""
+    """A lattice anneal through a row-sharded Session is bit-identical to
+    the single-device run (same key => same counter stream), not merely
+    the same energy scale."""
     rec = _run_forced("""
+    import sys
+    sys.path.insert(0, "tests")
     import jax, json
     import jax.numpy as jnp
     import numpy as np
-    from repro.core.distributed import (LatticeSpec, make_lattice_anneal,
-                                        make_sk_lattice)
-    from repro.core.hardware import HardwareConfig
-    spec = LatticeSpec(4, 4, chains=2)
-    chip = make_sk_lattice(spec, jax.random.PRNGKey(0),
-                           HardwareConfig.ideal())
+    from repro import api
+    from repro.core.chimera import make_chimera
+    from repro.core.distributed import sparse_energy
+    from test_lattice import anneal, lattice_codes, lattice_machine
+    g = make_chimera(4, 4)
+    codes = lattice_codes(g, 0)
     betas = jnp.linspace(0.1, 2.0, 20)
-    run1 = make_lattice_anneal(spec, None, n_sweeps=20, record_every=10)
-    m1, e1 = run1(chip, jax.random.PRNGKey(1), betas)
+    ses1 = api.Session(lattice_machine(g).sampler_spec(chains=2))
+    chip1 = ses1.program_edges(*codes)
+    m1, e1 = anneal(ses1, chip1, jax.random.PRNGKey(1), betas, 10)
     mesh = jax.make_mesh((2,), ("data",))
-    run2 = make_lattice_anneal(spec, mesh, n_sweeps=20, record_every=10)
-    m2, e2 = run2(chip, jax.random.PRNGKey(1), betas)
+    ses2 = api.Session(lattice_machine(g).sampler_spec(
+        chains=2, mesh=mesh, partition=api.Partition(rows="data")))
+    # both runs scored on one device: equal spins give equal energies
+    m2, e2 = anneal(ses2, ses2.program_edges(*codes), jax.random.PRNGKey(1),
+                    betas, 10, score=lambda m: sparse_energy(
+                        chip1, jnp.asarray(np.asarray(m))))
     ok_m = bool(np.array_equal(np.asarray(m1), np.asarray(m2)))
-    ok_e = bool(np.array_equal(np.asarray(e1), np.asarray(e2)))
-    print(json.dumps({"m": ok_m, "e": ok_e,
-                      "e_last": float(np.asarray(e2)[-1])}))
+    ok_e = bool(np.array_equal(e1, e2))
+    print(json.dumps({"m": ok_m, "e": ok_e, "e_last": float(e1[-1])}))
     """, n_dev=2)
     assert rec["m"] and rec["e"]
     assert rec["e_last"] < 0

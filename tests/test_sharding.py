@@ -95,27 +95,26 @@ def test_sharded_train_step_matches_single_device(arch):
 def test_distributed_lattice_matches_energy_scale():
     """Sharded Chimera lattice anneals to the same energy scale (4 dev)."""
     script = textwrap.dedent("""
-        import os
+        import os, sys
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        sys.path.insert(0, "tests")
         import jax, json
         import jax.numpy as jnp
         import numpy as np
-        from repro.core.distributed import (LatticeSpec, make_lattice_anneal,
-                                            make_sk_lattice,
-                                            lattice_input_sharding)
-        from repro.core.hardware import HardwareConfig
-        spec = LatticeSpec(8, 8)
-        chip = make_sk_lattice(spec, jax.random.PRNGKey(0),
-                               HardwareConfig.ideal())
+        from repro import api
+        from repro.core.chimera import make_chimera
+        from test_lattice import anneal, lattice_codes, lattice_machine
+        g = make_chimera(8, 8)
+        codes = lattice_codes(g, 0)
         betas = jnp.linspace(0.1, 2.5, 60)
-        run1 = make_lattice_anneal(spec, None, n_sweeps=60, record_every=20)
-        _, e1 = run1(chip, jax.random.PRNGKey(1), betas)
+        ses1 = api.Session(lattice_machine(g).sampler_spec(chains=1))
+        _, e1 = anneal(ses1, ses1.program_edges(*codes),
+                       jax.random.PRNGKey(1), betas, record_every=20)
         mesh = jax.make_mesh((2, 2), ("data", "model"))
-        run2 = make_lattice_anneal(spec, mesh, n_sweeps=60, record_every=20)
-        sh = lattice_input_sharding(mesh)
-        chip_sh = jax.device_put(chip, jax.tree.map(lambda _: sh, chip))
-        _, e2 = run2(chip_sh, jax.random.PRNGKey(1), betas)
-        e1 = np.asarray(e1); e2 = np.asarray(e2)
+        ses2 = api.Session(lattice_machine(g).sampler_spec(
+            chains=1, mesh=mesh, partition=api.Partition(rows="data")))
+        _, e2 = anneal(ses2, ses2.program_edges(*codes),
+                       jax.random.PRNGKey(1), betas, record_every=20)
         print(json.dumps({"e1": float(e1[e1 != 0][-1]),
                           "e2": float(e2[e2 != 0][-1])}))
     """)

@@ -7,10 +7,8 @@ calling `Session.sample`, for every backend and noise kind — and swapping
 programs must never retrace.  The fleet axis (`sample_fleet`,
 `make_cd_fleet_step`) vmaps that operand: a stacked K-program batch
 through one executable must match K sequential single-program calls bit
-for bit (fused backends demote to their scan siblings under vmap).  The
-double-buffered upload kernel (`sweep_sparse_stream_pallas`) must run the
-CURRENT program exactly as the plain resident kernel while staging the
-NEXT program unchanged.  Multi-device cases run in subprocesses with a
+for bit (fused backends demote to their scan siblings under vmap).
+Multi-device cases run in subprocesses with a
 forced host platform (XLA_FLAGS must be set before jax initializes).
 """
 import json
@@ -28,10 +26,6 @@ from repro import api
 from repro.core.cd import CDConfig, PBitMachine
 from repro.core.chimera import make_chimera
 from repro.core.hardware import sample_mismatch_sparse
-from repro.kernels.sweep_fused import (
-    sweep_sparse_pallas,
-    sweep_sparse_stream_pallas,
-)
 
 ROOT = Path(__file__).resolve().parent.parent
 SUBPROC_ENV = {"PYTHONPATH": f"{ROOT}/src", "PATH": "/usr/bin:/bin",
@@ -232,52 +226,6 @@ def test_fleet_cd_matches_sequential():
         for name in out_k[5]:
             np.testing.assert_array_equal(np.asarray(out_f[5][name][k]),
                                           np.asarray(out_k[5][name]))
-
-
-# ---------------------------------------------------------------------------
-# double-buffered program upload kernel
-# ---------------------------------------------------------------------------
-def test_stream_kernel_chain_matches_serialized():
-    """A 4-program chain through `sweep_sparse_stream_pallas` (each launch
-    runs program i while staging program i+1) is bit-identical to four
-    serialized `sweep_sparse_pallas` launches, and every staged output is
-    exactly the next program's weights."""
-    g = make_chimera(2, 2)
-    mach = PBitMachine.create(g, jax.random.PRNGKey(0), sparse=True,
-                              noise="counter")
-    ses = _session(mach, chains=6)
-    chips = [ses.program_edges(*_codes(g, 60 + i)) for i in range(4)]
-    c0 = chips[0]
-    masks = (jnp.asarray(g.color == 0), jnp.asarray(g.color == 1))
-    m0 = ses.random_spins(jax.random.PRNGKey(2))
-    betas = jnp.broadcast_to(jnp.linspace(0.3, 1.5, 3)[:, None], (3, 6))
-    ns0 = jnp.asarray([42, 0], jnp.uint32)
-
-    def plain(chip, m, ns):
-        return sweep_sparse_pallas(
-            m, c0.nbr_idx, chip.nbr_w, chip.h, chip.tanh_gain,
-            chip.tanh_offset, chip.rand_gain, chip.comp_offset, *masks,
-            betas, ns, noise_mode="counter", block_b=8, interpret=True)
-
-    m_s, ns_s = m0, ns0
-    for chip in chips:
-        m_s, ns_s = plain(chip, m_s, ns_s)
-
-    m_d, ns_d = m0, ns0
-    w, h = chips[0].nbr_w, chips[0].h
-    for i, chip in enumerate(chips):
-        nxt = chips[(i + 1) % 4]
-        m_d, ns_d, w_next, h_next = sweep_sparse_stream_pallas(
-            m_d, c0.nbr_idx, w, h, chip.tanh_gain, chip.tanh_offset,
-            chip.rand_gain, chip.comp_offset, *masks, betas, ns_d,
-            nxt.nbr_w, nxt.h, block_b=8, interpret=True)
-        np.testing.assert_array_equal(np.asarray(w_next),
-                                      np.asarray(nxt.nbr_w, np.float32))
-        np.testing.assert_array_equal(np.asarray(h_next),
-                                      np.asarray(nxt.h, np.float32))
-        w, h = w_next, h_next
-    np.testing.assert_array_equal(np.asarray(m_d), np.asarray(m_s))
-    np.testing.assert_array_equal(np.asarray(ns_d), np.asarray(ns_s))
 
 
 # ---------------------------------------------------------------------------

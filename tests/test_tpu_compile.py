@@ -1,5 +1,7 @@
 """Compile rehearsals: every Pallas kernel of the main path, built for a
-described (not attached) TPU v5e at the chip's full width (N=440, B=128).
+described (not attached) TPU v5e at the chip's full width (N=440, B=128),
+and the sharded engine's fused exchange windows on a described 4-chip
+row mesh.
 
 Interpret mode runs any jnp inside a kernel; Mosaic does not (cross-vreg
 lane gathers, uint32 -> float32 casts, scalar stores to VMEM).  These
@@ -17,7 +19,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import pbit
@@ -138,17 +139,6 @@ def test_sweep_fused_dense_compiles(one_chip, chip_graph, noise):
                    .compile())
 
 
-def test_sweep_sparse_stream_compiles(one_chip, chip_graph):
-    n = chip_graph.n_nodes
-    d = chip_graph.neighbor_table()[0].shape[0]
-    args = _common(one_chip, n, d)
-    args += [_shape(one_chip, (2,), jnp.uint32),
-             _shape(one_chip, (d, n), jnp.float32),
-             _shape(one_chip, (n,), jnp.float32)]
-    _assert_kernel(sweep_fused.sweep_sparse_stream_pallas.lower(
-        *args, interpret=False).compile())
-
-
 def test_pbit_half_sweep_compiles(one_chip, chip_graph):
     n = chip_graph.n_nodes
     f32 = functools.partial(_shape, one_chip, dtype=jnp.float32)
@@ -158,33 +148,80 @@ def test_pbit_half_sweep_compiles(one_chip, chip_graph):
                    .compile())
 
 
-def test_exchange_kernel_compiles_on_four_chips(topo, chip_graph):
-    """The in-kernel RDMA halo exchange under shard_map on a 4-chip row
-    mesh: each shard holds a chip-width band plus its two halos."""
+# Half-sweep windows of one launch (`half_offset`, `n_half`), as the
+# sharded engine cuts a launch at its exchange points: a window that
+# starts on a sweep's second half, one that stops after a first half, and
+# one that does both.
+@pytest.mark.parametrize("half_offset,n_half", [
+    pytest.param(3, 2 * S - 3, id="starts-mid-sweep"),
+    pytest.param(0, 5, id="ends-mid-sweep"),
+    pytest.param(3, 6, id="both"),
+])
+def test_sweep_sparse_window_compiles(one_chip, chip_graph, half_offset,
+                                      n_half):
+    n = chip_graph.n_nodes
+    d = chip_graph.neighbor_table()[0].shape[0]
+    u32 = functools.partial(_shape, one_chip, (2,), jnp.uint32)
+    args = _common(one_chip, n, d) + [
+        u32(), None, None, _shape(one_chip, (S,), jnp.float32), None, u32()]
+    _assert_kernel(sweep_fused.sweep_sparse_pallas.lower(
+        *args, interpret=False, accumulate=True, half_offset=half_offset,
+        n_half=n_half).compile())
+
+
+# The sharded engine's fused loop on a 4-chip row mesh: each launch of 4
+# sweeps splits at halo_every=3 into windows [0, 3), [3, 6), [6, 8) with
+# a ppermute swap before each, under shard_map.  Built by the Session and
+# its `ShardedEngine`, with the static tables described where a run would
+# place them.
+@pytest.mark.parametrize("what", ["sample", "stats"])
+@pytest.mark.parametrize("mode", ["barrier", "async"])
+def test_sharded_fused_windows_compile(topo, monkeypatch, mode, what):
     from jax.sharding import Mesh
 
-    n_row, n_loc, H, d = 4, 440, 32, 6
-    n_ext = n_loc + 2 * H
-    mesh = Mesh(np.asarray(topo.devices).reshape(n_row), ("row",))
+    from repro import api
+    from repro.core import distributed as dist
+    from repro.core.cd import PBitMachine
+    from repro.core.chimera import make_chimera
+    from repro.core.hardware import (EffectiveChip, HardwareConfig,
+                                     sample_mismatch_sparse)
 
-    def local(m, idx, w, rows, masks, betas, ns, coords, send):
-        out = sweep_fused.sweep_sparse_exchange_pallas(
-            m[0], idx[0], w[0], *rows[0], masks[0, 0], masks[0, 1],
-            betas[0], ns[0], send[0, 0], send[0, 1],
-            coord_offset=coords[0], n_loc=n_loc, halo=H, ex_pts=(0, 4, 8),
-            mode="barrier", axis_name="row", n_row=n_row)
-        return out[0][None], out[1][None]
+    def described(x, sharding):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct((n_row, *shape), dtype,
-                                    sharding=NamedSharding(mesh, P("row")))
-
-    args = [sds((B, n_ext), jnp.float32), sds((d, n_ext), jnp.int32),
-            sds((d, n_ext), jnp.float32), sds((5, n_ext), jnp.float32),
-            sds((2, n_ext), jnp.bool_), sds((S, B), jnp.float32),
-            sds((2,), jnp.uint32), sds((2,), jnp.uint32),
-            sds((2, H), jnp.int32)]
-    fn = jax.shard_map(local, mesh=mesh, in_specs=(P("row"),) * len(args),
-                       out_specs=(P("row"), P("row")), check_vma=False)
-    compiled = jax.jit(fn).lower(*args).compile()
-    _assert_kernel(compiled)
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("row",))
+    part = api.Partition(rows="row")
+    g = make_chimera(16, 8)            # four bands of 256 spins
+    n, d = g.n_nodes, g.neighbor_table()[0].shape[0]
+    hw = HardwareConfig()
+    mm = jax.tree_util.tree_map(
+        described, jax.eval_shape(lambda k: sample_mismatch_sparse(
+            k, n, d, hw), jax.random.PRNGKey(0)),
+        dist.mismatch_shardings(mesh, part))
+    monkeypatch.setattr(dist.ShardedEngine, "_put", staticmethod(described))
+    sync = api.Sync(halo_every=3, mode=mode, sweeps_per_launch=4)
+    mach = PBitMachine(graph=g, hw=hw, mismatch=mm, noise="counter",
+                       backend="fused_sparse", mesh=mesh, partition=part,
+                       sync=sync)
+    ses = api.Session(mach.sampler_spec(chains=B).replace(interpret=False))
+    assert ses.backend == "fused_sparse"
+    assert dist.halo_exchange_segments(sync.exchange_points(), 8) == (
+        (0, 3), (3, 6), (6, 8))
+    eng = ses._engine
+    nodes = dist.band_sharding(mesh, part, 1, 0)
+    slots = dist.band_sharding(mesh, part, 2, 1)
+    f32 = functools.partial(_shape, nodes, (n,), jnp.float32)
+    chip = EffectiveChip(
+        W=None, h=f32(), tanh_gain=f32(), tanh_offset=f32(), rand_gain=f32(),
+        comp_offset=f32(), nbr_idx=_shape(slots, (d, n), jnp.int32),
+        nbr_w=_shape(slots, (d, n), jnp.float32))
+    m = _shape(eng.spin_sharding, (B, n), jnp.float32)
+    ns = _shape(eng.noise_sharding, (2,), jnp.uint32)
+    if what == "sample":
+        fn = ses._build_sample(False, False)
+        args = (chip, m, ns, _shape(eng.noise_sharding, (2 * 4,),
+                                    jnp.float32))
+    else:
+        fn = ses._build_stats(2 * 4, 2, 1.0, False)
+        args = (chip, m, ns)
+    _assert_kernel(fn.lower(*args).compile())
