@@ -655,6 +655,12 @@ class Session:
             return self._dispatch(fn, prog, m, noise_state, betas)
 
     def _build_sample_program(self, collect: bool):
+        # one jit: a changed optional-field structure (clamps, mismatch,
+        # program-borne betas) retraces, changed values never do
+        return self._jit(self._sample_program_impl(collect),
+                         "sample_program")
+
+    def _sample_program_impl(self, collect: bool):
         def impl(tables, prog, m, ns, betas):
             mm = self.spec.mismatch if prog.mismatch is None \
                 else prog.mismatch
@@ -672,9 +678,53 @@ class Session:
                 backend=self.backend, interpret=self.interpret,
                 flip_fn=self._flip_fn)
 
-        # one jit: a changed optional-field structure (clamps, mismatch,
-        # program-borne betas) retraces, changed values never do
-        return self._jit(impl, "sample_program")
+        return impl
+
+    def sample_program_keyed(
+        self,
+        prog: Program,
+        base_key: jax.Array,
+        seq: jax.Array,
+        betas: jax.Array,
+    ) -> tuple[jax.Array, jax.Array]:
+        """`sample_program` from fresh chains drawn inside the same
+        compiled call: (m', key).
+
+        ``key = fold_in(base_key, seq)`` splits into (km, kn); the initial
+        spins are `pbit.random_spins(km, chains, N)` and the noise state
+        `noise_state(kn)`, then the program runs as `sample_program` runs
+        it.  The spins are bit for bit those of that eager recipe, and
+        ``key`` is returned for the caller's replay record.  ``seq`` is an
+        operand (a uint32 scalar): one executable serves every launch and
+        every program of a structure, so a serving launch is one dispatch
+        (`serve.SamplerService`).  On a band-resident mesh the draws take
+        the engine's spin and noise shardings, as `random_spins` and
+        `noise_state` place them.
+        """
+        with span("session.sample_program_keyed"):
+            fn = self._fn(("sample_program_keyed",),
+                          self._build_sample_program_keyed)
+            return self._dispatch(fn, prog, base_key, seq, betas)
+
+    def _build_sample_program_keyed(self):
+        run = self._sample_program_impl(False)
+        chains, n = self.spec.chains, self.graph.n_nodes
+        eng = self._engine
+        m_sh = None if eng is None else eng.spin_sharding
+        ns_sh = None if eng is None else eng.noise_sharding
+
+        def impl(tables, prog, base_key, seq, betas):
+            key = jax.random.fold_in(base_key, seq)
+            km, kn = jax.random.split(key)
+            m = pbit.random_spins(km, chains, n)
+            ns = self._noise_init(kn)
+            if m_sh is not None:
+                m = jax.lax.with_sharding_constraint(m, m_sh)
+                ns = jax.lax.with_sharding_constraint(ns, ns_sh)
+            m, _, _ = run(tables, prog, m, ns, betas)
+            return m, key
+
+        return self._jit(impl, "sample_program_keyed")
 
     def sample_fleet(
         self,

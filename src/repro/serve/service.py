@@ -53,11 +53,9 @@ from collections import Counter, deque
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import api
-from repro.core import pbit
 from repro.core.chimera import ChimeraGraph
 from repro.core.distributed import surviving_mesh
 from repro.core.hardware import HardwareConfig, sample_mismatch_sparse
@@ -492,13 +490,12 @@ class SamplerService:
     def _execute(self, batch: list[Ticket]) -> None:
         seq = self._launch_seq
         self._launch_seq += 1
-        key = jax.random.fold_in(self._base_key, seq)
         t_start = self._clock()
         attempts = [0]
 
         def attempt():
             attempts[0] += 1
-            return self._attempt(batch, seq, key)
+            return self._attempt(batch, seq)
 
         with span("serve.launch", seq=seq, requests=len(batch),
                   chains=sum(t.req.chains for t in batch)):
@@ -507,7 +504,7 @@ class SamplerService:
             replays = 0
             while True:
                 try:
-                    m, fp, entry = retry_step(
+                    m, key, fp = retry_step(
                         attempt, max_retries=self.max_retries,
                         backoff_s=self.backoff_s,
                         max_backoff_s=self.max_backoff_s,
@@ -554,10 +551,10 @@ class SamplerService:
                     queue_s=t_start - t.t_admitted, exec_s=exec_s,
                     attempts=attempts[0], launch_seq=seq, chain_offset=off,
                     bucket_shape=t.bshape, bucket_fingerprint=fp,
-                    launch_key=np.asarray(key), request_id=t.request_id))
+                    launch_key=key, request_id=t.request_id))
                 off += t.req.chains
 
-    def _attempt(self, batch: list[Ticket], seq: int, key):
+    def _attempt(self, batch: list[Ticket], seq: int):
         if self.injector is not None:
             delay = self.injector.on_launch(seq, self)  # may raise Transient
             if delay:
@@ -567,27 +564,26 @@ class SamplerService:
         head = batch[0]
         with span("serve.entry"):
             fp, entry = self._entry_for(head.bshape)
-        bg = entry.embeddable
         with span("serve.inputs"):
-            km, kn = jax.random.split(key)
-            m0 = pbit.random_spins(km, self.capacity_chains, bg.n_nodes)
-            ns = entry.session.noise_state(kn)
-            cm, cv = self._assemble_clamps(batch, bg)
-            # scatter codes, call: the program (codes + clamps) is a
-            # runtime operand of the bucket Session's one compiled
-            # executable — no per-digest chip cache, no retrace on a new
-            # tenant problem
-            prog = entry.session.make_program(
-                jnp.asarray(head.Jb), jnp.asarray(head.hb),
-                clamp_mask=cm, clamp_values=cv)
-            betas = jnp.asarray(head.betas)
+            cm, cv = self._assemble_clamps(batch, entry.embeddable)
+            # the program (codes + clamps) and the launch seq are runtime
+            # operands of the bucket Session's one compiled executable,
+            # which draws the launch's spins and noise state itself: no
+            # per-digest chip cache, no retrace on a new tenant problem,
+            # and one host->device placement per launch
+            prog, seq_d, betas = jax.device_put((
+                api.Program(J_codes=head.Jb, h_codes=head.hb,
+                            clamp_mask=cm, clamp_values=cv),
+                np.uint32(seq), head.betas))
         with span("serve.dispatch"):
-            m, _, _ = entry.session.sample_program(prog, m0, ns, betas)
+            m, key = entry.session.sample_program_keyed(
+                prog, self._base_key, seq_d, betas)
         # materialize on the host *inside* the attempt: a shard dying
         # mid-launch surfaces here, where the replay machinery can see it
         with span("serve.fetch"):
-            m = np.asarray(m)
-        return m, fp, entry
+            m, key = jax.device_get((m, key))
+        key.setflags(write=False)  # shared by every result of the launch
+        return m, key, fp
 
     def _assemble_clamps(self, batch: list[Ticket], bg: ChimeraGraph):
         head = batch[0]
@@ -599,7 +595,7 @@ class SamplerService:
             vals = np.asarray(t.req.clamp_values, np.float32)
             cv[off:off + t.req.chains, t.emb.node_map] = vals
             off += t.req.chains
-        return jnp.asarray(head.bucket_mask), jnp.asarray(cv)
+        return head.bucket_mask, cv
 
     # ------------------------------------------------------------------
     # degradation ladder

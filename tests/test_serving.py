@@ -326,6 +326,104 @@ class TestServiceCore:
             :, emb.node_map]
         np.testing.assert_array_equal(res.spins, ref)
 
+    @pytest.mark.parametrize("clamped", [False, True],
+                             ids=["free", "clamped"])
+    def test_launch_matches_the_eager_recipe(self, g11, clamped):
+        """The launch's one compiled call draws what the eager recipe
+        draws: a result's spins and ``launch_key`` equal, bit for bit,
+        fold_in(seed, seq), split, `pbit.random_spins`,
+        `Session.noise_state`, `make_program` and `sample_program`."""
+        svc = _service(seed=11)
+        n = g11.n_nodes
+        kw = {}
+        if clamped:
+            mask = np.zeros(n, bool)
+            mask[[0, 5]] = True
+            vals = np.where(np.arange(3 * n).reshape(3, n) % 3, 1.0,
+                            -1.0).astype(np.float32)
+            kw = dict(clamp_mask=mask, clamp_values=vals)
+        svc.submit(_request(g11, chains=1, seed=9))          # launch 0
+        first = svc.submit(_request(g11, chains=1, seed=4, **(
+            dict(kw, clamp_values=-vals[:1]) if clamped else kw)))
+        req = _request(g11, chains=3, seed=4, **kw)
+        ticket = svc.submit(req)                             # launch 1
+        svc.drain()
+        res = ticket.result()
+        assert res.launch_seq == first.result().launch_seq == 1
+        assert res.chain_offset == 1
+        spec = svc.bucket_spec(g11)
+        sess = api.Session(spec)
+        key = jax.random.fold_in(jax.random.PRNGKey(11), res.launch_seq)
+        np.testing.assert_array_equal(res.launch_key, np.asarray(key))
+        km, kn = jax.random.split(key)
+        m0 = pbit.random_spins(km, svc.capacity_chains, spec.graph.n_nodes)
+        ns = sess.noise_state(kn)
+        emb = embed_graph(g11, spec.graph)
+        Jb, hb = embed_program(emb, req.J_codes, req.h_codes)
+        cm = cv = None
+        if clamped:
+            cm = np.zeros(spec.graph.n_nodes, bool)
+            cm[emb.node_map] = mask
+            cv = np.zeros((svc.capacity_chains, spec.graph.n_nodes),
+                          np.float32)
+            cv[0, emb.node_map] = -vals[0]
+            cv[1:4, emb.node_map] = vals
+        prog = sess.make_program(jnp.asarray(Jb), jnp.asarray(hb),
+                                 clamp_mask=cm, clamp_values=cv)
+        m, _, _ = sess.sample_program(
+            prog, m0, ns, jnp.full((req.n_sweeps,), req.beta, jnp.float32))
+        np.testing.assert_array_equal(
+            res.spins, np.asarray(m)[1:4][:, emb.node_map])
+
+    def test_warm_pump_is_one_dispatch_and_one_fetch(self, g11,
+                                                     monkeypatch):
+        """After warm-up a launch of several requests places its operands
+        once, makes one compiled call and no eager device op, and copies
+        to the host once: no implicit transfer either way."""
+        svc = _service(capacity_chains=8)
+        svc.submit(_request(g11, chains=1, seed=2))
+        svc.drain()                                   # build and compile
+        (entry,) = svc.cache._entries.values()
+        calls = []
+
+        def counted(key, fn):
+            def call(*args):
+                calls.append(key)
+                return fn(*args)
+            return call
+
+        fns = entry.session._fns
+        for key in list(fns):
+            fns[key] = counted(key, fns[key])
+        eager = []
+
+        def count(owner, name):
+            fn = getattr(owner, name)
+
+            def call(*args, **kw):
+                eager.append(name)
+                return fn(*args, **kw)
+            monkeypatch.setattr(owner, name, call)
+
+        for owner, name in [(jax.random, "fold_in"), (jax.random, "split"),
+                            (pbit, "random_spins"),
+                            (api.Session, "noise_state"),
+                            (api.Session, "make_program"),
+                            (api.Session, "sample_program"),
+                            (jax, "device_put"), (jax, "device_get")]:
+            count(owner, name)
+        tickets = [svc.submit(_request(g11, chains=c, seed=3))
+                   for c in (2, 3, 1)]
+        with jax.transfer_guard("disallow"):
+            assert svc.pump() == 3
+        results = [t.result() for t in tickets]
+        assert len({r.launch_seq for r in results}) == 1
+        assert calls == [("sample_program_keyed",)]
+        assert sorted(eager) == ["device_get", "device_put"]
+        for r in results[1:]:
+            np.testing.assert_array_equal(r.launch_key,
+                                          results[0].launch_key)
+
     def test_batching_multiplexes_one_launch(self, g11):
         svc = _service(capacity_chains=8)
         a = svc.submit(_request(g11, tenant="a", chains=2, seed=5))
