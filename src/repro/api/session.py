@@ -102,6 +102,20 @@ class SessionState(NamedTuple):
 # works on specs a Session would reject — programming only depends on the
 # graph, the mismatch instance, and the analog model)
 # ---------------------------------------------------------------------------
+def _state_sharding(engine) -> SessionState:
+    return SessionState(engine.spin_sharding, engine.noise_sharding)
+
+
+def _on_host(x, dtype=None):
+    """``x`` as numpy, or as it is if it is already a device array (cast
+    to ``dtype`` either way); None stays None.  Nothing moves."""
+    if x is None:
+        return None
+    if isinstance(x, jax.Array):
+        return x if dtype is None else x.astype(dtype)
+    return np.asarray(x, dtype)
+
+
 def _graph_tables(spec: SamplerSpec, tables=None):
     if tables is not None:
         return tables
@@ -506,31 +520,57 @@ class Session:
         """(B, N) spins of +-1 drawn from ``key``; on a band-resident mesh
         each device draws only its band (the draw is partitionable, so it
         equals the single-device one)."""
-        sharding = None if self._engine is None \
-            else self._engine.spin_sharding
-        if sharding is None:
-            return pbit.random_spins(key, self.spec.chains,
-                                     self.graph.n_nodes)
-        fn = self._fn(("random_spins",), lambda: jax.jit(
-            functools.partial(pbit.random_spins, batch=self.spec.chains,
-                              n_nodes=self.graph.n_nodes),
-            out_shardings=sharding))
-        with span("dist.place"):
-            return fn(key)
+        return self._drawn("random_spins", self._draw_spins,
+                           lambda e: e.spin_sharding, key)
 
     def noise_state(self, key: jax.Array) -> jax.Array:
-        sharding = None if self._engine is None \
-            else self._engine.noise_sharding
-        if sharding is None:
-            return self._noise_init(key)
-        fn = self._fn(("noise_state",), lambda: jax.jit(
-            self._noise_init, out_shardings=sharding))
-        with span("dist.place"):
-            return fn(key)
+        return self._drawn("noise_state", self._noise_init,
+                           lambda e: e.noise_sharding, key)
 
     def init_state(self, key: jax.Array) -> SessionState:
+        return self._drawn("init_state", self._draw, _state_sharding, key)
+
+    def launch_state(self, base_key: jax.Array, seq
+                     ) -> tuple[SessionState, jax.Array]:
+        """``init_state(fold_in(base_key, seq))`` in one compiled call:
+        (state, key).
+
+        ``seq`` (a uint32) is an operand, so one executable serves every
+        launch, and the key comes back for the caller's replay record.
+        The state is bit for bit the eager recipe's (`serve.SamplerService`
+        draws each launch's chains with it)."""
+        with span("session.launch_state"):
+            fn = self._fn(("launch_state",), self._build_launch_state)
+            return fn(base_key, np.uint32(seq))
+
+    def _build_launch_state(self):
+        def impl(base_key, seq):
+            key = jax.random.fold_in(base_key, seq)
+            return self._drawn("init_state", self._draw, _state_sharding,
+                               key), key
+
+        return named_jit(impl, "launch_state")
+
+    def _draw_spins(self, key: jax.Array) -> jax.Array:
+        return pbit.random_spins(key, self.spec.chains, self.graph.n_nodes)
+
+    def _draw(self, key: jax.Array) -> SessionState:
+        """The initial state's one recipe: split, spins from the first
+        key, noise state from the second."""
         k1, k2 = jax.random.split(key)
-        return SessionState(self.random_spins(k1), self.noise_state(k2))
+        return SessionState(self._draw_spins(k1), self._noise_init(k2))
+
+    def _drawn(self, name: str, draw, sharding, key: jax.Array):
+        """``draw(key)``; on a band-resident mesh compiled to land in
+        ``sharding(engine)``, so each device draws only its part (inside
+        a jit, as a nested call that pins the sharding)."""
+        eng = self._engine
+        if eng is None or eng.spin_sharding is None:
+            return draw(key)
+        fn = self._fn((name,), lambda: jax.jit(
+            draw, out_shardings=sharding(eng)))
+        with span("dist.place"):
+            return fn(key)
 
     # ------------------------------------------------------------------
     # chip programming (dense or sparse-native, per the spec's mismatch)
@@ -592,21 +632,15 @@ class Session:
         """
         with span("session.make_program"):
             E, n = self.graph.n_edges, self.graph.n_nodes
-            if self._band_program:
-                # codes stay off any one device: h in its bands, the
-                # edge list split evenly (each band gathers its edges
-                # in-jit)
-                J = self._engine.place_edges(J_edge_codes)
-                h = self._engine.place_nodes(h_codes)
-            else:
-                J = jnp.asarray(J_edge_codes)
-                h = jnp.asarray(h_codes)
-            if J.shape != (E,):
+            J, h = _on_host(J_edge_codes), _on_host(h_codes)
+            if np.shape(J) != (E,):
                 raise ValueError(
                     f"J_edge_codes must be edge-list shaped ({E},), got "
-                    f"{J.shape}; scatter dense codes to the edge list first")
-            if h.shape != (n,):
-                raise ValueError(f"h_codes must be ({n},), got {h.shape}")
+                    f"{np.shape(J)}; scatter dense codes to the edge list "
+                    f"first")
+            if np.shape(h) != (n,):
+                raise ValueError(
+                    f"h_codes must be ({n},), got {np.shape(h)}")
             if mismatch is not None and \
                     type(mismatch) is not type(self.spec.mismatch):
                 raise ValueError(
@@ -614,14 +648,23 @@ class Session:
                     f"not match the spec's "
                     f"{type(self.spec.mismatch).__name__}; the dense/sparse "
                     f"programming route is baked into the trace")
-            if clamp_mask is not None:
-                clamp_mask = jnp.asarray(clamp_mask)
-                if clamp_values is not None:
-                    clamp_values = jnp.asarray(clamp_values, jnp.float32)
-            elif clamp_values is not None:
+            if clamp_mask is None and clamp_values is not None:
                 raise ValueError("clamp_values without clamp_mask")
-            if betas is not None:
-                betas = jnp.asarray(betas, jnp.float32)
+            rest = (_on_host(clamp_mask),
+                    None if clamp_mask is None
+                    else _on_host(clamp_values, np.float32),
+                    _on_host(betas, np.float32))
+            if self._band_program:
+                # codes stay off any one device: h in its bands, the
+                # edge list split evenly (each band gathers its edges
+                # in-jit)
+                J = self._engine.place_edges(J)
+                h = self._engine.place_nodes(h)
+                clamp_mask, clamp_values, betas = jax.device_put(rest)
+            else:
+                # one placement for the whole program
+                J, h, (clamp_mask, clamp_values, betas) = jax.device_put(
+                    (J, h, rest))
             return Program(J_codes=J, h_codes=h, mismatch=mismatch,
                            clamp_mask=clamp_mask, clamp_values=clamp_values,
                            betas=betas)
@@ -655,12 +698,6 @@ class Session:
             return self._dispatch(fn, prog, m, noise_state, betas)
 
     def _build_sample_program(self, collect: bool):
-        # one jit: a changed optional-field structure (clamps, mismatch,
-        # program-borne betas) retraces, changed values never do
-        return self._jit(self._sample_program_impl(collect),
-                         "sample_program")
-
-    def _sample_program_impl(self, collect: bool):
         def impl(tables, prog, m, ns, betas):
             mm = self.spec.mismatch if prog.mismatch is None \
                 else prog.mismatch
@@ -678,53 +715,9 @@ class Session:
                 backend=self.backend, interpret=self.interpret,
                 flip_fn=self._flip_fn)
 
-        return impl
-
-    def sample_program_keyed(
-        self,
-        prog: Program,
-        base_key: jax.Array,
-        seq: jax.Array,
-        betas: jax.Array,
-    ) -> tuple[jax.Array, jax.Array]:
-        """`sample_program` from fresh chains drawn inside the same
-        compiled call: (m', key).
-
-        ``key = fold_in(base_key, seq)`` splits into (km, kn); the initial
-        spins are `pbit.random_spins(km, chains, N)` and the noise state
-        `noise_state(kn)`, then the program runs as `sample_program` runs
-        it.  The spins are bit for bit those of that eager recipe, and
-        ``key`` is returned for the caller's replay record.  ``seq`` is an
-        operand (a uint32 scalar): one executable serves every launch and
-        every program of a structure, so a serving launch is one dispatch
-        (`serve.SamplerService`).  On a band-resident mesh the draws take
-        the engine's spin and noise shardings, as `random_spins` and
-        `noise_state` place them.
-        """
-        with span("session.sample_program_keyed"):
-            fn = self._fn(("sample_program_keyed",),
-                          self._build_sample_program_keyed)
-            return self._dispatch(fn, prog, base_key, seq, betas)
-
-    def _build_sample_program_keyed(self):
-        run = self._sample_program_impl(False)
-        chains, n = self.spec.chains, self.graph.n_nodes
-        eng = self._engine
-        m_sh = None if eng is None else eng.spin_sharding
-        ns_sh = None if eng is None else eng.noise_sharding
-
-        def impl(tables, prog, base_key, seq, betas):
-            key = jax.random.fold_in(base_key, seq)
-            km, kn = jax.random.split(key)
-            m = pbit.random_spins(km, chains, n)
-            ns = self._noise_init(kn)
-            if m_sh is not None:
-                m = jax.lax.with_sharding_constraint(m, m_sh)
-                ns = jax.lax.with_sharding_constraint(ns, ns_sh)
-            m, _, _ = run(tables, prog, m, ns, betas)
-            return m, key
-
-        return self._jit(impl, "sample_program_keyed")
+        # one jit: a changed optional-field structure (clamps, mismatch,
+        # program-borne betas) retraces, changed values never do
+        return self._jit(impl, "sample_program")
 
     def sample_fleet(
         self,

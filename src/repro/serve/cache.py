@@ -20,9 +20,8 @@ make reuse systematic:
   pure shape-bucket key (graph bucket, resolved backend/interpret,
   partition/sync/mesh, mismatch *structure* — never drawn values): the
   programmed chip is a runtime operand of the cached Session's compiled
-  closures (`api.Program` + `Session.sample_program_keyed`), so a cache
-  entry needs no per-program state at all — dispatch is "scatter codes,
-  call".
+  closures (`api.Program` + `Session.sample_program`), so a cache entry
+  needs no per-program state at all — dispatch is "scatter codes, call".
   The service holds one bucket-sized spec per fingerprint and evicts
   least-recently-used Sessions under memory pressure.  Hit/miss/
   eviction counters surface in the service's `healthz()`; the
@@ -145,9 +144,9 @@ class CacheEntry:
 
     There is deliberately no per-program state here: the programmed chip
     used to live in a per-entry digest->EffectiveChip LRU, but with
-    `Session.sample_program_keyed` the program is an operand of the
-    compiled executable, so dispatch re-scatters the O(E) codes every
-    launch and the cache's only job is holding compiled Sessions.
+    `Session.sample_program` the program is an operand of the compiled
+    executable, so dispatch re-scatters the O(E) codes every launch and
+    the cache's only job is holding compiled Sessions.
     """
 
     session: Any                 # api.Session

@@ -564,20 +564,20 @@ class SamplerService:
         head = batch[0]
         with span("serve.entry"):
             fp, entry = self._entry_for(head.bshape)
+        sess = entry.session
         with span("serve.inputs"):
             cm, cv = self._assemble_clamps(batch, entry.embeddable)
-            # the program (codes + clamps) and the launch seq are runtime
-            # operands of the bucket Session's one compiled executable,
-            # which draws the launch's spins and noise state itself: no
-            # per-digest chip cache, no retrace on a new tenant problem,
-            # and one host->device placement per launch
-            prog, seq_d, betas = jax.device_put((
-                api.Program(J_codes=head.Jb, h_codes=head.hb,
-                            clamp_mask=cm, clamp_values=cv),
-                np.uint32(seq), head.betas))
+            # scatter codes, call: the program (codes + clamps) is a
+            # runtime operand of the bucket Session's one compiled
+            # executable — no per-digest chip cache, no retrace on a new
+            # tenant problem; the Session places it with one device_put
+            prog = sess.make_program(head.Jb, head.hb, clamp_mask=cm,
+                                     clamp_values=cv)
         with span("serve.dispatch"):
-            m, key = entry.session.sample_program_keyed(
-                prog, self._base_key, seq_d, betas)
+            # two compiled calls, no eager op: the launch's chains drawn
+            # from (base key, seq), then the program
+            (m0, ns), key = sess.launch_state(self._base_key, seq)
+            m, _, _ = sess.sample_program(prog, m0, ns, head.betas)
         # materialize on the host *inside* the attempt: a shard dying
         # mid-launch surfaces here, where the replay machinery can see it
         with span("serve.fetch"):

@@ -377,9 +377,10 @@ class TestServiceCore:
 
     def test_warm_pump_is_one_dispatch_and_one_fetch(self, g11,
                                                      monkeypatch):
-        """After warm-up a launch of several requests places its operands
-        once, makes one compiled call and no eager device op, and copies
-        to the host once: no implicit transfer either way."""
+        """After warm-up a launch of several requests places its program
+        once, makes two compiled calls (the chains' draw, then
+        `sample_program`) and no eager device op, and copies to the host
+        once: no implicit device->host transfer."""
         svc = _service(capacity_chains=8)
         svc.submit(_request(g11, chains=1, seed=2))
         svc.drain()                                   # build and compile
@@ -408,17 +409,15 @@ class TestServiceCore:
         for owner, name in [(jax.random, "fold_in"), (jax.random, "split"),
                             (pbit, "random_spins"),
                             (api.Session, "noise_state"),
-                            (api.Session, "make_program"),
-                            (api.Session, "sample_program"),
                             (jax, "device_put"), (jax, "device_get")]:
             count(owner, name)
         tickets = [svc.submit(_request(g11, chains=c, seed=3))
                    for c in (2, 3, 1)]
-        with jax.transfer_guard("disallow"):
+        with jax.transfer_guard_device_to_host("disallow"):
             assert svc.pump() == 3
         results = [t.result() for t in tickets]
         assert len({r.launch_seq for r in results}) == 1
-        assert calls == [("sample_program_keyed",)]
+        assert calls == [("launch_state",), ("sample_program", False)]
         assert sorted(eager) == ["device_get", "device_put"]
         for r in results[1:]:
             np.testing.assert_array_equal(r.launch_key,

@@ -130,10 +130,10 @@ def test_serve_spans_nest_and_name_the_request_and_launch(tmp_path):
     entry = _one_within(spans, "serve.entry", launch)
     _one_within(spans, "serve.build", entry)  # the bucket's first launch
     inputs = _one_within(spans, "serve.inputs", launch)
-    assert not [s for s in spans
-                if s.name.startswith("session.") and _within(s, inputs)]
+    _one_within(spans, "session.make_program", inputs)
     dispatch = _one_within(spans, "serve.dispatch", launch)
-    _one_within(spans, "session.sample_program_keyed", dispatch)
+    _one_within(spans, "session.launch_state", dispatch)
+    _one_within(spans, "session.sample_program", dispatch)
     fetch = _one_within(spans, "serve.fetch", launch)
     assert entry.end <= inputs.start <= inputs.end <= dispatch.start
     assert dispatch.end <= fetch.start
@@ -224,8 +224,7 @@ def lowerings():
     return {
         "sample_program": (ses._build_sample_program(False),
                            (prog, m, ns, betas)),
-        "sample_program_keyed": (ses._build_sample_program_keyed(),
-                                 (prog, key, np.uint32(3), betas)),
+        "launch_state": (ses._build_launch_state(), (key, np.uint32(3))),
         "sample": (ses._build_sample(False, False), (chip, m, ns, betas)),
         "stats": (ses._build_stats(3, 1, 1.0, False), (chip, m, ns)),
         "visible_hist": (ses._build_hist(vis, 1), (chip, m, ns, betas)),
@@ -247,7 +246,7 @@ def lowerings():
     }
 
 
-@pytest.mark.parametrize("name", ["sample_program", "sample_program_keyed",
+@pytest.mark.parametrize("name", ["sample_program", "launch_state",
                                   "sample", "stats",
                                   "visible_hist", "cd_step", "sample_fleet",
                                   "cd_fleet_step", "cd_epoch", "cd_eval"])
